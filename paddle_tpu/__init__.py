@@ -200,8 +200,7 @@ def flops(net, input_size, custom_ops=None, print_detail=False):
         return out._value if isinstance(out, Tensor) else out
 
     try:
-        from ._compat import cost_analysis as _cost_analysis
-        cost = _cost_analysis(_j.jit(fwd).lower(x).compile())
+        cost = _j.jit(fwd).lower(x).compile().cost_analysis()
     except Exception as e:
         import warnings as _w
         _w.warn(f"paddle.flops could not trace the forward at input_size="

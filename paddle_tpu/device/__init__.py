@@ -31,8 +31,8 @@ def get_available_custom_device():
 
 def synchronize(device=None):
     """Block until all queued work on the device is done."""
-    (jax.effects_barrier if hasattr(jax, "effects_barrier") else lambda: None)()
-    for d in jax.live_arrays() if hasattr(jax, "live_arrays") else []:
+    jax.effects_barrier()
+    for d in jax.live_arrays():
         try:
             d.block_until_ready()
             break

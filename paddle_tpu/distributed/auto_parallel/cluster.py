@@ -130,7 +130,10 @@ class Cluster:
         for key, spec in _KNOWN_CHIPS.items():
             if key in kind:
                 return spec
-        return _KNOWN_CHIPS["tpu v5e"]
+        raise ValueError(
+            f"no spec-sheet row for device kind {kind!r} (known: "
+            f"{sorted(_KNOWN_CHIPS)}); a roofline against another chip's "
+            f"peaks would be a wrong number, so add the row")
 
     # -- queries --------------------------------------------------------------
     @property

@@ -154,8 +154,7 @@ def compile_and_rank(model_factory, batch_structs, plans=None,
                            mem.output_size_in_bytes -
                            mem.alias_size_in_bytes)
                 metrics["peak_bytes_per_chip"] = peak
-                from ..._compat import cost_analysis as _cost_analysis
-                cost = _cost_analysis(compiled)
+                cost = compiled.cost_analysis()
                 flops = float(cost.get("flops", 0.0))
                 bytes_ = float(cost.get("bytes accessed", 0.0))
                 metrics["flops"] = flops

@@ -76,8 +76,7 @@ class Tuner:
             m["peak_bytes"] = int(mem.temp_size_in_bytes +
                                   mem.argument_size_in_bytes +
                                   mem.output_size_in_bytes)
-        from ..._compat import cost_analysis as _cost_analysis
-        cost = _cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         if cost:
             flops = float(cost.get("flops", 0.0))
             bytes_ = float(cost.get("bytes accessed", 0.0))

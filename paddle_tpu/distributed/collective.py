@@ -221,8 +221,7 @@ def _sharded_axis_exec(fn, value, g: Group):
                                            (s if isinstance(s, tuple) else (s,))
                                            if s is not None]:
         return None
-    from .._compat import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)(value)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)(value)
 
 
 # -- core collectives --------------------------------------------------------

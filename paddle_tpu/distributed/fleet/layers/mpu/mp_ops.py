@@ -52,28 +52,13 @@ def _identity_bwd(axis, _, g):
 _identity_raw.defvjp(_identity_fwd, _identity_bwd)
 
 
-def _psum_st(x, axis):
-    """psum with an identity-transposing graph.  The custom_vjp below is
-    lost when jax.vjp runs inside an outer grad trace (the apply_op
-    double-nesting case) and jax falls back to transposing the forward
-    graph; on legacy jax that transposes psum to ANOTHER psum, silently
-    re-reducing the cotangent.  The straight-through form keeps the
-    forward value (up to 1 ulp) while its graph transpose is identity.
-    Modern jax transposes psum-of-replicated to identity already, so the
-    exact psum is kept there."""
-    from ....._compat import _SHARD_MAP_IS_TOPLEVEL
-    if _SHARD_MAP_IS_TOPLEVEL:
-        return jax.lax.psum(x, axis)
-    return x + jax.lax.stop_gradient(jax.lax.psum(x, axis) - x)
-
-
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _allreduce_raw(x, axis):
-    return _psum_st(x, axis)
+    return jax.lax.psum(x, axis)
 
 
 def _allreduce_fwd(x, axis):
-    return _psum_st(x, axis), None
+    return jax.lax.psum(x, axis), None
 
 
 def _allreduce_bwd(axis, _, g):
@@ -103,8 +88,7 @@ _concat_raw.defvjp(_concat_fwd, _concat_bwd)
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _split_raw(x, axis):
-    from ....._compat import bound_axis_size
-    n = bound_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     i = jax.lax.axis_index(axis)
     w = x.shape[-1] // n
     return jax.lax.dynamic_slice_in_dim(x, i * w, w, x.ndim - 1)

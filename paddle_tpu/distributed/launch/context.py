@@ -75,8 +75,29 @@ class Context:
 
     def nproc_per_node(self):
         if self.args.nproc_per_node is not None:
-            return self.args.nproc_per_node
-        if self.args.devices:
-            return len(self.args.devices.split(","))
-        env = self.envs.get("PADDLE_NPROC_PER_NODE")
-        return int(env) if env else 1
+            n = self.args.nproc_per_node
+        elif self.args.devices:
+            n = len(self.args.devices.split(","))
+        else:
+            n = int(self.envs.get("PADDLE_NPROC_PER_NODE") or 1)
+        check_one_process_per_tpu_host(n)
+        return n
+
+
+def check_one_process_per_tpu_host(nproc: int):
+    """A chip belongs to one process at a time, and every JAX process opens
+    all the chips of its host — so on a TPU host the launch unit is ONE
+    process per host driving all local chips (scale out with --nnodes).
+    N > 1 local workers is the CPU-simulation shape (JAX_PLATFORMS=cpu);
+    on TPU hardware the second worker would fail or hang in backend init,
+    so fail here, fast, from a parent that never imports JAX."""
+    import glob
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").lower().startswith("cpu")
+    chips = glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*")
+    if nproc > 1 and chips and not on_cpu:
+        raise RuntimeError(
+            f"{nproc} worker processes requested on a host with "
+            f"{len(chips)} TPU chip(s): a chip belongs to one process and "
+            f"each JAX process opens every local chip. Run one process per "
+            f"host (it drives all local chips through the mesh) and scale "
+            f"with --nnodes, or set JAX_PLATFORMS=cpu for a CPU simulation.")

@@ -34,25 +34,15 @@ def init_parallel_env(strategy=None):
     if world > 1 and os.environ.get("PADDLE_MASTER") and \
             os.environ.get("PADDLE_TRAINER_ID") is not None:
         # IMPORTANT: don't touch jax.process_count()/jax.devices() before
-        # initialize — backend init would make the rendezvous impossible
-        # (and round 1's silent `except: pass` hid exactly that bug)
-        try:
-            already = jax.distributed.is_initialized()
-        except AttributeError:
-            already = False
-        if not already:
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=os.environ["PADDLE_MASTER"],
-                    num_processes=world,
-                    process_id=int(os.environ["PADDLE_TRAINER_ID"]))
-            except Exception as e:
-                import warnings
-                warnings.warn(
-                    f"multi-process rendezvous failed ({type(e).__name__}: "
-                    f"{e}); continuing single-process — collectives will "
-                    f"only span this process's devices", RuntimeWarning,
-                    stacklevel=2)
+        # initialize — backend init would make the rendezvous impossible.
+        # A failed rendezvous raises: continuing single-process would run
+        # every "collective" over this process's devices only, and look
+        # like a working job.
+        if not jax.distributed.is_initialized():
+            jax.distributed.initialize(
+                coordinator_address=os.environ["PADDLE_MASTER"],
+                num_processes=world,
+                process_id=int(os.environ["PADDLE_TRAINER_ID"]))
 
     coll._ensure_default_group()
     if mesh_mod.get_global_mesh() is None:

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import _compat
 from ..core import random as random_mod
 from ..core.tensor import Tensor
 from ..nn.functional_call import functional_call, state_values
@@ -320,7 +319,7 @@ class GPipeTrainStep:
                     h = jnp.pad(h, widths)
                 blk_vals = cast(merged("blocks", params))
                 h_spec = P(batch_axis, *([None] * (h.ndim - 1)))
-                h = _compat.shard_map(
+                h = jax.shard_map(
                     pipeline, mesh=mesh,
                     in_specs=(h_spec,
                               {k: blk_specs[k] for k in blk_vals}),
@@ -764,7 +763,7 @@ class Stash1F1BTrainStep(GPipeTrainStep):
                 post_vals = cast(params["post"])
                 h_spec = P(batch_axis, *([None] * (h.ndim - 1)))
                 lab_spec = P(batch_axis, *([None] * (yb.ndim - 1)))
-                loss, du, gblk, gpost = _compat.shard_map(
+                loss, du, gblk, gpost = jax.shard_map(
                     pipeline_stash, mesh=mesh,
                     in_specs=(h_spec, lab_spec, blk_param_specs,
                               blk_buf_specs, P()),

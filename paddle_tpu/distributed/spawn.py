@@ -24,8 +24,9 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     """
     if nprocs in (-1, 0, None):
         nprocs = int(os.environ.get("PADDLE_NPROC_PER_NODE", 1))
-    from .launch.context import Node
+    from .launch.context import Node, check_one_process_per_tpu_host
 
+    check_one_process_per_tpu_host(nprocs)
     ports = [Node.get_free_port() for _ in range(nprocs)]
     eps = [f"127.0.0.1:{p}" for p in ports]
     # reference default is 'spawn' (fresh interpreter — safe with the

@@ -687,6 +687,17 @@ class ShardedTrainStep:
         core, slots = self._split_tree()
         return self._jitted.lower(core, slots, lr, tuple(batch)).compile()
 
+    def lower(self, *batch):
+        """The jax `Lowered` of the live step for this batch: traced and
+        lowered, neither compiled nor run — `lower(x, y).as_text()` shows
+        what the step will execute (e.g. its Pallas custom calls)."""
+        batch = self.shard_batch(*batch)
+        if self._jitted is None:
+            self._jitted = self._build(len(batch))
+        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+        core, slots = self._split_tree()
+        return self._jitted.lower(core, slots, lr, batch)
+
     def _donate_argnums(self):
         """Shared donation policy for the single- and multi-step jits:
         donate the core state (arg 0) only when the caller opted in, and
@@ -739,12 +750,12 @@ class ShardedTrainStep:
         """K train steps in ONE device dispatch: each arg is a [K, B, ...]
         stack of K per-step batches; returns the K losses.
 
-        Host dispatch is not free — through a remote-dispatch path it can
-        cost ~10 ms per call (docs/PERF.md), which at ~150 ms steps leaves
-        the chip idle most of the time if every step is its own call.  A
-        lax.scan over the stacked batches amortizes that to one dispatch
-        (the reference amortizes the same way by keeping the train loop in
-        C++, trainer.cc run loop)."""
+        Host dispatch is not free: when a step is short next to the
+        host's per-call cost, a call per step leaves the chip idle between
+        them.  A lax.scan over the stacked batches amortizes that to one
+        dispatch (the reference amortizes the same way by keeping the
+        train loop in C++, trainer.cc run loop).  What a dispatch costs on
+        the stock TPU backend is not measured yet (PERF.md)."""
         k = int(stacked[0].shape[0])
         vals = []
         for b in stacked:

@@ -76,25 +76,32 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
+def _mxu(a, b, dims):
+    """f32-accumulated MXU contraction.  A bf16 operand has no
+    higher-precision mode, and Mosaic refuses the request ("Bad lhs type")
+    rather than ignoring it — so `jax_default_matmul_precision="highest"`
+    (tests/conftest.py pins it; a user may) must not reach a bf16 dot.
+    f32 operands keep whatever the config asks for."""
+    low = a.dtype == jnp.bfloat16 or b.dtype == jnp.bfloat16
+    return jax.lax.dot_general(
+        a, b, (dims, ((0,), (0,))),
+        precision=jax.lax.Precision.DEFAULT if low else None,
+        preferred_element_type=jnp.float32)
+
+
 def _qk(q, k):
     """(nb,bq,d) x (nb,bk,d) -> scores (nb,bq,bk), f32."""
-    return jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    return _mxu(q, k, ((2,), (2,)))
 
 
 def _pv(p, v):
     """(nb,bq,bk) x (nb,bk,d) -> (nb,bq,d), f32."""
-    return jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    return _mxu(p, v, ((2,), (1,)))
 
 
 def _tq_contract(a, b):
     """(nb,bq,bk) x (nb,bq,d) contracted over bq -> (nb,bk,d), f32."""
-    return jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    return _mxu(a, b, ((1,), (1,)))
 
 
 def _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols):

@@ -258,8 +258,7 @@ class GPTSelfAttention(Layer):
         nh = self.num_heads
         axis = getattr(self.qkv_proj.mp_group, "axis_name", None) or "mp"
         if self.mp_degree > 1 and mesh_mod.axis_bound(axis):
-            from .._compat import bound_axis_size
-            nh //= bound_axis_size(axis)
+            nh //= mesh_mod.bound_axis_size(axis)
         qkv = qkv.reshape([b, t, nh, 3, self.head_dim])
         qkv = _constrain(qkv, P(_U, _U, "mp", _U, _U))
         if cache is None and not use_cache:

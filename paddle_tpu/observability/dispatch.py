@@ -2,13 +2,15 @@
 
 Every framework op funnels through ``core.op.apply_op``; when telemetry is
 on, that hub calls :func:`record` with the op name and host wall-time.  The
-eager-vs-traced split rides on ``jax.core.trace_state_clean()``: inside any
-jit/vjp trace the op executes as graph construction (its host time is trace
-overhead, not kernel time), outside it is a real eager dispatch — the same
-distinction the reference draws between dygraph kernel launches and static
-program building.
+eager-vs-traced split rides on ``jax.core.trace_ctx.is_top_level()``:
+inside any jit/vjp trace the op executes as graph construction (its host
+time is trace overhead, not kernel time), outside it is a real eager
+dispatch — the same distinction the reference draws between dygraph kernel
+launches and static program building.
 """
 from __future__ import annotations
+
+import jax
 
 from . import metrics as metrics_mod
 from . import registry
@@ -18,17 +20,9 @@ OP_DISPATCH_TOTAL = "paddle_tpu_op_dispatch_total"
 OP_HOST_SECONDS = "paddle_tpu_op_host_seconds_total"
 
 
-def _trace_state_clean() -> bool:
-    import jax
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - future jax relocations
-        return True
-
-
 def record(name: str, seconds: float):
     """One op dispatch: count it, split by mode, accumulate host time."""
-    mode = "eager" if _trace_state_clean() else "traced"
+    mode = "eager" if jax.core.trace_ctx.is_top_level() else "traced"
     reg = registry()
     reg.counter(OP_DISPATCH_TOTAL,
                 "framework op dispatches through apply_op").inc(

@@ -182,8 +182,7 @@ def register_program(program: str, key, fn, args, kwargs):
     if not (sampling_active() or _telemetry_on()):
         return
     try:
-        from .._compat import cost_analysis
-        cost = cost_analysis(fn.lower(*args, **kwargs).compile())
+        cost = fn.lower(*args, **kwargs).compile().cost_analysis()
     except Exception:  # noqa: BLE001 — AOT path missing on this fn: no cost
         return
     register_cost(program, key, cost)

@@ -439,8 +439,10 @@ class Engine:
             gather temp, int8 pools stream int8 bytes.  Greedy output
             is token-identical to the XLA read; decode stays ONE
             compiled signature and composes with every flag here.  On
-            CPU the kernel runs in Pallas interpret mode (auto-detected;
-            the parity gate tier-1 exercises).
+            the ``cpu`` backend the kernel runs in Pallas interpret mode
+            (the parity gate tier-1 exercises); anywhere else Mosaic
+            compiles it, and a ``max_pages_per_slot`` x heads whose
+            scores row cannot fit VMEM is a ``ValueError`` here.
         sample_on_device: fuse temperature/top-k/greedy sampling into the
             decode program (per-slot params + counter-based PRNG keys);
             only ``[B(, k)]`` token ids cross the host boundary per step.
@@ -585,6 +587,14 @@ class Engine:
                     f"max_pages_per_slot must be >= 1, got {n_pt}")
             n_pages = (self.max_slots * dense_pages if num_pages is None
                        else int(num_pages))
+            if decode_kernel == "pallas":
+                # what Mosaic cannot compile is refused here, by name —
+                # never run interpreted, never quietly read through XLA
+                from ..kernels.paged_attention import check_supported
+                check_supported(
+                    page_size=P, max_pages_per_slot=n_pt,
+                    heads=int(getattr(cfg, "num_attention_heads", 1)),
+                    width=self._spec_width)
             self._page_alloc = PageAllocator(n_pages, P)
             self._max_pages_per_slot = n_pt
             # virtual per-slot length: how far a slot's page table can

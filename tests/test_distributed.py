@@ -12,7 +12,7 @@ from jax.sharding import PartitionSpec as P
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 from paddle_tpu.distributed import fleet
-from paddle_tpu._compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(autouse=True)

@@ -15,7 +15,7 @@ from paddle_tpu.distributed.fleet.elastic import (ElasticLevel,
                                                   ElasticManager,
                                                   ElasticStatus)
 from paddle_tpu.distributed.fleet.elastic.manager import _parse_np
-from paddle_tpu._compat import shard_map
+from jax import shard_map
 
 
 # -- TCPStore (native C++) ---------------------------------------------------

@@ -15,7 +15,7 @@ from paddle_tpu.incubate.distributed.models import moe
 from paddle_tpu.incubate.distributed.models.moe import (
     ClipGradForMOEByGlobalNorm, MoELayer, NaiveGate, SwitchGate, GShardGate,
     _limit_by_capacity, _number_count, _prune_gate_by_capacity)
-from paddle_tpu._compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(autouse=True)

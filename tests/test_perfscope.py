@@ -19,7 +19,6 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import observability as obs
-from paddle_tpu._compat import cost_analysis
 from paddle_tpu.observability import flight, perfscope, retrace, watchdog
 
 
@@ -63,8 +62,8 @@ def test_cost_registered_per_signature_matches_cost_analysis():
     f(x)
     st = perfscope.program_stats("perfscope.cost")
     assert st is not None and st["signatures"] == 1
-    expect = cost_analysis(
-        jax.jit(lambda x: (x @ x).sum()).lower(x).compile())
+    expect = jax.jit(
+        lambda x: (x @ x).sum()).lower(x).compile().cost_analysis()
     (cost,) = st["costs"].values()
     assert cost["flops"] == pytest.approx(
         float(expect.get("flops", 0.0)), rel=1e-6)

@@ -11,7 +11,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.kernels.ring_attention import (
     ring_attention, ulysses_attention, _dense_attention)
-from paddle_tpu._compat import shard_map
+from jax import shard_map
 
 
 def _mesh(n=4):

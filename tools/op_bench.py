@@ -73,28 +73,20 @@ def _materialize(shapes):
 
 
 def bench_op(fn, args, iters: int = 20, warmup: int = 2) -> float:
-    """Median-of-three timing of `iters` executions, us/call.
-
-    The fence transfers ONE element sliced on-device: block_until_ready is
-    not a reliable sync on remote-dispatch backends, and fetching the full
-    output would time device-to-host bandwidth instead of the op.
-    """
+    """Median-of-three timing of `iters` executions, us/call; every window
+    ends in `block_until_ready`, so it times finished device work."""
     import jax
-
-    def _fence(out):
-        leaf = jax.tree.leaves(out)[0]
-        np.asarray(leaf.ravel()[0:1])
 
     jitted = jax.jit(fn)
     for _ in range(max(1, warmup)):
         out = jitted(*args)
-    _fence(out)
+    jax.block_until_ready(out)
     times = []
     for _ in range(3):
         t0 = time.perf_counter()
         for _ in range(iters):
             out = jitted(*args)
-        _fence(out)
+        jax.block_until_ready(out)
         times.append((time.perf_counter() - t0) / iters)
     return float(np.median(times) * 1e6)
 
@@ -108,6 +100,8 @@ def main(argv=None):
 
     import jax
 
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     device = jax.devices()[0]
     results = []
     for key, (op, config, fn, shapes) in sorted(_configs().items()):
