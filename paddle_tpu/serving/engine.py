@@ -25,7 +25,8 @@ production LLM servers (vLLM/Orca-style continuous batching) converged on:
 * **observability**: spans + flight events for admit/prefill/decode/evict,
   gauges for active slots and queue depth, histograms for time-to-first-
   token and per-token latency — all through the paddle_tpu.observability
-  registry, live from request one.
+  registry, live from request one.  Every scheduler iteration is cut into
+  ``serving.*`` phases (docs/serving.md) that reach the JAX profiler's trace.
 
 **Decode fast path** (docs/serving.md "Decode fast path"): decode is
 HBM-bandwidth-bound — every step reads the full weights + KV pool to emit
@@ -84,6 +85,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..observability import flight, registry, span
+from ..observability.trace import phase
 from ..observability import perfscope as _perfscope
 from ..observability import steps as _steps
 from ..observability import watchdog as _watchdog
@@ -674,6 +676,7 @@ class Engine:
         self._counts = {"submitted": 0, "completed": 0, "rejected": 0,
                         "cancelled": 0, "deadline_expired": 0, "failed": 0,
                         "decode_steps": 0, "prefill_batches": 0,
+                        "prefill_tokens": 0, "prefill_padded_tokens": 0,
                         "tokens": 0, "resubmitted": 0, "redispatched": 0,
                         "interrupted": 0, "prefix_hits": 0,
                         "prefix_misses": 0, "prefix_evictions": 0,
@@ -1616,7 +1619,9 @@ class Engine:
                 # age via health())
                 self._last_progress = time.perf_counter()
             if not did:
-                self._wake.wait(0.02)
+                with phase("serving.wait") as idle:
+                    if not self._wake.wait(0.02):
+                        idle.drop()         # nobody called: no record
                 self._wake.clear()
 
     def _fail_as_dead(self, cause: BaseException):
@@ -1695,11 +1700,19 @@ class Engine:
 
     def _step_once(self) -> bool:
         """One scheduler iteration: sweep, admit (batched prefill), one
-        batched decode step.  Returns whether any work happened."""
+        batched decode step.  Returns whether any work happened.  Every
+        instant of it lies in one ``serving.*`` leaf phase (the table in
+        docs/observability.md)."""
         faults.fault_point("serving.scheduler")
-        self._sweep()
-        did = self._admit()
-        did = self._decode_step() or did
+        # unlocked reads: the counts only label the iteration's record
+        with phase("serving.iteration", active=self._pool.n_active,
+                   queued=len(self._queue)) as it:
+            with phase("serving.sweep"):
+                self._sweep()
+            did = self._admit()
+            did = self._decode_step() or did
+            if not did:
+                it.drop()       # an idle turn leaves the span ring alone
         return did
 
     def health(self) -> dict:
@@ -2102,7 +2115,7 @@ class Engine:
     def _admit(self) -> bool:
         import jax
 
-        with self._lock:
+        with phase("serving.admit"), self._lock:
             if self.paged_kv:
                 batch, evicted = self._admit_paged_locked()
             else:
@@ -2124,37 +2137,39 @@ class Engine:
                     # cold start: the first admission wave pays the pool
                     # build — attribute it, don't leave a mystery gap
                     req.journey.phase("build", t_b0, dt_b)
-        self._flush_adapter_uploads(batch)
-        self._flush_promotes(batch)
-        if evicted:
-            registry().counter(
-                SERVING_PREFIX_EVICTIONS,
-                "prefix-cache rows evicted back to the free list").inc(
-                float(evicted))
-        if prefix_metrics is not None:
-            reg = registry()
-            hits, misses = prefix_metrics
-            if hits:
-                reg.counter(SERVING_PREFIX_HITS,
-                            "admissions served from the prefix cache").inc(
-                    float(hits))
-            if misses:
-                reg.counter(SERVING_PREFIX_MISSES,
-                            "admissions with no usable cached prefix").inc(
-                    float(misses))
-        for req in batch:
-            # per-request PRNG base key for the device sampler (one tiny
-            # eager op per ADMISSION, not per token)
-            req._base_key = np.asarray(jax.random.PRNGKey(req.seed),
-                                       np.uint32)
-        cold = [r for r in batch if r._prefix_src is None]
-        hits = [r for r in batch if r._prefix_src is not None]
+        with phase("serving.admit.wave", n=len(batch)):
+            # what the admitted wave needs before its prefill
+            self._flush_adapter_uploads(batch)
+            self._flush_promotes(batch)
+            if evicted:
+                registry().counter(
+                    SERVING_PREFIX_EVICTIONS,
+                    "prefix-cache rows evicted back to the free list").inc(
+                    float(evicted))
+            if prefix_metrics is not None:
+                reg = registry()
+                hits, misses = prefix_metrics
+                if hits:
+                    reg.counter(
+                        SERVING_PREFIX_HITS,
+                        "admissions served from the prefix cache").inc(
+                        float(hits))
+                if misses:
+                    reg.counter(
+                        SERVING_PREFIX_MISSES,
+                        "admissions with no usable cached prefix").inc(
+                        float(misses))
+            for req in batch:
+                # per-request PRNG base key for the device sampler (one
+                # tiny eager op per ADMISSION, not per token)
+                req._base_key = np.asarray(jax.random.PRNGKey(req.seed),
+                                           np.uint32)
+            cold = [r for r in batch if r._prefix_src is None]
+            hits = [r for r in batch if r._prefix_src is not None]
         if cold:
             self._prefill_cold(cold)
         if hits:
             self._prefill_hits(hits)
-        with self._lock:
-            self._gauges_locked()
         return True
 
     def _set_slot_params_locked(self, req: RequestHandle):
@@ -2330,6 +2345,60 @@ class Engine:
         bucket = _bucket(max(r.prompt.size for r in batch),
                          min(8, self._limit), self._limit)
         P = self.prefill_batch
+        prompt_tokens = sum(int(r.prompt.size) for r in batch)
+        with span("serving.prefill", n=len(batch), bucket=bucket):
+            try:
+                with phase("serving.prefill.dispatch", rows=len(batch),
+                           batch_rows=P, bucket=bucket,
+                           prompt_tokens=prompt_tokens,
+                           padded_tokens=P * bucket):
+                    (ids, slot_idx, plens, temps, topks, keys, aid_rows,
+                     tables) = self._prefill_rows(batch, bucket)
+                    t0 = time.perf_counter()
+                    faults.fault_point("serving.prefill", n=len(batch))
+                    if self._decode_timeout_s is not None:
+                        _watchdog.arm("serving.prefill",
+                                      self._decode_timeout_s)
+                    extra = ((self._adp_args(aid_rows),)
+                             if self._adapters is not None else ())
+                    if self.paged_kv:
+                        out, self._pools = self._prefill_fn(
+                            self._values, jnp.asarray(ids), self._pools,
+                            jnp.asarray(tables), jnp.asarray(plens),
+                            jnp.asarray(temps), jnp.asarray(topks),
+                            jnp.asarray(keys), *extra)
+                    else:
+                        out, self._pools = self._prefill_fn(
+                            self._values, jnp.asarray(ids), self._pools,
+                            jnp.asarray(slot_idx), jnp.asarray(plens),
+                            jnp.asarray(temps), jnp.asarray(topks),
+                            jnp.asarray(keys), *extra)
+                with phase("serving.prefill.fetch"):
+                    out = np.asarray(out)
+            finally:
+                if self._decode_timeout_s is not None:
+                    _watchdog.disarm()
+            with phase("serving.prefill.emit"):
+                dt = time.perf_counter() - t0
+                with self._lock:
+                    self._counts["prefill_batches"] += 1
+                    self._counts["prefill_tokens"] += prompt_tokens
+                    self._counts["prefill_padded_tokens"] += P * bucket
+                registry().histogram(
+                    SERVING_BATCH_SECONDS,
+                    "prefill/decode batch wall time").observe(
+                    dt, labels={"phase": "prefill"})
+                for req in batch:
+                    if req.journey is not None:
+                        req.journey.phase("prefill", t0, dt, n=len(batch),
+                                          bucket=bucket,
+                                          prompt=int(req.prompt.size))
+                self._emit_first_tokens(batch, out, by_slot=False)
+
+    def _prefill_rows(self, batch, bucket: int):
+        """The host arrays of one cold prefill dispatch: ``prefill_batch``
+        rows of ``bucket`` positions, the rows past ``batch`` padding."""
+        P = self.prefill_batch
         ids = np.zeros((P, bucket), np.int64)
         slot_idx = np.full(P, self.max_slots, np.int32)
         plens = np.ones(P, np.int32)
@@ -2357,42 +2426,7 @@ class Engine:
                               prompt_len=int(req.prompt.size),
                               queue_wait_ms=round(
                                   1e3 * (req.t_admit - req.t_submit), 3))
-        t0 = time.perf_counter()
-        faults.fault_point("serving.prefill", n=len(batch))
-        if self._decode_timeout_s is not None:
-            _watchdog.arm("serving.prefill", self._decode_timeout_s)
-        try:
-            extra = ((self._adp_args(aid_rows),)
-                     if self._adapters is not None else ())
-            with span("serving.prefill", n=len(batch), bucket=bucket):
-                if self.paged_kv:
-                    out, self._pools = self._prefill_fn(
-                        self._values, jnp.asarray(ids), self._pools,
-                        jnp.asarray(tables), jnp.asarray(plens),
-                        jnp.asarray(temps), jnp.asarray(topks),
-                        jnp.asarray(keys), *extra)
-                else:
-                    out, self._pools = self._prefill_fn(
-                        self._values, jnp.asarray(ids), self._pools,
-                        jnp.asarray(slot_idx), jnp.asarray(plens),
-                        jnp.asarray(temps), jnp.asarray(topks),
-                        jnp.asarray(keys), *extra)
-                out = np.asarray(out)
-        finally:
-            if self._decode_timeout_s is not None:
-                _watchdog.disarm()
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self._counts["prefill_batches"] += 1
-        registry().histogram(SERVING_BATCH_SECONDS,
-                             "prefill/decode batch wall time").observe(
-            dt, labels={"phase": "prefill"})
-        for req in batch:
-            if req.journey is not None:
-                req.journey.phase("prefill", t0, dt, n=len(batch),
-                                  bucket=bucket,
-                                  prompt=int(req.prompt.size))
-        self._emit_first_tokens(batch, out, by_slot=False)
+        return ids, slot_idx, plens, temps, topks, keys, aid_rows, tables
 
     def _prefill_hits(self, hits) -> None:
         """Prefix-cache hit path.  Dense pool: device-copy the cached
@@ -2403,17 +2437,106 @@ class Engine:
         (host-side int writes); only a partial boundary page needs its
         one-page COW clone before the tail writes into it."""
         import jax.numpy as jnp
-        P = self.prefill_batch
-        scratch = self.max_slots
         paged = self.paged_kv
-        sentinel = self._page_alloc.num_pages if paged else scratch
+        n_rows = self.max_slots + 1
+        tails = [r.prompt.size - r._prefix_match for r in hits]
+        tail_tokens = int(sum(tails))
+        tb = _bucket(max(tails), 1, self._limit)
+        with span("serving.tail_prefill", n=len(hits), bucket=tb):
+            try:
+                # `serving.prefix_copy`, the copy program's dispatch, is a
+                # named step inside this phase
+                with phase("serving.tail_prefill.dispatch", rows=len(hits),
+                           batch_rows=n_rows, bucket=tb,
+                           prompt_tokens=tail_tokens,
+                           padded_tokens=n_rows * tb):
+                    (src, dst, n_copy, cow_ids, ids, lens, gidx, tables,
+                     aids_snap) = self._tail_rows(hits, tb)
+                    t0 = time.perf_counter()
+                    faults.fault_point("serving.prefill", n=len(hits))
+                    if self._decode_timeout_s is not None:
+                        _watchdog.arm("serving.tail_prefill",
+                                      self._decode_timeout_s)
+                    if n_copy or not paged:
+                        # dense: whole-row clone per hit; paged: only the
+                        # COW'd boundary pages (usually zero — block ==
+                        # page size makes every shared page a full page)
+                        with span("serving.prefix_copy", n=n_copy):
+                            self._pools = self._copy_fn(
+                                self._pools, jnp.asarray(src),
+                                jnp.asarray(dst))
+                        if paged and n_copy:
+                            with self._lock:
+                                self._counts["page_cow_copies"] += n_copy
+                            registry().counter(
+                                SERVING_KV_COW_COPIES,
+                                "shared KV pages cloned for a diverging "
+                                "writer").inc(float(n_copy))
+                            flight.record(
+                                "serving", "page_cow", copies=n_copy,
+                                requests=",".join(map(str, cow_ids)))
+                    t_copy_end = time.perf_counter()
+                    extra = ((self._adp_args(aids_snap),)
+                             if self._adapters is not None else ())
+                    if paged:
+                        out, self._pools = self._tail_fn(
+                            self._values, jnp.asarray(ids), self._pools,
+                            jnp.asarray(lens), jnp.asarray(tables),
+                            jnp.asarray(gidx), jnp.asarray(self._temps),
+                            jnp.asarray(self._topks),
+                            jnp.asarray(self._keys), *extra)
+                    else:
+                        out, self._pools = self._tail_fn(
+                            self._values, jnp.asarray(ids), self._pools,
+                            jnp.asarray(lens), jnp.asarray(gidx),
+                            jnp.asarray(self._temps),
+                            jnp.asarray(self._topks),
+                            jnp.asarray(self._keys), *extra)
+                with phase("serving.tail_prefill.fetch"):
+                    out = np.asarray(out)
+            finally:
+                if self._decode_timeout_s is not None:
+                    _watchdog.disarm()
+            with phase("serving.tail_prefill.emit"):
+                t_end = time.perf_counter()
+                dt = t_end - t0
+                with self._lock:
+                    self._counts["prefill_batches"] += 1
+                    self._counts["prefill_tokens"] += tail_tokens
+                    self._counts["prefill_padded_tokens"] += n_rows * tb
+                registry().histogram(
+                    SERVING_BATCH_SECONDS,
+                    "prefill/decode batch wall time").observe(
+                    dt, labels={"phase": "tail_prefill"})
+                cow_set = set(cow_ids)
+                for req in hits:
+                    if req.journey is None:
+                        continue
+                    m = req._prefix_match
+                    # dense hits device-copy their cached row; paged hits
+                    # share pages by reference (zero-copy) unless a
+                    # boundary page COWed
+                    if not paged or req.request_id in cow_set:
+                        req.journey.phase("prefix_copy", t0, t_copy_end - t0,
+                                          cached_tokens=m)
+                    req.journey.phase(
+                        "tail_prefill", t_copy_end, t_end - t_copy_end,
+                        cached_tokens=m, tail=int(req.prompt.size - m),
+                        zero_copy=bool(paged and
+                                       req.request_id not in cow_set))
+                self._emit_first_tokens(hits, out, by_slot=True)
+
+    def _tail_rows(self, hits, tb: int):
+        """The host arrays of one prefix-hit wave: copy sources and
+        targets, and every slot row's tail padded to ``tb`` positions."""
+        P = self.prefill_batch
+        paged = self.paged_kv
+        sentinel = self._page_alloc.num_pages if paged else self.max_slots
+        n_rows = self.max_slots + 1
         src = np.full(P, sentinel, np.int32)
         dst = np.full(P, sentinel, np.int32)
         n_copy = 0
         cow_ids: list[int] = []      # requests whose boundary page COWs
-        n_rows = self.max_slots + 1
-        tails = [r.prompt.size - r._prefix_match for r in hits]
-        tb = _bucket(max(tails), 1, self._limit)
         ids = np.zeros((n_rows, tb), np.int64)
         lens = np.full(n_rows, self._park, np.int32)
         gidx = np.zeros(n_rows, np.int32)
@@ -2444,71 +2567,7 @@ class Engine:
             if paged:
                 tables = np.array(self._page_tables)
             aids_snap = np.array(self._aids)
-        t0 = time.perf_counter()
-        faults.fault_point("serving.prefill", n=len(hits))
-        if self._decode_timeout_s is not None:
-            _watchdog.arm("serving.tail_prefill", self._decode_timeout_s)
-        try:
-            if n_copy or not paged:
-                # dense: whole-row clone per hit; paged: only the COW'd
-                # boundary pages (usually zero — block == page size makes
-                # every shared page a full page)
-                with span("serving.prefix_copy", n=n_copy):
-                    self._pools = self._copy_fn(
-                        self._pools, jnp.asarray(src), jnp.asarray(dst))
-                if paged and n_copy:
-                    with self._lock:
-                        self._counts["page_cow_copies"] += n_copy
-                    registry().counter(
-                        SERVING_KV_COW_COPIES,
-                        "shared KV pages cloned for a diverging writer"
-                    ).inc(float(n_copy))
-                    flight.record("serving", "page_cow", copies=n_copy,
-                                  requests=",".join(map(str, cow_ids)))
-            t_copy_end = time.perf_counter()
-            extra = ((self._adp_args(aids_snap),)
-                     if self._adapters is not None else ())
-            with span("serving.tail_prefill", n=len(hits), bucket=tb):
-                if paged:
-                    out, self._pools = self._tail_fn(
-                        self._values, jnp.asarray(ids), self._pools,
-                        jnp.asarray(lens), jnp.asarray(tables),
-                        jnp.asarray(gidx), jnp.asarray(self._temps),
-                        jnp.asarray(self._topks), jnp.asarray(self._keys),
-                        *extra)
-                else:
-                    out, self._pools = self._tail_fn(
-                        self._values, jnp.asarray(ids), self._pools,
-                        jnp.asarray(lens), jnp.asarray(gidx),
-                        jnp.asarray(self._temps), jnp.asarray(self._topks),
-                        jnp.asarray(self._keys), *extra)
-                out = np.asarray(out)
-        finally:
-            if self._decode_timeout_s is not None:
-                _watchdog.disarm()
-        t_end = time.perf_counter()
-        dt = t_end - t0
-        with self._lock:
-            self._counts["prefill_batches"] += 1
-        registry().histogram(SERVING_BATCH_SECONDS,
-                             "prefill/decode batch wall time").observe(
-            dt, labels={"phase": "tail_prefill"})
-        cow_set = set(cow_ids)
-        for req in hits:
-            if req.journey is None:
-                continue
-            m = req._prefix_match
-            # dense hits device-copy their cached row; paged hits share
-            # pages by reference (zero-copy) unless a boundary page COWed
-            if not paged or req.request_id in cow_set:
-                req.journey.phase("prefix_copy", t0, t_copy_end - t0,
-                                  cached_tokens=m)
-            req.journey.phase("tail_prefill", t_copy_end,
-                              t_end - t_copy_end, cached_tokens=m,
-                              tail=int(req.prompt.size - m),
-                              zero_copy=bool(paged and
-                                             req.request_id not in cow_set))
-        self._emit_first_tokens(hits, out, by_slot=True)
+        return src, dst, n_copy, cow_ids, ids, lens, gidx, tables, aids_snap
 
     def _emit_first_tokens(self, batch, out, by_slot: bool):
         """Shared tail of both admission paths: record TTFT and emit each
@@ -2551,6 +2610,8 @@ class Engine:
                 finishers.append(req)
         for req in finishers:
             req._finish(None)
+        with self._lock:
+            self._gauges_locked()
 
     # -- decode --------------------------------------------------------------
     def _decode_step(self) -> bool:
@@ -2558,6 +2619,43 @@ class Engine:
             active = self._pool.active()
             if not active:
                 return False
+        import jax.numpy as jnp
+        with span("serving.decode", active=len(active)):
+            with phase("serving.decode.build"):
+                (drafts, ids, lengths, temps, topks, keys, aids,
+                 tables) = self._decode_inputs(active)
+            try:
+                with phase("serving.decode.dispatch", active=len(active)):
+                    t0 = time.perf_counter()
+                    faults.fault_point("serving.decode", active=len(active))
+                    if self._decode_timeout_s is not None:
+                        _watchdog.arm("serving.decode",
+                                      self._decode_timeout_s)
+                    extra = ((self._adp_args(aids),)
+                             if self._adapters is not None else ())
+                    if self.paged_kv:
+                        out, self._pools = self._decode_fn(
+                            self._values, jnp.asarray(ids), self._pools,
+                            jnp.asarray(lengths), jnp.asarray(tables),
+                            jnp.asarray(temps), jnp.asarray(topks),
+                            jnp.asarray(keys), *extra)
+                    else:
+                        out, self._pools = self._decode_fn(
+                            self._values, jnp.asarray(ids), self._pools,
+                            jnp.asarray(lengths), jnp.asarray(temps),
+                            jnp.asarray(topks), jnp.asarray(keys), *extra)
+                with phase("serving.decode.fetch"):
+                    out = np.asarray(out)
+            finally:
+                if self._decode_timeout_s is not None:
+                    _watchdog.disarm()
+            with phase("serving.decode.emit"):
+                self._decode_emit(active, drafts, lengths, out, t0)
+        return True
+
+    def _decode_inputs(self, active: dict):
+        """`serving.decode.build`: the drafts and the locked snapshot of
+        the slot-state arrays one decode dispatch carries."""
         W = self._spec_width
         drafts: dict = {}
         if W > 1:
@@ -2587,30 +2685,12 @@ class Engine:
             aids = np.array(self._aids)
             tables = (np.array(self._page_tables) if self.paged_kv
                       else None)
-        import jax.numpy as jnp
-        t0 = time.perf_counter()
-        faults.fault_point("serving.decode", active=len(active))
-        if self._decode_timeout_s is not None:
-            _watchdog.arm("serving.decode", self._decode_timeout_s)
-        try:
-            extra = ((self._adp_args(aids),)
-                     if self._adapters is not None else ())
-            with span("serving.decode", active=len(active)):
-                if self.paged_kv:
-                    out, self._pools = self._decode_fn(
-                        self._values, jnp.asarray(ids), self._pools,
-                        jnp.asarray(lengths), jnp.asarray(tables),
-                        jnp.asarray(temps), jnp.asarray(topks),
-                        jnp.asarray(keys), *extra)
-                else:
-                    out, self._pools = self._decode_fn(
-                        self._values, jnp.asarray(ids), self._pools,
-                        jnp.asarray(lengths), jnp.asarray(temps),
-                        jnp.asarray(topks), jnp.asarray(keys), *extra)
-                out = np.asarray(out)
-        finally:
-            if self._decode_timeout_s is not None:
-                _watchdog.disarm()
+        return drafts, ids, lengths, temps, topks, keys, aids, tables
+
+    def _decode_emit(self, active: dict, drafts: dict, lengths, out, t0):
+        """`serving.decode.emit`: accept, stream and account the tokens
+        of one fetched decode batch; retire what finished."""
+        W = self._spec_width
         dt = time.perf_counter() - t0
         with self._lock:
             self._counts["decode_steps"] += 1
@@ -2705,7 +2785,6 @@ class Engine:
             req._finish(None)
         with self._lock:
             self._gauges_locked()
-        return True
 
     def _emit_one(self, req: RequestHandle, token: int) -> bool:
         """Stream one token to the request; returns whether the request
