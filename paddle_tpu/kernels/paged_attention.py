@@ -53,6 +53,10 @@ built (tests/test_kernels_tpu_aot.py keeps the accepted matrix
 compiling between chip runs).  The serving engine routes decode through
 here only inside :func:`decode_kernel_scope` (``Engine(decode_kernel="pallas")``), the
 same trace-local mechanism the multi-LoRA adapter path uses.
+
+The **dense** pool's decode read (:func:`dense_decode_attention`, at the
+end of this file) shares the scope and the interpret-mode rule: one pass
+over each row's live blocks, routed by :func:`dense_read_block`.
 """
 from __future__ import annotations
 
@@ -63,6 +67,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -340,3 +345,224 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret_now(),
     )(*operands)
+
+
+# -- the dense pool's decode read ---------------------------------------------
+#
+# The engine's dense pool is ``[n_rows, max_len, heads, head_dim]`` per
+# layer, reserved whole; a decode step attends, per slot row, over the
+# positions that row has written.  The XLA read streams all ``max_len``
+# positions of every row under a validity mask (and a parked row, whose
+# length is ``max_len``, attends to everything).  This kernel streams, per
+# row, only the ``DENSE_BLOCK``-position blocks that hold live positions
+# ``0 .. length + W - 1`` and nothing at all for a parked row.
+#
+# The grid is a **work list** (:func:`_work_list`), one step per live block,
+# its length a run-time value (a dynamic grid bound): a dead block costs
+# nothing, where a skipped step of a static ``(n_rows, max_len / block)``
+# grid costs ~0.35 us — 1.3 ms of a 1.3B decode step (PERF.md section 5).
+# The step list and the lengths ride as scalar-prefetch operands; a step
+# holds the K and the V block of the same positions and folds them into a
+# running (max, sum, accumulator) per (query, head): an f32 softmax over the
+# whole row, computed blockwise in ONE pass.  K and V stay in the pool's own
+# ``[block, heads, head_dim]`` layout: merged to ``[block * heads, head_dim]``
+# (a free reshape) they are plain matmul operands, ``q [W * heads, head_dim]``
+# against every (position, head) pair, and the mask keeps the pairs whose
+# heads agree — the MXU is otherwise idle in a decode step, and no transpose
+# or per-head slice of a block is needed.
+
+# positions per block, timed on the v5e at 25 rows x 2048 (PERF.md section
+# 5): a step streams its whole block, so a larger one rounds every row's
+# read further up; a smaller one pays the ~0.35 us step more often
+DENSE_BLOCK = 128
+
+
+def live_blocks(lengths, width: int, max_len: int, block: int):
+    """Blocks of ``block`` positions the dense read streams for each row:
+    those that hold positions ``0 .. length + width - 1``, none for a parked
+    row (``length >= max_len``).  The kernel's work list is built from this
+    count and the engine's ``decode_kv_read_positions`` sums it (numpy in,
+    numpy out; jax in, jax out)."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    lengths = xp.asarray(lengths, xp.int32)
+    n = xp.minimum((lengths + (width + block - 1)) // block,
+                   max_len // block)
+    return xp.where(lengths >= max_len, 0, n).astype(xp.int32)
+
+
+def _work_list(nb, n_blk: int):
+    """The grid as a list of steps: one per live block, and one for a
+    parked row (it only writes the row's zeros).  Returns ``(steps, row,
+    blk, held)``: how many steps there are, and per step its row, its block
+    within the row, and the flat index ``row * n_blk + block`` of the pool
+    block it holds — a parked row's step keeps the block of the step before
+    it (an unchanged block index is not fetched again).  Entries past
+    ``steps`` are unused."""
+    xp = jnp if isinstance(nb, jax.Array) else np
+    B = nb.shape[0]
+    per_row = xp.maximum(nb, 1)
+    ends = xp.cumsum(per_row, dtype=xp.int32)
+    t = xp.arange(B * n_blk, dtype=xp.int32)
+    # the row of step t: how many rows end at or before it (one compare,
+    # no search loop in the decode program)
+    row = xp.minimum((t[:, None] >= ends[None, :]).sum(axis=1),
+                     B - 1).astype(xp.int32)
+    blk = t - (ends - per_row)[row]
+    flat = xp.where(blk < nb[row], row * n_blk + blk, 0)
+    held = (jax.lax.cummax(flat) if xp is jnp
+            else np.maximum.accumulate(flat))
+    return ends[-1], row, blk.astype(xp.int32), held.astype(xp.int32)
+
+
+def dense_blocks_held(lengths, width: int, max_len: int, block: int):
+    """The ``(row, block)`` of the pool the kernel's grid holds at each step,
+    in grid order: what the K/V index map reads, evaluated on the host.  A
+    step whose block differs from the step before is a fetch (the tests
+    count them against :func:`live_blocks`)."""
+    n_blk = max_len // block
+    steps, _, _, held = _work_list(
+        live_blocks(np.asarray(lengths), width, max_len, block), n_blk)
+    return [divmod(int(f), n_blk) for f in held[:int(steps)]]
+
+
+def dense_read_block(*, heads: int, head_dim: int, dtype, width: int,
+                     max_len: int):
+    """The block size at which the dense pool's decode read goes through
+    the kernel, or ``None`` where it keeps the XLA read: on the ``cpu``
+    backend (unless a test pinned the mode), for an int8 pool, for a
+    ``max_len`` the block does not divide, or for a span so wide that the
+    ``[width * heads, block * heads]`` scores and the double-buffered K and
+    V blocks would not fit VMEM."""
+    if _INTERPRET is None and jax.default_backend() == "cpu":
+        return None
+    if jnp.dtype(dtype) == jnp.int8:
+        return None
+    P = min(DENSE_BLOCK, max_len)
+    vmem = (4 * width * heads * P * heads * 4
+            + 4 * P * heads * head_dim * jnp.dtype(dtype).itemsize)
+    return None if max_len % P or 2 * vmem > _VMEM_LIMIT_BYTES else P
+
+
+def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, q_ref, k_ref,
+                  v_ref, col_ref, qrow_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                  P, W, H, scale):
+    t = pl.program_id(0)
+    r, i = row_ref[t], blk_ref[t]
+    n = nb_ref[r]
+    D = q_ref.shape[-1]
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(i < n)
+    def _block():
+        k2 = k_ref[0].reshape(P * H, D)
+        v2 = v_ref[0].reshape(P * H, D)
+        q2 = q_ref[0].reshape(W * H, D).astype(k2.dtype)
+        # [W*H, P*H]: query (w, h') against key (p, h); only h' == h counts
+        s = jax.lax.dot_general(
+            q2, k2, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        pos, head = col_ref[0:1, :], col_ref[1:2, :]          # [1, P*H]
+        qw, qhead = qrow_ref[:, 0:1], qrow_ref[:, 1:2]        # [W*H, 1]
+        keep = (head == qhead) & (i * P + pos <= len_ref[r] + qw)
+        s = jnp.where(keep, s, jnp.float32(_NEG_INF))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a dropped pair underflows to exactly 0: m_new is finite from block
+        # 0 on (position 0 is live for every query of a live row)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v2.dtype), v2, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(i + 1 >= n)
+    def _finish():
+        # the row's last step; a parked row (n == 0) read nothing, and its
+        # output, never read, is zeros
+        l = l_ref[...]
+        out = jnp.where(l > 0, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0)
+        o_ref[0] = out.reshape(W, H, D).astype(o_ref.dtype)
+
+
+def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
+                           scale=None):
+    """Per-slot decode attention over the dense pool, streaming live blocks
+    only.
+
+    Args:
+        q: ``[n_rows, W, heads, head_dim]`` queries of the step's new
+            positions ``length .. length + W - 1``.
+        k_pool / v_pool: ``[n_rows, max_len, heads, head_dim]`` float
+            pools, **post-write** like :func:`paged_decode_attention`.
+        lengths: ``[n_rows]`` int32 start positions; a parked row sits at
+            ``max_len`` and reads nothing.
+        block: positions per block (default :data:`DENSE_BLOCK`, at most
+            ``max_len``); must divide ``max_len``.
+
+    Returns:
+        ``[n_rows, W, heads, head_dim]`` in ``q.dtype`` (zeros for a parked
+        row).
+    """
+    B, W, H, D = q.shape
+    L = k_pool.shape[1]
+    P = min(DENSE_BLOCK, L) if block is None else int(block)
+    if L % P:
+        raise ValueError(f"block={P} does not divide max_len={L}")
+    n_blk = L // P
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    nb = live_blocks(lengths, W, L, P)
+    steps, row, blk, held = _work_list(nb, n_blk)
+    # which (position, head) a column of the scores is, and which (query,
+    # head) a row: int32 operands, fetched once (their block never moves)
+    cols = jnp.stack([jnp.repeat(jnp.arange(P, dtype=jnp.int32), H),
+                      jnp.tile(jnp.arange(H, dtype=jnp.int32), P)])
+    qrows = jnp.stack([jnp.repeat(jnp.arange(W, dtype=jnp.int32), H),
+                       jnp.tile(jnp.arange(H, dtype=jnp.int32), W)], axis=1)
+    # index maps run on the scalar core: explicit int32 throughout (x64 is
+    # on), and `t * 0` for a zero
+
+    def _kvmap(t, ln, nbr, rw, bk, hd):
+        nblk = jnp.int32(n_blk)
+        return (jax.lax.div(hd[t], nblk), jax.lax.rem(hd[t], nblk),
+                t * 0, t * 0)
+
+    def _qmap(t, ln, nbr, rw, bk, hd):
+        return (rw[t], t * 0, t * 0, t * 0)
+
+    def _const(t, *_):
+        return (t * 0, t * 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((1, W, H, D), _qmap),
+                  pl.BlockSpec((1, P, H, D), _kvmap),
+                  pl.BlockSpec((1, P, H, D), _kvmap),
+                  pl.BlockSpec((2, P * H), _const),
+                  pl.BlockSpec((W * H, 2), _const)],
+        out_specs=pl.BlockSpec((1, W, H, D), _qmap),
+        scratch_shapes=[pltpu.VMEM((W * H, 1), jnp.float32),   # running max
+                        pltpu.VMEM((W * H, 1), jnp.float32),   # running sum
+                        pltpu.VMEM((W * H, D), jnp.float32)],  # accumulator
+    )
+    kernel = functools.partial(_dense_kernel, P=P, W=W, H=H,
+                               scale=float(scale))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, W, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # every step carries the row's running softmax: sequential
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret_now(),
+    )(lengths, nb, row, blk, held, q, k_pool, v_pool, cols, qrows)

@@ -423,6 +423,22 @@ class GPTSelfAttention(Layer):
                             v_raw = v_raw.at[rows, cols].set(
                                 v._value.astype(v_raw.dtype), mode="drop")
                             k_att, v_att = k_raw, v_raw
+                            # the engine's decode step on the TPU: the
+                            # read streams each row's live blocks only
+                            # (kernels/paged_attention.py "the dense
+                            # pool's decode read"); everywhere else, and
+                            # for tail_prefill's long spans, the masked
+                            # XLA read below
+                            from ..kernels import paged_attention as _pk
+                            blk = (_pk.dense_read_block(
+                                heads=nh, head_dim=self.head_dim,
+                                dtype=k_raw.dtype, width=t,
+                                max_len=k_raw.shape[1])
+                                if _pk.active() else None)
+                            if blk is not None:
+                                att_out = _pk.dense_decode_attention(
+                                    q._value, k_raw, v_raw, start,
+                                    block=blk)
                         att_len = k_raw.shape[1]
                     if att_out is not None:
                         out = _T(att_out, _internal=True)

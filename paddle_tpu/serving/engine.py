@@ -445,6 +445,15 @@ class Engine:
             (the parity gate tier-1 exercises); anywhere else Mosaic
             compiles it, and a ``max_pages_per_slot`` x heads whose
             scores row cannot fit VMEM is a ``ValueError`` here.
+            The **dense** pool (the default) has no setting: on the TPU
+            its decode step reads, per slot, only the 128-position
+            blocks that hold live KV and nothing for an idle slot
+            (``kernels/paged_attention.py`` ``dense_decode_attention``,
+            routed from the same scope where ``dense_read_block``
+            applies); on the ``cpu`` backend, for an int8 pool and in
+            ``tail_prefill`` the masked XLA read over the whole pool
+            stays.  ``stats()`` counts both sides of it:
+            ``decode_kv_live_positions`` / ``decode_kv_read_positions``.
         sample_on_device: fuse temperature/top-k/greedy sampling into the
             decode program (per-slot params + counter-based PRNG keys);
             only ``[B(, k)]`` token ids cross the host boundary per step.
@@ -677,6 +686,8 @@ class Engine:
                         "cancelled": 0, "deadline_expired": 0, "failed": 0,
                         "decode_steps": 0, "prefill_batches": 0,
                         "prefill_tokens": 0, "prefill_padded_tokens": 0,
+                        "decode_kv_live_positions": 0,
+                        "decode_kv_read_positions": 0,
                         "tokens": 0, "resubmitted": 0, "redispatched": 0,
                         "interrupted": 0, "prefix_hits": 0,
                         "prefix_misses": 0, "prefix_evictions": 0,
@@ -1210,15 +1221,30 @@ class Engine:
             with self._lock:
                 self._ledger_rows.append(brow)
 
-        # Pallas decode kernel (kernels/paged_attention.py): the scope is
-        # entered inside the DECODE jit only, so that one program's paged
-        # attention read traces through the fused kernel while prefill /
-        # tail-prefill keep the XLA gather — a trace-time routing
-        # decision, not an operand, so the signature count is unchanged
+        # Pallas decode kernels (kernels/paged_attention.py): the scope is
+        # entered inside the DECODE jit only, so that one program's
+        # attention read traces through a kernel while prefill /
+        # tail-prefill keep the XLA read — a trace-time routing
+        # decision, not an operand, so the signature count is unchanged.
+        # The paged pool takes its kernel when asked to
+        # (decode_kernel="pallas"); the dense pool's decode enters the
+        # scope always, and the model routes the read where
+        # `dense_read_block` says the kernel applies (the TPU; never an
+        # int8 pool).  `_decode_read_block`: the positions per block or
+        # page of the kernel this engine's decode reads through, None on
+        # an XLA read (the kv_read count of `_decode_step`).
+        from ..kernels.paged_attention import (
+            decode_kernel_scope as _pk_scope, dense_read_block)
         use_pallas_decode = self.decode_kernel == "pallas"
-        if use_pallas_decode:
-            from ..kernels.paged_attention import (
-                decode_kernel_scope as _pk_scope)
+        if self.paged_kv:
+            self._decode_read_block = (self._page_alloc.page_size
+                                       if use_pallas_decode else None)
+        else:
+            k0 = kv[0][0]
+            self._decode_read_block = dense_read_block(
+                heads=int(k0.shape[2]), head_dim=int(k0.shape[3]),
+                dtype=jnp.int8 if quant else k0.dtype,
+                width=self._spec_width, max_len=L)
 
         def _mstate(values, adp, pk=False):
             """Swapped model state, plus the batched-adapter scope when
@@ -1542,7 +1568,7 @@ class Engine:
             # W=k the speculative verify — same program shape either way,
             # ONE signature per engine config.
             caches_t = _caches_from(pools, lengths)
-            with _mstate(_dq(values), adp):
+            with _mstate(_dq(values), adp, pk=True):
                 logits, new_caches = _fwd_all(
                     Tensor(ids, _internal=True), caches_t)
             pools = _pools_from(new_caches)
@@ -2624,8 +2650,10 @@ class Engine:
             with phase("serving.decode.build"):
                 (drafts, ids, lengths, temps, topks, keys, aids,
                  tables) = self._decode_inputs(active)
+                kv_live, kv_read = self._decode_kv_positions(active, lengths)
             try:
-                with phase("serving.decode.dispatch", active=len(active)):
+                with phase("serving.decode.dispatch", active=len(active),
+                           kv_live=kv_live, kv_read=kv_read):
                     t0 = time.perf_counter()
                     faults.fault_point("serving.decode", active=len(active))
                     if self._decode_timeout_s is not None:
@@ -2652,6 +2680,31 @@ class Engine:
             with phase("serving.decode.emit"):
                 self._decode_emit(active, drafts, lengths, out, t0)
         return True
+
+    def _decode_kv_positions(self, active: dict, lengths):
+        """Per layer, the KV positions one decode dispatch needs
+        (`kv_live`: each active slot's context and its new span) and the
+        positions its attention read streams (`kv_read`): every row whole
+        on an XLA read, each row's live blocks or pages on a kernel."""
+        from ..kernels.paged_attention import live_blocks
+        W = self._spec_width
+        kv_live = int(sum(int(lengths[s]) + W for s in active))
+        span = (self._max_pages_per_slot * self._page_alloc.page_size
+                if self.paged_kv else self.max_len)
+        P = self._decode_read_block
+        if P is None:
+            kv_read = len(lengths) * span
+        else:
+            nb = live_blocks(lengths, W, span, P)
+            if self.paged_kv:
+                # the paged kernel's index map stands on one clamped page
+                # for a parked row
+                nb = np.maximum(nb, 1)
+            kv_read = int(nb.sum()) * P
+        with self._lock:
+            self._counts["decode_kv_live_positions"] += kv_live
+            self._counts["decode_kv_read_positions"] += kv_read
+        return kv_live, kv_read
 
     def _decode_inputs(self, active: dict):
         """`serving.decode.build`: the drafts and the locked snapshot of

@@ -174,6 +174,32 @@ def test_paged_decode_compiles_at_bench_shape(v5e, compiled_paged):
     assert _mosaic_calls(c) == 1
 
 
+# -- the dense pool's decode read (ISSUE 26) ---------------------------------
+
+_DENSE = [  # (id, rows, max_len, heads, head_dim, W, dtype, precision)
+    ("serve-cells-bf16", 25, 2048, 16, 128, 1, bf16, "default"),
+    ("serve-cells-verify4", 25, 2048, 16, 128, 4, bf16, "default"),
+    ("chip-smoke-f32-highest", 9, 2048, 16, 128, 1, f32, "highest"),
+    ("gpt2-small-bf16", 9, 640, 12, 64, 1, bf16, "default"),
+]
+
+
+@pytest.mark.parametrize("B,L,H,D,W,dt,precision", [c[1:] for c in _DENSE],
+                         ids=[c[0] for c in _DENSE])
+def test_dense_decode_read_compiles(v5e, compiled_paged, B, L, H, D, W, dt,
+                                    precision):
+    """`dense_decode_attention` at the serve cells' shape — 24 slots +
+    scratch x 2048, 16 x 128, bf16, `DENSE_BLOCK` — with a run-time grid
+    bound; and what `chip_smoke.py` and a 12 x 64 model hand it."""
+    assert pa.dense_read_block(heads=H, head_dim=D, dtype=dt, width=W,
+                               max_len=L) == pa.DENSE_BLOCK == 128
+    pool = jax.ShapeDtypeStruct((B, L, H, D), dt)
+    c = _compile(pa.dense_decode_attention, v5e,
+                 jax.ShapeDtypeStruct((B, W, H, D), dt), pool, pool,
+                 jax.ShapeDtypeStruct((B,), jnp.int32), precision=precision)
+    assert _mosaic_calls(c) == 1
+
+
 def test_engine_refuses_what_the_kernel_cannot_take():
     """The one Mosaic limit (the scores row must fit VMEM) is a ValueError
     at construction that names it — not interpret mode, not the XLA read."""

@@ -16,6 +16,11 @@ Covers, all in interpret mode (tier-1 runs on CPU):
 * ``generate(decode_kernel=...)`` passthrough parity.
 * perfscope: the kernel books analytic flops/bytes under its own
   program (XLA's cost_analysis zeroes custom calls).
+* the dense pool's decode read (ISSUE 26): the kernel against the XLA
+  masked read for ragged lengths, a parked row and a row at the buffer's
+  end; the blocks its index map visits against `live_blocks`; routing by
+  backend and pool dtype; the engine's tokens, signature count and
+  `decode_kv_*_positions` through it.
 """
 import time
 
@@ -273,3 +278,132 @@ def test_generate_passthrough(tiny_gpt):
     got = model.generate(ids, max_new_tokens=6, paged_kv=True,
                          page_size=8, decode_kernel="pallas")
     np.testing.assert_array_equal(got, base)
+
+
+# -- the dense pool's decode read (ISSUE 26) ----------------------------------
+
+_L, _P = 32, 8      # max_len, block: four blocks a row
+
+_DENSE_LENGTHS = {
+    # a row at 0, mid-block, at a block boundary, at max_len - 1, parked,
+    # and one more live row after the parked one
+    "ragged": [0, 5, 8, _L - 1, _L, 13],
+    "parked-first": [_L, _L, 3, _L, 20, _L],
+    "all-parked": [_L] * 6,
+    "all-full": [_L - 1] * 6,
+}
+
+
+def _dense_ref(q, k, v, lengths):
+    """models/gpt.py's dense per-slot read, verbatim: the whole buffer
+    under `p <= start + j`, `_sdpa_ref`'s f32 softmax."""
+    B, W, H, D = q.shape
+    cols = lengths[:, None] + jnp.arange(W)[None, :]
+    mask = jnp.arange(k.shape[1])[None, None, :] <= cols[:, :, None]
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    scores = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) / np.sqrt(D)
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    return np.asarray(jnp.swapaxes(
+        jnp.einsum("bhqk,bhkd->bhqd", probs, vt), 1, 2))
+
+
+@pytest.mark.parametrize("lengths", list(_DENSE_LENGTHS.values()),
+                         ids=list(_DENSE_LENGTHS))
+@pytest.mark.parametrize("W", [1, 4])
+def test_dense_kernel_parity(W, lengths):
+    rs = np.random.RandomState(len(lengths) + W)
+    lengths = np.asarray(lengths, np.int32)
+    B, H, D = len(lengths), 2, 16
+    q = jnp.asarray(rs.randn(B, W, H, D).astype(np.float32))
+    k = jnp.asarray(rs.randn(B, _L, H, D).astype(np.float32))
+    v = jnp.asarray(rs.randn(B, _L, H, D).astype(np.float32))
+    pa.use_interpret_mode(True)
+    got = np.asarray(jax.jit(
+        lambda *a: pa.dense_decode_attention(*a, block=_P))(
+            q, k, v, jnp.asarray(lengths)))
+    want = _dense_ref(q, k, v, jnp.asarray(lengths))
+    live = lengths < _L
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    assert not got[~live].any()          # a parked row read nothing: zeros
+
+
+@pytest.mark.parametrize("lengths", list(_DENSE_LENGTHS.values()),
+                         ids=list(_DENSE_LENGTHS))
+@pytest.mark.parametrize("W", [1, 4])
+def test_dense_blocks_visited_are_the_blocks_counted(W, lengths):
+    """`live_blocks` (what the engine's `kv_read` sums) against the pool
+    blocks the kernel's grid holds, step by step: every live block once, a
+    parked row none, and no block fetched twice."""
+    lengths = np.asarray(lengths, np.int32)
+    nb = pa.live_blocks(lengths, W, _L, _P)
+    want = [(r, i) for r, ln in enumerate(lengths) if ln < _L
+            for i in range(min(-(-(int(ln) + W) // _P), _L // _P))]
+    assert int(nb.sum()) == len(want)
+    assert not nb[lengths >= _L].any()
+    held = pa.dense_blocks_held(lengths, W, _L, _P)
+    fetched = [b for j, b in enumerate(held) if j == 0 or b != held[j - 1]]
+    # before the first live block the map stands on block (0, 0)
+    assert [b for b in fetched if b in want] == want
+    assert set(fetched) - set(want) <= {(0, 0)}
+    assert len(fetched) == len(set(fetched))
+
+
+def test_dense_read_routing(monkeypatch):
+    kw = dict(heads=16, head_dim=128, dtype=jnp.bfloat16, width=1,
+              max_len=2048)
+    assert pa.dense_read_block(**kw) is None        # the cpu backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.dense_read_block(**kw) == pa.DENSE_BLOCK
+    assert pa.dense_read_block(**{**kw, "width": 4}) == pa.DENSE_BLOCK
+    assert pa.dense_read_block(**{**kw, "dtype": jnp.int8}) is None
+    assert pa.dense_read_block(**{**kw, "max_len": 2000}) is None
+    assert pa.dense_read_block(**{**kw, "max_len": 64}) == 64
+    assert pa.dense_read_block(**{**kw, "width": 512}) is None   # VMEM
+    monkeypatch.undo()
+    pa.use_interpret_mode(True)                      # a test's pin routes
+    assert pa.dense_read_block(**kw) == pa.DENSE_BLOCK
+
+
+@pytest.mark.parametrize("kv_dtype,spec_k", [(None, 0), (None, 3),
+                                             ("int8", 0)],
+                         ids=["f32-w1", "f32-wk", "int8-keeps-xla"])
+def test_dense_engine_through_the_kernel(tiny_gpt, monkeypatch, kv_dtype,
+                                         spec_k):
+    """The default engine's decode step with the kernel engaged: the same
+    greedy tokens as the XLA read, ONE decode signature, and
+    `decode_kv_read_positions` counts live blocks instead of whole rows."""
+    model, cfg = tiny_gpt
+    prompts = _prompts(cfg, 5, seed=3)
+    kw = dict(max_slots=3, max_len=64, kv_dtype=kv_dtype)
+    if spec_k:
+        kw["speculative_k"] = spec_k
+
+    def run():
+        eng = Engine(model, **kw)
+        try:
+            return _run(eng, prompts), eng.stats(), eng._decode_read_block
+        finally:
+            eng.shutdown()
+
+    base, st0, blk0 = run()
+    assert blk0 is None
+    assert (st0["decode_kv_read_positions"] ==
+            st0["decode_steps"] * 4 * 64)           # 3 slots + scratch
+    monkeypatch.setattr(pa, "DENSE_BLOCK", 16)
+    pa.use_interpret_mode(True)
+    got, st1, blk1 = run()
+    for b, g in zip(base, got):
+        np.testing.assert_array_equal(g, b)
+    assert st1["decode_compiles"] == 1
+    assert st1["decode_kv_live_positions"] == st0["decode_kv_live_positions"]
+    if kv_dtype == "int8":
+        assert blk1 is None
+        assert (st1["decode_kv_read_positions"] ==
+                st0["decode_kv_read_positions"])
+    else:
+        assert blk1 == 16
+        assert (st1["decode_kv_live_positions"]
+                <= st1["decode_kv_read_positions"]
+                < st0["decode_kv_read_positions"] // 2)

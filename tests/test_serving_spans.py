@@ -88,7 +88,7 @@ def _run(model, cfg, tmp, prompts, **engine_kw):
         time.sleep(0.1)
         trace.clear()
         r = types.SimpleNamespace()
-        flight0 = len(flight.events("span_begin"))
+        flight0 = _flight_mark()
         stats0 = eng.stats()
 
         def body():
@@ -104,8 +104,10 @@ def _run(model, cfg, tmp, prompts, **engine_kw):
         stats1 = eng.stats()
         r.delta = {k: stats1[k] - stats0[k]
                    for k in ("prefill_batches", "prefill_tokens",
-                             "prefill_padded_tokens", "decode_steps")}
-        r.flight_spans = flight.events("span_begin")[flight0:]
+                             "prefill_padded_tokens", "decode_steps",
+                             "decode_kv_live_positions",
+                             "decode_kv_read_positions")}
+        r.flight_spans = _span_begins_since(flight0)
         r.ring = trace.spans()
         r.prefill_batch = eng.prefill_batch
         return r
@@ -133,6 +135,16 @@ def hits(tiny_gpt, tmp_path_factory):
         for _ in range(4)]
     return _run(model, cfg, tmp_path_factory.mktemp("hits"), prompts,
                 prefix_cache=True, prefix_block=8)
+
+
+def _flight_mark() -> int:
+    """The newest flight event's seq: `_span_begins_since` reads on from
+    here (an index into the ring would stand still once the ring is full)."""
+    return max((e["seq"] for e in flight.events()), default=0)
+
+
+def _span_begins_since(mark: int) -> list:
+    return [e for e in flight.events("span_begin") if e["seq"] > mark]
 
 
 def _named(events, name):
@@ -204,6 +216,23 @@ def test_prefill_dispatch_counts_agree_with_stats(cold):
     assert all(1 <= e[3]["active"] <= 4 for e in dec)
     roots = _named(cold.events, "serving.iteration")
     assert all({"active", "queued"} <= set(e[3]) for e in roots)
+
+
+@pytest.mark.parametrize("run", ["cold", "hits"])
+def test_decode_dispatch_kv_counts_agree_with_stats(run, request):
+    """ISSUE 26: `kv_live` / `kv_read` on every decode dispatch, the same
+    sums in `Engine.stats()`; on the CPU the XLA read streams every row of
+    the pool whole (4 slots + scratch, 64 positions each)."""
+    r = request.getfixturevalue(run)
+    dec = _named(r.events, "serving.decode.dispatch")
+    assert len(dec) == r.delta["decode_steps"] > 0
+    for e in dec:
+        assert e[3]["kv_read"] == 5 * 64
+        assert e[3]["active"] <= e[3]["kv_live"] <= e[3]["active"] * 64
+    assert (sum(e[3]["kv_live"] for e in dec) ==
+            r.delta["decode_kv_live_positions"])
+    assert (sum(e[3]["kv_read"] for e in dec) ==
+            r.delta["decode_kv_read_positions"])
 
 
 def test_tail_dispatch_counts_agree_with_stats(hits):
@@ -312,7 +341,7 @@ def test_span_is_a_profiler_annotation(tmp_path):
 
 def test_phase_keeps_out_of_the_flight_ring_and_can_be_dropped():
     trace.clear()
-    n0 = len(flight.events("span_begin"))
+    n0 = _flight_mark()
     with trace.phase("t25.root") as root:
         with trace.phase("t25.leaf"):
             pass
@@ -324,7 +353,7 @@ def test_phase_keeps_out_of_the_flight_ring_and_can_be_dropped():
     assert [r["name"] for r in trace.spans()] == ["t25.leaf", "t25.loud",
                                                   "t25.root"]
     assert trace.spans("t25.leaf")[0]["parent_id"] == root.id
-    begun = [e["name"] for e in flight.events("span_begin")[n0:]]
+    begun = [e["name"] for e in _span_begins_since(n0)]
     assert begun == ["t25.loud"]
     trace.clear()
     with trace.phase("t25.root") as root:
@@ -347,7 +376,7 @@ def test_phase_keeps_out_of_the_flight_ring_and_can_be_dropped():
     def f():
         return 1
     assert f() == 1 and trace.spans("t25.decorated")
-    assert not [e for e in flight.events("span_begin")[n0:]
+    assert not [e for e in _span_begins_since(n0)
                 if e["name"] == "t25.decorated"]
 
 
@@ -363,3 +392,21 @@ def test_readers_count_padding_from_the_trace(cold):
     assert span_readers.idle_under(obs, ["serving.decode.emit"]) is None
     s = span_readers.split(cold.path)
     assert s["count"]["serving.decode.fetch"] == cold.delta["decode_steps"]
+
+
+def test_readers_count_dead_kv_from_the_trace(cold):
+    """`engine.decode_kv_dead_share.*`: the metric files' reader and args."""
+    import json
+    from benchmark import span_readers
+    for cell in ("steady", "saturated"):
+        with open(os.path.join(
+                os.path.dirname(span_readers.__file__), "metrics",
+                f"engine.decode_kv_dead_share.{cell}.json")) as f:
+            spec = json.load(f)
+        assert spec["reader"] == "span_readers.stat_complement_pct"
+        got = span_readers.stat_complement_pct(
+            {"span_trace_path": cold.path}, **spec["args"])
+        assert got == pytest.approx(
+            100.0 * (1 - cold.delta["decode_kv_live_positions"] /
+                     cold.delta["decode_kv_read_positions"]))
+        assert 50.0 < got < 100.0
