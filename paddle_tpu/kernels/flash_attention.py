@@ -104,7 +104,7 @@ def _tq_contract(a, b):
     return _mxu(a, b, ((1,), (1,)))
 
 
-def _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols):
+def _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols, window=None):
     """None when no masking is needed (interior tile, no kv padding)."""
     mask = None
     if pad_cols:                # kv padding exists: mask the dead columns
@@ -114,8 +114,28 @@ def _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols):
         col = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cm = col <= row + offset
+        if window is not None:  # query t reads keys t - window + 1 .. t
+            cm &= col > row + (offset - window)
         mask = cm if mask is None else (mask & cm)
     return None if mask is None else mask[None]  # broadcast over head dim
+
+
+def _tile_live(i, j, bq, bk, causal, offset, window=None):
+    """Whether tile (i, j) holds any unmasked pair: not strictly above the
+    causal diagonal band and, with a window, not wholly before it."""
+    if not causal:
+        return True
+    live = j * bk <= i * bq + (bq - 1) + offset
+    if window is not None:
+        live &= j * bk + (bk - 1) > i * bq + (offset - window)
+    return live
+
+
+def _same_heads(q, k):
+    """K or V tile of a grouped-query call: the group's one KV head,
+    broadcast to the query heads of the block."""
+    return k if k.shape[0] == q.shape[0] else jnp.broadcast_to(
+        k, (q.shape[0],) + k.shape[1:])
 
 
 # -- forward ------------------------------------------------------------------
@@ -129,22 +149,24 @@ def _rld(ref):
 
 
 def _scaled_scores(q, k, i, j, *, scale, causal, offset, bq, bk,
-                   pad_cols, t_real):
+                   pad_cols, t_real, window=None):
     """Masked scaled scores for one tile.  The scale folds into the small
     (nb,bq,d) q operand instead of the (nb,bq,bk) score tile — 16x fewer
     VPU multiplies at d=64."""
     q = (q.astype(jnp.float32) * jnp.float32(scale)).astype(q.dtype)
     s = _qk(q, k)
-    mask = _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols)
+    mask = _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols, window)
     if mask is not None:
         s = jnp.where(mask, s, jnp.float32(_NEG_INF))
     return s
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                scale, causal, offset, bq, bk, nk, t_real, pad_cols):
+                scale, causal, offset, bq, bk, nk, t_real, pad_cols,
+                window=None):
     i, j = pl.program_id(1), pl.program_id(2)
-    qv, kv, vv = _rld(q_ref), _rld(k_ref), _rld(v_ref)
+    qv = _rld(q_ref)
+    kv, vv = _same_heads(qv, _rld(k_ref)), _same_heads(qv, _rld(v_ref))
 
     if nk == 1:
         # no scratch is declared for the one-pass path (scratch == ())
@@ -152,7 +174,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         # rescaling carry (alpha, running m/l broadcasts) is dead weight
         s = _scaled_scores(qv, kv, i, j, scale=scale, causal=causal,
                            offset=offset, bq=bq, bk=bk, pad_cols=pad_cols,
-                           t_real=t_real)
+                           t_real=t_real, window=window)
         m = jnp.max(s, axis=2, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.maximum(jnp.sum(p, axis=2, keepdims=True),
@@ -170,16 +192,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         l_i[:] = jnp.zeros_like(l_i)
         acc[:] = jnp.zeros_like(acc)
 
-    live = True
-    if causal:
-        # kv block strictly above the diagonal band → nothing to do
-        live = j * bk <= i * bq + (bq - 1) + offset
-
-    @pl.when(live)
+    # a kv block strictly above the diagonal band, or wholly before the
+    # window, has nothing to do
+    @pl.when(_tile_live(i, j, bq, bk, causal, offset, window))
     def _compute():
         s = _scaled_scores(qv, kv, i, j, scale=scale, causal=causal,
                            offset=offset, bq=bq, bk=bk, pad_cols=pad_cols,
-                           t_real=t_real)
+                           t_real=t_real, window=window)
         m_prev = m_i[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -196,12 +215,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         lse_ref[...] = m_i[:, :, :1] + jnp.log(l)
 
 
-def _flash_fwd(q, k, v, scale, causal):
-    """q,k,v: [BH, T, D] → (out [BH,Tq,D], lse [BH,Tq,1])."""
+def _flash_fwd(q, k, v, scale, causal, window=None):
+    """q: [BH, Tq, D], k, v: [BH / group, Tk, D] → (out [BH,Tq,D], lse
+    [BH,Tq,1]).  With group > 1 (grouped-query attention) a grid step holds
+    one KV head and the `group` query heads that read it: the K and V tiles
+    are fetched once for all of them."""
     bh, tq, d = q.shape
     tk = k.shape[1]
+    group = bh // k.shape[0]
     bq, bk = _block_sizes(tq, tk)
-    nb = _head_block(bh, bq, bk)
+    if group > 1:
+        nb = group
+        while nb * bq * bk * 4 > 16 * 1024 * 1024 and bq > 128:
+            bq //= 2        # the f32 score tile of the whole group in VMEM
+    else:
+        nb = _head_block(bh, bq, bk)
+    nkv = nb // group       # KV heads per grid step
     qp = _pad_to(q, 1, bq)
     kp = _pad_to(k, 1, bk)
     vp = _pad_to(v, 1, bk)
@@ -211,14 +240,15 @@ def _flash_fwd(q, k, v, scale, causal):
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, offset=offset,
-        bq=bq, bk=bk, nk=nk, t_real=tk, pad_cols=(tkp != tk))
+        bq=bq, bk=bk, nk=nk, t_real=tk, pad_cols=(tkp != tk), window=window)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh // nb, nq, nk),
         in_specs=[
             pl.BlockSpec((nb, bq, d), lambda b, i, j: (b, i, j * 0)),
-            pl.BlockSpec((nb, bk, d), lambda b, i, j: (b, j, i * 0)),
-            pl.BlockSpec((nb, bk, d), lambda b, i, j: (b, j, i * 0)),
+            pl.BlockSpec((nkv, bk, d), lambda b, i, j: (b, j, i * 0)),
+            pl.BlockSpec((nkv, bk, d), lambda b, i, j: (b, j, i * 0)),
         ],
         out_specs=[
             pl.BlockSpec((nb, bq, d), lambda b, i, j: (b, i, j * 0)),
@@ -245,7 +275,8 @@ def _flash_fwd(q, k, v, scale, causal):
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *out_refs, scale, causal, offset,
-                      bq, bk, t_real, pad_cols, fused_out=False):
+                      bq, bk, t_real, pad_cols, fused_out=False,
+                      window=None):
     """Single-tile backward (nq == nk == 1): dq, dk, dv in one pass sharing
     one recomputation of s/p — the two-kernel split exists only to give
     each output a sequential accumulation dimension, which a single tile
@@ -256,7 +287,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     do = do_ref[...]
     qs = (q.astype(jnp.float32) * jnp.float32(scale)).astype(q.dtype)
     s = _qk(qs, k)
-    mask = _tile_mask(0, 0, bq, bk, causal, offset, t_real, pad_cols)
+    mask = _tile_mask(0, 0, bq, bk, causal, offset, t_real, pad_cols, window)
     if mask is not None:
         s = jnp.where(mask, s, jnp.float32(_NEG_INF))
     p = jnp.exp(s - lse_ref[...])
@@ -281,24 +312,20 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale, causal, offset, bq, bk, nk, t_real,
-                   pad_cols):
+                   pad_cols, window=None):
     i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    live = True
-    if causal:
-        live = j * bk <= i * bq + (bq - 1) + offset
-
-    @pl.when(live)
+    @pl.when(_tile_live(i, j, bq, bk, causal, offset, window))
     def _compute():
         q, k, v = _rld(q_ref), _rld(k_ref), _rld(v_ref)
         do = do_ref[...]
         s = _scaled_scores(q, k, i, j, scale=scale, causal=causal,
                            offset=offset, bq=bq, bk=bk, pad_cols=pad_cols,
-                           t_real=t_real)
+                           t_real=t_real, window=window)
         p = jnp.exp(s - lse_ref[...])
         dp = _qk(do, v)                    # (nb, bq, bk)
         ds = p * (dp - delta_ref[...])     # scale folds into k below
@@ -312,7 +339,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, offset, bq, bk, nq, t_real, pad_cols):
+                    scale, causal, offset, bq, bk, nq, t_real, pad_cols,
+                    window=None):
     j, i = pl.program_id(1), pl.program_id(2)  # j: kv block, i: q block
 
     @pl.when(i == 0)
@@ -320,17 +348,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = True
-    if causal:
-        live = j * bk <= i * bq + (bq - 1) + offset
-
-    @pl.when(live)
+    @pl.when(_tile_live(i, j, bq, bk, causal, offset, window))
     def _compute():
         q, k, v = _rld(q_ref), _rld(k_ref), _rld(v_ref)
         do = do_ref[...]
         qs = (q.astype(jnp.float32) * jnp.float32(scale)).astype(q.dtype)
         s = _qk(qs, k)
-        mask = _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols)
+        mask = _tile_mask(i, j, bq, bk, causal, offset, t_real, pad_cols,
+                          window)
         if mask is not None:
             s = jnp.where(mask, s, jnp.float32(_NEG_INF))
         p = jnp.exp(s - lse_ref[...])
@@ -345,8 +370,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, do, scale, causal):
+def _flash_bwd(q, k, v, o, lse, do, scale, causal, window=None):
     bh, tq, d = q.shape
+    group = bh // k.shape[0]
+    if group > 1:
+        # grouped-query heads: the kernels below hold one KV head per query
+        # head, so K and V are repeated going in and their gradients summed
+        # over each group coming out (serving never differentiates; a
+        # training path for grouped heads would fold this into the kernels)
+        dq, dk, dv = _flash_bwd(q, jnp.repeat(k, group, axis=0),
+                                jnp.repeat(v, group, axis=0), o, lse, do,
+                                scale, causal, window)
+        fold = lambda g: g.reshape(  # noqa: E731
+            (bh // group, group) + g.shape[1:]).sum(axis=1).astype(k.dtype)
+        return dq, fold(dk), fold(dv)
     tk = k.shape[1]
     bq, bk = _block_sizes(tq, tk)
     nb = _head_block(bh, bq, bk)
@@ -366,7 +403,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal):
     if nq == 1 and nk == 1:
         fused = functools.partial(
             _bwd_fused_kernel, scale=scale, causal=causal, offset=offset,
-            bq=bq, bk=bk, t_real=tk, pad_cols=(tkp != tk))
+            bq=bq, bk=bk, t_real=tk, pad_cols=(tkp != tk), window=window)
         # one score tile per invocation: halve the head block vs the
         # split kernels' budget since dq/dk/dv tiles coexist in VMEM
         nbf = max(1, _head_block(bh, bq, bk) // 2)
@@ -377,6 +414,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal):
         kmap = lambda b, i, j: (b, j, i * 0)       # noqa: E731
         dq, dk, dv = pl.pallas_call(
             fused,
+            name="flash_bwd",
             grid=(bh // nbf, 1, 1),
             in_specs=[
                 pl.BlockSpec((nbf, bq, d), qmap),
@@ -405,9 +443,10 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal):
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, offset=offset,
-        bq=bq, bk=bk, nk=nk, t_real=tk, pad_cols=(tkp != tk))
+        bq=bq, bk=bk, nk=nk, t_real=tk, pad_cols=(tkp != tk), window=window)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd",
         grid=(bh // nb, nq, nk),
         in_specs=[
             pl.BlockSpec((nb, bq, d), lambda b, i, j: (b, i, j * 0)),
@@ -428,9 +467,10 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal):
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, offset=offset,
-        bq=bq, bk=bk, nq=nq, t_real=tk, pad_cols=(tkp != tk))
+        bq=bq, bk=bk, nq=nq, t_real=tk, pad_cols=(tkp != tk), window=window)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd",
         grid=(bh // nb, nk, nq),
         in_specs=[
             pl.BlockSpec((nb, bq, d), lambda b, j, i: (b, i, j * 0)),
@@ -496,6 +536,7 @@ def _flash_fused_fwd_impl(qkv, scale, causal):
         bq=bq, bk=bk, nk=nk, t_real=t, pad_cols=(tp != t))
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh // nb, nq, nk),
         in_specs=_role_specs(nb, bq, bk, d),
         out_specs=[
@@ -540,6 +581,7 @@ def _flash_fused_bwd_impl(qkv, o, lse, do, scale, causal):
         qmap3 = lambda b, i, j: (b, i, j * 0)      # noqa: E731
         dqkv = pl.pallas_call(
             fused,
+            name="flash_bwd",
             grid=(bh // nbf, 1, 1),
             in_specs=_role_specs(nbf, bq, bk, d) + [
                 pl.BlockSpec((nbf, bq, d), qmap3),
@@ -593,20 +635,20 @@ def flash_attention_qkv_fused(qkv, causal=True, scale=None):
 
 # -- custom_vjp glue ----------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, scale, causal):
-    out, _ = _flash_fwd(q, k, v, scale, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, scale, causal, window):
+    out, _ = _flash_fwd(q, k, v, scale, causal, window)
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, causal):
-    out, lse = _flash_fwd(q, k, v, scale, causal)
+def _flash_fwd_rule(q, k, v, scale, causal, window):
+    out, lse = _flash_fwd(q, k, v, scale, causal, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, res, do):
+def _flash_bwd_rule(scale, causal, window, res, do):
     q, k, v, out, lse = res
-    return _flash_bwd(q, k, v, out, lse, do, scale, causal)
+    return _flash_bwd(q, k, v, out, lse, do, scale, causal, window)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -614,25 +656,33 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 # -- public API ---------------------------------------------------------------
 
-def flash_attention_bhtd(q, k, v, causal=True, scale=None):
-    """q,k,v: [BH or (B,H), T, D] jax arrays, 3D."""
+def flash_attention_bhtd(q, k, v, causal=True, scale=None, window=None):
+    """q: [BH, T, D]; k, v: [BH / group, T, D] (group 1, or grouped-query
+    heads: query head i reads KV head i // group).  `window` (causal only)
+    keeps keys t - window + 1 .. t for query t; tiles wholly before the
+    window are skipped like those above the diagonal."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, float(scale), bool(causal))
+    if window is not None and not causal:
+        raise ValueError("window= needs causal=True")
+    return _flash(q, k, v, float(scale), bool(causal),
+                  None if window is None else int(window))
 
 
-def flash_attention_bthd(q, k, v, causal=True, scale=None):
-    """Paddle fused-op layout [B, T, H, D] (Tensor or jax.Array in/out)."""
+def flash_attention_bthd(q, k, v, causal=True, scale=None, window=None):
+    """Paddle fused-op layout [B, T, H, D] (Tensor or jax.Array in/out);
+    k, v may hold fewer heads (a divisor of q's)."""
     from ..core.op import apply_op
     from ..core.tensor import Tensor
 
     def raw(qv, kv, vv):
         b, tq, h, d = qv.shape
-        tk = kv.shape[1]
+        tk, hk = kv.shape[1], kv.shape[2]
         q3 = jnp.transpose(qv, (0, 2, 1, 3)).reshape(b * h, tq, d)
-        k3 = jnp.transpose(kv, (0, 2, 1, 3)).reshape(b * h, tk, d)
-        v3 = jnp.transpose(vv, (0, 2, 1, 3)).reshape(b * h, tk, d)
-        o3 = flash_attention_bhtd(q3, k3, v3, causal=causal, scale=scale)
+        k3 = jnp.transpose(kv, (0, 2, 1, 3)).reshape(b * hk, tk, d)
+        v3 = jnp.transpose(vv, (0, 2, 1, 3)).reshape(b * hk, tk, d)
+        o3 = flash_attention_bhtd(q3, k3, v3, causal=causal, scale=scale,
+                                  window=window)
         return jnp.transpose(o3.reshape(b, h, tq, d), (0, 2, 1, 3))
 
     if isinstance(q, Tensor):

@@ -377,29 +377,44 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
 DENSE_BLOCK = 128
 
 
-def live_blocks(lengths, width: int, max_len: int, block: int):
+def first_block(lengths, block: int, window=None):
+    """The first block a row's read needs: 0, or on a sliding-window layer
+    the block that holds position ``length - window + 1``, the oldest key
+    the row's first new query still reads."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    lengths = xp.asarray(lengths, xp.int32)
+    if window is None:
+        return xp.zeros_like(lengths)
+    return (xp.maximum(lengths - (window - 1), 0) // block).astype(xp.int32)
+
+
+def live_blocks(lengths, width: int, max_len: int, block: int, window=None):
     """Blocks of ``block`` positions the dense read streams for each row:
-    those that hold positions ``0 .. length + width - 1``, none for a parked
-    row (``length >= max_len``).  The kernel's work list is built from this
+    those that hold positions ``0 .. length + width - 1`` (from
+    :func:`first_block` on with a ``window``), none for a parked row
+    (``length >= max_len``).  The kernel's work list is built from this
     count and the engine's ``decode_kv_read_positions`` sums it (numpy in,
     numpy out; jax in, jax out)."""
     xp = jnp if isinstance(lengths, jax.Array) else np
     lengths = xp.asarray(lengths, xp.int32)
     n = xp.minimum((lengths + (width + block - 1)) // block,
-                   max_len // block)
+                   max_len // block) - first_block(lengths, block, window)
     return xp.where(lengths >= max_len, 0, n).astype(xp.int32)
 
 
-def _work_list(nb, n_blk: int):
+def _work_list(nb, n_blk: int, first=None):
     """The grid as a list of steps: one per live block, and one for a
     parked row (it only writes the row's zeros).  Returns ``(steps, row,
     blk, held)``: how many steps there are, and per step its row, its block
-    within the row, and the flat index ``row * n_blk + block`` of the pool
-    block it holds — a parked row's step keeps the block of the step before
-    it (an unchanged block index is not fetched again).  Entries past
-    ``steps`` are unused."""
+    within the row's live run (which starts at block ``first[row]``), and
+    the flat index ``row * n_blk + block`` of the pool block it holds — a
+    parked row's step keeps the block of the step before it (an unchanged
+    block index is not fetched again).  Entries past ``steps`` are
+    unused."""
     xp = jnp if isinstance(nb, jax.Array) else np
     B = nb.shape[0]
+    if first is None:
+        first = xp.zeros_like(nb)
     per_row = xp.maximum(nb, 1)
     ends = xp.cumsum(per_row, dtype=xp.int32)
     t = xp.arange(B * n_blk, dtype=xp.int32)
@@ -408,44 +423,52 @@ def _work_list(nb, n_blk: int):
     row = xp.minimum((t[:, None] >= ends[None, :]).sum(axis=1),
                      B - 1).astype(xp.int32)
     blk = t - (ends - per_row)[row]
-    flat = xp.where(blk < nb[row], row * n_blk + blk, 0)
+    flat = xp.where(blk < nb[row], row * n_blk + first[row] + blk, 0)
     held = (jax.lax.cummax(flat) if xp is jnp
             else np.maximum.accumulate(flat))
     return ends[-1], row, blk.astype(xp.int32), held.astype(xp.int32)
 
 
-def dense_blocks_held(lengths, width: int, max_len: int, block: int):
+def dense_blocks_held(lengths, width: int, max_len: int, block: int,
+                      window=None):
     """The ``(row, block)`` of the pool the kernel's grid holds at each step,
     in grid order: what the K/V index map reads, evaluated on the host.  A
     step whose block differs from the step before is a fetch (the tests
     count them against :func:`live_blocks`)."""
     n_blk = max_len // block
+    lengths = np.asarray(lengths)
     steps, _, _, held = _work_list(
-        live_blocks(np.asarray(lengths), width, max_len, block), n_blk)
+        live_blocks(lengths, width, max_len, block, window), n_blk,
+        first_block(lengths, block, window))
     return [divmod(int(f), n_blk) for f in held[:int(steps)]]
 
 
 def dense_read_block(*, heads: int, head_dim: int, dtype, width: int,
-                     max_len: int):
+                     max_len: int, kv_heads=None):
     """The block size at which the dense pool's decode read goes through
     the kernel, or ``None`` where it keeps the XLA read: on the ``cpu``
     backend (unless a test pinned the mode), for an int8 pool, for a
     ``max_len`` the block does not divide, or for a span so wide that the
-    ``[width * heads, block * heads]`` scores and the double-buffered K and
-    V blocks would not fit VMEM."""
+    ``[width * heads, block * kv_heads]`` scores and the double-buffered K
+    and V blocks would not fit VMEM.  A pool of grouped-query heads
+    (``kv_heads < heads``) holds ``1 / group`` the bytes per position, so
+    its block is ``DENSE_BLOCK`` times the largest power of two in the
+    group: a step then streams about the bytes ``DENSE_BLOCK`` was timed
+    at."""
     if _INTERPRET is None and jax.default_backend() == "cpu":
         return None
     if jnp.dtype(dtype) == jnp.int8:
         return None
-    P = min(DENSE_BLOCK, max_len)
-    vmem = (4 * width * heads * P * heads * 4
-            + 4 * P * heads * head_dim * jnp.dtype(dtype).itemsize)
+    kv_heads = heads if kv_heads is None else kv_heads
+    P = min(DENSE_BLOCK << ((heads // kv_heads).bit_length() - 1), max_len)
+    vmem = (4 * width * heads * P * kv_heads * 4
+            + 4 * P * kv_heads * head_dim * jnp.dtype(dtype).itemsize)
     return None if max_len % P or 2 * vmem > _VMEM_LIMIT_BYTES else P
 
 
-def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, q_ref, k_ref,
-                  v_ref, col_ref, qrow_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  P, W, H, scale):
+def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, first_ref,
+                  q_ref, k_ref, v_ref, col_ref, qrow_ref, o_ref, m_ref,
+                  l_ref, acc_ref, *, P, W, H, Hkv, scale, window):
     t = pl.program_id(0)
     r, i = row_ref[t], blk_ref[t]
     n = nb_ref[r]
@@ -459,23 +482,32 @@ def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, q_ref, k_ref,
 
     @pl.when(i < n)
     def _block():
-        k2 = k_ref[0].reshape(P * H, D)
-        v2 = v_ref[0].reshape(P * H, D)
+        k2 = k_ref[0].reshape(P * Hkv, D)
+        v2 = v_ref[0].reshape(P * Hkv, D)
         q2 = q_ref[0].reshape(W * H, D).astype(k2.dtype)
-        # [W*H, P*H]: query (w, h') against key (p, h); only h' == h counts
+        # [W*H, P*Hkv]: query (w, h') against key (p, h); only the pairs
+        # whose query head reads that KV head count (h' // group == h)
         s = jax.lax.dot_general(
             q2, k2, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(scale)
-        pos, head = col_ref[0:1, :], col_ref[1:2, :]          # [1, P*H]
+        pos, head = col_ref[0:1, :], col_ref[1:2, :]          # [1, P*Hkv]
         qw, qhead = qrow_ref[:, 0:1], qrow_ref[:, 1:2]        # [W*H, 1]
-        keep = (head == qhead) & (i * P + pos <= len_ref[r] + qw)
+        pos = (first_ref[r] + i) * P + pos
+        keep = (head == qhead) & (pos <= len_ref[r] + qw)
+        if window is not None:
+            keep &= pos > len_ref[r] + qw - window
         s = jnp.where(keep, s, jnp.float32(_NEG_INF))
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # a dropped pair underflows to exactly 0: m_new is finite from block
-        # 0 on (position 0 is live for every query of a live row)
+        # 0 on (position 0 is live for every query of a live row).  On a
+        # window layer the first block can lie wholly before a later
+        # query's window: its m_new stays at the floor, where exp(0) would
+        # count the dropped pairs
         p = jnp.exp(s - m_new)
+        if window is not None:
+            p = jnp.where(keep, p, 0.0)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
             p.astype(v2.dtype), v2, (((1,), (0,)), ((), ())),
@@ -492,15 +524,20 @@ def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, q_ref, k_ref,
 
 
 def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
-                           scale=None):
+                           scale=None, window=None):
     """Per-slot decode attention over the dense pool, streaming live blocks
     only.
 
     Args:
         q: ``[n_rows, W, heads, head_dim]`` queries of the step's new
             positions ``length .. length + W - 1``.
-        k_pool / v_pool: ``[n_rows, max_len, heads, head_dim]`` float
-            pools, **post-write** like :func:`paged_decode_attention`.
+        k_pool / v_pool: ``[n_rows, max_len, kv_heads, head_dim]`` float
+            pools, **post-write** like :func:`paged_decode_attention`;
+            ``kv_heads`` divides ``heads`` (query head i reads KV head
+            ``i // group``; a block is read once for its whole group).
+        window: sliding-window layers: a query at position p reads keys
+            ``p - window + 1 .. p``; the work list starts at the block that
+            holds the first of them, the mask is exact inside it.
         lengths: ``[n_rows]`` int32 start positions; a parked row sits at
             ``max_len`` and reads nothing.
         block: positions per block (default :data:`DENSE_BLOCK`, at most
@@ -511,7 +548,9 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
         row).
     """
     B, W, H, D = q.shape
-    L = k_pool.shape[1]
+    L, Hkv = k_pool.shape[1], k_pool.shape[2]
+    if H % Hkv:
+        raise ValueError(f"{H} query heads are no multiple of {Hkv} KV heads")
     P = min(DENSE_BLOCK, L) if block is None else int(block)
     if L % P:
         raise ValueError(f"block={P} does not divide max_len={L}")
@@ -519,45 +558,50 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     lengths = jnp.asarray(lengths, jnp.int32)
-    nb = live_blocks(lengths, W, L, P)
-    steps, row, blk, held = _work_list(nb, n_blk)
-    # which (position, head) a column of the scores is, and which (query,
-    # head) a row: int32 operands, fetched once (their block never moves)
-    cols = jnp.stack([jnp.repeat(jnp.arange(P, dtype=jnp.int32), H),
-                      jnp.tile(jnp.arange(H, dtype=jnp.int32), P)])
+    nb = live_blocks(lengths, W, L, P, window)
+    first = first_block(lengths, P, window)
+    steps, row, blk, held = _work_list(nb, n_blk, first)
+    # which (position, KV head) a column of the scores is, and which (query,
+    # KV head it reads) a row: int32 operands, fetched once (their block
+    # never moves)
+    cols = jnp.stack([jnp.repeat(jnp.arange(P, dtype=jnp.int32), Hkv),
+                      jnp.tile(jnp.arange(Hkv, dtype=jnp.int32), P)])
     qrows = jnp.stack([jnp.repeat(jnp.arange(W, dtype=jnp.int32), H),
-                       jnp.tile(jnp.arange(H, dtype=jnp.int32), W)], axis=1)
+                       jnp.tile(jnp.arange(H, dtype=jnp.int32) // (H // Hkv),
+                                W)], axis=1)
     # index maps run on the scalar core: explicit int32 throughout (x64 is
     # on), and `t * 0` for a zero
 
-    def _kvmap(t, ln, nbr, rw, bk, hd):
+    def _kvmap(t, ln, nbr, rw, bk, hd, fb):
         nblk = jnp.int32(n_blk)
         return (jax.lax.div(hd[t], nblk), jax.lax.rem(hd[t], nblk),
                 t * 0, t * 0)
 
-    def _qmap(t, ln, nbr, rw, bk, hd):
+    def _qmap(t, ln, nbr, rw, bk, hd, fb):
         return (rw[t], t * 0, t * 0, t * 0)
 
     def _const(t, *_):
         return (t * 0, t * 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(steps,),
         in_specs=[pl.BlockSpec((1, W, H, D), _qmap),
-                  pl.BlockSpec((1, P, H, D), _kvmap),
-                  pl.BlockSpec((1, P, H, D), _kvmap),
-                  pl.BlockSpec((2, P * H), _const),
+                  pl.BlockSpec((1, P, Hkv, D), _kvmap),
+                  pl.BlockSpec((1, P, Hkv, D), _kvmap),
+                  pl.BlockSpec((2, P * Hkv), _const),
                   pl.BlockSpec((W * H, 2), _const)],
         out_specs=pl.BlockSpec((1, W, H, D), _qmap),
         scratch_shapes=[pltpu.VMEM((W * H, 1), jnp.float32),   # running max
                         pltpu.VMEM((W * H, 1), jnp.float32),   # running sum
                         pltpu.VMEM((W * H, D), jnp.float32)],  # accumulator
     )
-    kernel = functools.partial(_dense_kernel, P=P, W=W, H=H,
-                               scale=float(scale))
+    kernel = functools.partial(
+        _dense_kernel, P=P, W=W, H=H, Hkv=Hkv, scale=float(scale),
+        window=None if window is None else int(window))
     return pl.pallas_call(
         kernel,
+        name="dense_decode_read",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -565,4 +609,4 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret_now(),
-    )(lengths, nb, row, blk, held, q, k_pool, v_pool, cols, qrows)
+    )(lengths, nb, row, blk, held, first, q, k_pool, v_pool, cols, qrows)

@@ -13,6 +13,14 @@ from .bert import (  # noqa: F401
     build_bert,
     build_ernie,
 )
+from .decoder import (  # noqa: F401
+    DECODER_CONFIGS,
+    DecoderConfig,
+    DecoderForCausalLM,
+    DecoderModel,
+    build_decoder,
+    decoder_config,
+)
 from .gpt import (  # noqa: F401
     GPT_CONFIGS,
     GPTConfig,

@@ -269,246 +269,19 @@ class GPTSelfAttention(Layer):
                 qkv, dropout_p=self.attn_dropout_prob, is_causal=True,
                 training=self.training)
         else:
+            # every cache form (none, growing concat, static, per-slot,
+            # int8, paged) lives in models/kv_cache.py, shared with
+            # models/decoder.py
+            from .kv_cache import cached_attention
             q, k, v = (qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2])
-            new_cache = None
-            if cache is not None and len(cache) in (3, 4, 5, 6):
-                # STATIC cache (k_buf [B,L,nh,hd], v_buf, length): write the
-                # new keys/values in place at `length` and attend over the
-                # fixed-shape buffer under an explicit validity mask — every
-                # decode step is ONE compiled program with donated buffers
-                # (the AnalysisPredictor zero-copy run analog,
-                # analysis_predictor.cc:1618), instead of a concat that
-                # gives each position its own XLA shape.
-                # The 5-tuple form (k_buf, v_buf, length, k_scale, v_scale)
-                # is the int8-quantized pool (serving kv_dtype="int8"):
-                # buffers store int8, scales [B, L] carry one absmax scale
-                # per cached row; writes quantize, the attention read
-                # dequantizes inline (kv_quant helpers).
-                # The PAGED forms (serving paged_kv=True) add an int32
-                # page table at index 3: 4-tuple (k_pages, v_pages,
-                # lengths, page_table) and 6-tuple (..., k_scale,
-                # v_scale).  K/V live as [num_pages, page_size, heads,
-                # head_dim] pages; position p of row b maps to
-                # pages[page_table[b, p // P], p % P].  Writes scatter
-                # through the table (sentinel/out-of-range entries DROP
-                # — unallocated virtual positions are unwritable), reads
-                # gather the row's pages back into a [B, L_virt, ...]
-                # view under the same validity mask as the dense pool —
-                # the page table is just one more fixed-shape operand,
-                # so decode keeps its ONE compiled signature.
-                import jax.numpy as jnp
-
-                from ..core.tensor import Tensor as _T
-                k_buf, v_buf, pos0 = cache[0], cache[1], cache[2]
-                quantized = len(cache) in (5, 6)
-                paged = len(cache) in (4, 6)
-                k_raw = k_buf._value if isinstance(k_buf, _T) else k_buf
-                v_raw = v_buf._value if isinstance(v_buf, _T) else v_buf
-                start = jnp.asarray(pos0, jnp.int32)
-                if (quantized or paged) and start.ndim != 1:
-                    raise ValueError(
-                        "int8 (5/6-tuple) and paged (4/6-tuple) KV "
-                        "caches are supported only in the per-slot "
-                        "vector-length form the serving engine uses")
-                if start.ndim == 1:
-                    # PER-SLOT lengths (continuous batching, serving.Engine):
-                    # `pos0` is a [B] vector — every row owns a slot in a
-                    # shared pool and sits at its own position, so the new
-                    # keys/values scatter to per-row offsets and attention
-                    # runs under a per-row validity mask.  Rows whose write
-                    # would fall off the buffer end (an inactive slot parked
-                    # at max_len) are dropped by the scatter, never clipped
-                    # onto a live row.  t may be > 1 (speculative
-                    # verification / prefix-tail prefill): position j of a
-                    # row writes at its own offset + j and attends causally
-                    # within the new span.
-                    scale_i = 4 if paged else 3
-                    att_out = None
-                    if quantized:
-                        from ..serving.kv_quant import (dequantize_pool,
-                                                        quantize_rows)
-                        ks_raw, vs_raw = cache[scale_i], cache[scale_i + 1]
-                        ks_raw = (ks_raw._value if isinstance(ks_raw, _T)
-                                  else ks_raw)
-                        vs_raw = (vs_raw._value if isinstance(vs_raw, _T)
-                                  else vs_raw)
-                        kq, ksc = quantize_rows(k._value)
-                        vq, vsc = quantize_rows(v._value)
-                    if paged:
-                        # gather/scatter through the page table: position
-                        # p of row r lives at pages[table[r, p // P],
-                        # p % P].  Sentinel table entries (>= num_pages)
-                        # make the scatter DROP (an unallocated or
-                        # parked position is unwritable) and gather a
-                        # clamped garbage page that the validity mask
-                        # excludes from attention.
-                        pt = cache[3]
-                        pt = pt._value if isinstance(pt, _T) else pt
-                        pt = jnp.asarray(pt, jnp.int32)
-                        n_pages, psz = k_raw.shape[0], k_raw.shape[1]
-                        n_pt = pt.shape[1]
-                        virt = n_pt * psz
-                        rows = jnp.arange(pt.shape[0])[:, None]
-                        cols = start[:, None] + jnp.arange(t)[None, :]
-                        pslot = jnp.clip(cols // psz, 0, n_pt - 1)
-                        pid = jnp.where(cols < virt, pt[rows, pslot],
-                                        n_pages)
-                        off = cols % psz
-                        if quantized:
-                            k_raw = k_raw.at[pid, off].set(kq, mode="drop")
-                            v_raw = v_raw.at[pid, off].set(vq, mode="drop")
-                            ks_raw = ks_raw.at[pid, off].set(ksc,
-                                                             mode="drop")
-                            vs_raw = vs_raw.at[pid, off].set(vsc,
-                                                             mode="drop")
-                        else:
-                            k_raw = k_raw.at[pid, off].set(
-                                k._value.astype(k_raw.dtype), mode="drop")
-                            v_raw = v_raw.at[pid, off].set(
-                                v._value.astype(v_raw.dtype), mode="drop")
-                        # serving decode with Engine(decode_kernel=
-                        # "pallas"): the attention READ runs as the fused
-                        # Pallas kernel — page-table walk + (int8) dequant
-                        # + masked softmax in one custom call, no
-                        # [B, virt, ...] gather temp.  The write scatter
-                        # above is unchanged, so the kernel attends over
-                        # the post-write pool exactly like the XLA read.
-                        from ..kernels.paged_attention import (
-                            active as _paged_kernel_active)
-                        if _paged_kernel_active():
-                            from ..kernels.paged_attention import (
-                                paged_decode_attention)
-                            att_out = paged_decode_attention(
-                                q._value, k_raw, v_raw, pt, start,
-                                k_scale=ks_raw if quantized else None,
-                                v_scale=vs_raw if quantized else None)
-                        elif quantized:
-                            pt_safe = jnp.clip(pt, 0, n_pages - 1)
-                            k_att = dequantize_pool(
-                                k_raw[pt_safe].reshape(
-                                    (pt.shape[0], virt) + k_raw.shape[2:]),
-                                ks_raw[pt_safe].reshape(pt.shape[0], virt),
-                                k._value.dtype)
-                            v_att = dequantize_pool(
-                                v_raw[pt_safe].reshape(
-                                    (pt.shape[0], virt) + v_raw.shape[2:]),
-                                vs_raw[pt_safe].reshape(pt.shape[0], virt),
-                                v._value.dtype)
-                        else:
-                            pt_safe = jnp.clip(pt, 0, n_pages - 1)
-                            k_att = k_raw[pt_safe].reshape(
-                                (pt.shape[0], virt) + k_raw.shape[2:])
-                            v_att = v_raw[pt_safe].reshape(
-                                (pt.shape[0], virt) + v_raw.shape[2:])
-                        att_len = virt
-                    else:
-                        rows = jnp.arange(k_raw.shape[0])[:, None]
-                        cols = start[:, None] + jnp.arange(t)[None, :]
-                        if quantized:
-                            k_raw = k_raw.at[rows, cols].set(kq,
-                                                             mode="drop")
-                            v_raw = v_raw.at[rows, cols].set(vq,
-                                                             mode="drop")
-                            ks_raw = ks_raw.at[rows, cols].set(ksc,
-                                                               mode="drop")
-                            vs_raw = vs_raw.at[rows, cols].set(vsc,
-                                                               mode="drop")
-                            k_att = dequantize_pool(k_raw, ks_raw,
-                                                    k._value.dtype)
-                            v_att = dequantize_pool(v_raw, vs_raw,
-                                                    v._value.dtype)
-                        else:
-                            k_raw = k_raw.at[rows, cols].set(
-                                k._value.astype(k_raw.dtype), mode="drop")
-                            v_raw = v_raw.at[rows, cols].set(
-                                v._value.astype(v_raw.dtype), mode="drop")
-                            k_att, v_att = k_raw, v_raw
-                            # the engine's decode step on the TPU: the
-                            # read streams each row's live blocks only
-                            # (kernels/paged_attention.py "the dense
-                            # pool's decode read"); everywhere else, and
-                            # for tail_prefill's long spans, the masked
-                            # XLA read below
-                            from ..kernels import paged_attention as _pk
-                            blk = (_pk.dense_read_block(
-                                heads=nh, head_dim=self.head_dim,
-                                dtype=k_raw.dtype, width=t,
-                                max_len=k_raw.shape[1])
-                                if _pk.active() else None)
-                            if blk is not None:
-                                att_out = _pk.dense_decode_attention(
-                                    q._value, k_raw, v_raw, start,
-                                    block=blk)
-                        att_len = k_raw.shape[1]
-                    if att_out is not None:
-                        out = _T(att_out, _internal=True)
-                    else:
-                        mask = (jnp.arange(att_len)[None, None, :] <=
-                                cols[:, :, None])  # [B,t,L] causal+validity
-                        out = F.scaled_dot_product_attention(
-                            q, _T(k_att, _internal=True),
-                            _T(v_att, _internal=True),
-                            attn_mask=_T(mask[:, None], _internal=True),
-                            dropout_p=0.0, is_causal=False, training=False)
-                    out = out.reshape([b, t, nh * self.head_dim])
-                    out = _constrain(out, P(_U, _U, "mp"))
-                    out = self.out_proj(out)
-                    new_cache = (_T(k_raw, _internal=True),
-                                 _T(v_raw, _internal=True), start + t)
-                    if paged:
-                        new_cache = new_cache + (cache[3],)
-                    if quantized:
-                        new_cache = new_cache + (
-                            _T(ks_raw, _internal=True),
-                            _T(vs_raw, _internal=True))
-                    if use_cache:
-                        return out, new_cache
-                    return out
-                z = jnp.zeros((), jnp.int32)
-                k_raw = jax.lax.dynamic_update_slice(
-                    k_raw, k._value.astype(k_raw.dtype), (z, start, z, z))
-                v_raw = jax.lax.dynamic_update_slice(
-                    v_raw, v._value.astype(v_raw.dtype), (z, start, z, z))
-                if isinstance(pos0, int) and pos0 == 0:
-                    # static prefill (helper builds the cache inside the
-                    # prefill jit with a PYTHON-int length 0): no past to
-                    # attend over, so the prompt keeps the causal
-                    # flash-attention path instead of dense masked
-                    # attention over the zero-padded buffer
-                    out = F.scaled_dot_product_attention(
-                        q, k, v, dropout_p=0.0, is_causal=True,
-                        training=False)
-                else:
-                    max_len = k_raw.shape[1]
-                    qpos = start + jnp.arange(t)
-                    mask = (jnp.arange(max_len)[None, :] <=
-                            qpos[:, None])        # [t, L] causal + validity
-                    out = F.scaled_dot_product_attention(
-                        q, _T(k_raw, _internal=True),
-                        _T(v_raw, _internal=True),
-                        attn_mask=_T(mask[None, None], _internal=True),
-                        dropout_p=0.0, is_causal=False, training=False)
-                new_cache = (_T(k_raw, _internal=True),
-                             _T(v_raw, _internal=True), start + t)
-            else:
-                if cache is not None:
-                    # growing-concat cache: every decode step has a new
-                    # key length, so a jitted caller retraces per token —
-                    # the sentinel points at the static path once
-                    from ..observability.retrace import (
-                        note_dynamic_cache_growth)
-                    note_dynamic_cache_growth("models.gpt.GPTSelfAttention")
-                    from ..ops.manipulation import concat
-                    k = concat([cache[0], k], axis=1)
-                    v = concat([cache[1], v], axis=1)
-                out = F.scaled_dot_product_attention(
-                    q, k, v, dropout_p=self.attn_dropout_prob,
-                    is_causal=True, training=self.training)
+            out, new_cache = cached_attention(
+                q, k, v, cache, dropout_p=self.attn_dropout_prob,
+                training=self.training, owner="models.gpt.GPTSelfAttention")
             out = out.reshape([b, t, nh * self.head_dim])
         out = _constrain(out, P(_U, _U, "mp"))
         out = self.out_proj(out)
         if use_cache:
-            return out, (new_cache if new_cache is not None else (k, v))
+            return out, new_cache
         return out
 
 
@@ -662,28 +435,10 @@ class GPTModel(Layer):
             caches = [None] * len(self.layers)
         if position_ids is None and use_cache and caches[0] is not None:
             # incremental decode: offset positions by the cached key length
-            t = input_ids.shape[1]
-            if len(caches[0]) in (3, 4, 5, 6):
-                # static cache (k_buf, v_buf, length[, page_table]
-                # [, k_scale, v_scale]): position base may be a python int
-                # (static prefill) or a traced scalar (step); every tuple
-                # form keeps length at [2]
-                import jax.numpy as jnp
-
-                from ..core.tensor import Tensor as _T
-                past = jnp.asarray(caches[0][2], jnp.int64)
-                if past.ndim == 1:
-                    # per-slot lengths: each row decodes at its own position
-                    pos = past[:, None] + jnp.arange(t, dtype=jnp.int64)
-                else:
-                    pos = (past +
-                           jnp.arange(t, dtype=jnp.int64)).reshape(1, t)
-                position_ids = _T(pos, _internal=True)
-            else:
-                from ..ops.creation import arange
-                past = caches[0][0].shape[1]
-                position_ids = arange(past, past + t,
-                                      dtype="int64").reshape([1, t])
+            from .kv_cache import cache_positions
+            position_ids = Tensor(
+                cache_positions(caches[0], input_ids.shape[1]),
+                _internal=True)
         x = self.embeddings(input_ids, position_ids)
         x = _constrain(x, _activation_spec())
         new_caches = [] if use_cache else None

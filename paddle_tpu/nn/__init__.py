@@ -29,7 +29,7 @@ from .layer.pooling import (  # noqa: F401
     AdaptiveMaxPool1D, AdaptiveMaxPool2D, AdaptiveMaxPool3D,
 )
 from .layer.norm import (  # noqa: F401
-    BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, SyncBatchNorm, LayerNorm,
+    BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, SyncBatchNorm, LayerNorm, RMSNorm,
     GroupNorm, InstanceNorm1D, InstanceNorm2D, InstanceNorm3D,
     LocalResponseNorm, SpectralNorm,
 )

@@ -9,6 +9,7 @@ from .attention import (  # noqa: F401
     enable_flash_attention,
     fused_ln_linear,
     fused_qkv_attention,
+    rotary_embedding,
     scaled_dot_product_attention,
 )
 from ...ops.manipulation import pad  # noqa: F401

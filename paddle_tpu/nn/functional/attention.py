@@ -31,8 +31,13 @@ def enable_flash_attention(flag: bool):
     _USE_FLASH = bool(flag)
 
 
-def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, training):
-    # q,k,v: [B, T, H, D] (paddle convention)
+def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, training,
+              window=None):
+    # q: [B, T, H, D] (paddle convention); k, v may hold fewer heads
+    # (grouped-query attention): query head i reads KV head i // (H / Hkv)
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     qh = jnp.swapaxes(q, 1, 2)  # [B, H, T, D]
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
@@ -40,6 +45,9 @@ def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, training):
     if causal:
         Tq, Tk = scores.shape[-2], scores.shape[-1]
         cm = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
+        if window is not None:
+            # sliding window: query t reads keys t - window + 1 .. t
+            cm &= ~jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq - window)
         scores = jnp.where(cm, scores, jnp.array(-1e30, scores.dtype))
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -70,7 +78,7 @@ def _flash_ok(q) -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _flash_spmd(q, k, v, causal, scale):
+def _flash_spmd(q, k, v, causal, scale, window=None):
     """Pallas call partitioned over the live mesh: batch over dp/sharding,
     heads over mp (a pallas_call is an opaque custom-call to GSPMD, so the
     partitioning must be made explicit with shard_map)."""
@@ -83,18 +91,21 @@ def _flash_spmd(q, k, v, causal, scale):
             if mesh is not None and a in mesh.axis_names and
             mesh.shape.get(a, 1) > 1]
     if not live:
-        return flash_attention_bthd(q, k, v, causal=causal, scale=scale)
+        return flash_attention_bthd(q, k, v, causal=causal, scale=scale,
+                                    window=window)
     batch = tuple(a for a in ("dcn", "dp", "sharding") if a in live)
     heads = "mp" if "mp" in live else None
     n_batch = 1
     for a in batch:
         n_batch *= mesh.shape[a]
-    if q.shape[0] % n_batch or (heads and q.shape[2] % mesh.shape["mp"]):
+    if q.shape[0] % n_batch or (heads and (q.shape[2] % mesh.shape["mp"] or
+                                           k.shape[2] % mesh.shape["mp"])):
         raise FlashUnsupported("shapes not divisible by mesh axes")
     spec = P(batch if batch else None, None, heads, None)
 
     def local(qv, kv, vv):
-        return flash_attention_bthd(qv, kv, vv, causal=causal, scale=scale)
+        return flash_attention_bthd(qv, kv, vv, causal=causal, scale=scale,
+                                    window=window)
 
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
@@ -180,11 +191,23 @@ def _fused_flash_spmd(qkv, causal, scale):
 @defop
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 name=None):
-    """Inputs [batch, seq, heads, head_dim] like the reference fused op."""
+                                 window=None, name=None):
+    """Inputs [batch, seq, heads, head_dim] like the reference fused op.
+    `key`/`value` may hold fewer heads than `query` (grouped-query
+    attention: a divisor; query head i reads KV head i // group).  `window`
+    (with `is_causal`) keeps, for query t, the keys t - window + 1 .. t."""
     scale = 1.0 / math.sqrt(query.shape[-1])
+    if window is not None and not is_causal:
+        raise ValueError("window= needs is_causal=True; a cached read "
+                         "states its window in attn_mask")
+    if query.shape[2] % key.shape[2]:
+        raise ValueError(f"{query.shape[2]} query heads are no multiple of "
+                         f"{key.shape[2]} key/value heads")
     from ...distributed import mesh as mesh_mod
     if mesh_mod.axis_bound("sep"):
+        if window is not None or query.shape[2] != key.shape[2]:
+            raise ValueError("context parallelism (sep axis) supports "
+                             "neither a window nor grouped-query heads")
         # sequence axis is sharded (context parallelism): shard-local attention
         # would be globally wrong, so the ring path is mandatory here
         if attn_mask is not None or (dropout_p and training) or \
@@ -201,11 +224,26 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if attn_mask is None and not (dropout_p and training) and \
             _flash_ok(query):
         try:
-            return _flash_spmd(query, key, value, is_causal, scale)
+            return _flash_spmd(query, key, value, is_causal, scale, window)
         except FlashUnsupported:
             pass  # mesh-divisibility constraint: unfused reference path below
     return _sdpa_ref(query, key, value, attn_mask, dropout_p, is_causal, scale,
-                     training)
+                     training, window)
+
+
+@defop
+def rotary_embedding(x, positions, theta=10000.0, name=None):
+    """Rotary position encoding in the half-split layout (dimension i pairs
+    with i + head_dim / 2): x [batch, seq, heads, head_dim] at integer
+    `positions` [batch or 1, seq].  Angles and the rotation are float32."""
+    half = x.shape[-1] // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = positions.astype(jnp.float32)[:, :, None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 @defop

@@ -33,6 +33,18 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
     return out
 
 
+@defop
+def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+    """x / sqrt(mean(x^2) + epsilon) * weight over the last axis; the mean
+    of squares is taken in float32 and the result is in x's type."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    out = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                             + epsilon)
+    if weight is not None:
+        out = out * weight.astype(out.dtype)
+    return out.astype(x.dtype)
+
+
 def batch_norm(x, running_mean, running_var, weight=None, bias=None,
                training=False, momentum=0.9, epsilon=1e-5, data_format="NCHW",
                use_global_stats=None, name=None):

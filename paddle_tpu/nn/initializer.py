@@ -41,9 +41,24 @@ class Normal(Initializer):
     def __init__(self, mean=0.0, std=1.0):
         self.mean, self.std = mean, std
 
+    # a float32 draw keeps about twelve arrays of its size alive on a TPU:
+    # a [151936, 2560] table would need 19 GB.  Above _WHOLE elements the
+    # rows are drawn in blocks of at most _BLOCK elements, each from its own
+    # fold of the key (smaller shapes keep the one draw and their values)
+    _WHOLE, _BLOCK = 1 << 27, 1 << 25
+
     def __call__(self, shape, dtype=jnp.float32):
-        return (self.mean + self.std *
-                jax.random.normal(rnd.next_key(), shape)).astype(dtype)
+        key = rnd.next_key()
+        n = int(np.prod(shape)) if len(shape) else 1
+        if n <= self._WHOLE or len(shape) < 2:
+            return (self.mean + self.std *
+                    jax.random.normal(key, shape)).astype(dtype)
+        rows = max(1, self._BLOCK // (n // shape[0]))
+        return jnp.concatenate([
+            (self.mean + self.std * jax.random.normal(
+                jax.random.fold_in(key, r),
+                (min(rows, shape[0] - r),) + tuple(shape[1:]))).astype(dtype)
+            for r in range(0, shape[0], rows)])
 
 
 class TruncatedNormal(Initializer):

@@ -46,7 +46,15 @@ def get_retrace_threshold() -> int:
     return _threshold[0]
 
 
+_dtype_names: dict = {}      # dtype -> its name; `str(dtype)` costs ~1 us
+
+
 def _abstract_signature(args, kwargs=None) -> tuple:
+    """Runs on every dispatch of an instrumented entry point, over every
+    leaf (a serving decode carries the model's weights: hundreds), so it
+    keeps the tree definition as it is (hashed and compared structurally;
+    `str()` of it only where a new signature is logged) and looks each
+    dtype's name up."""
     import jax.tree_util as jtu
     leaves, treedef = jtu.tree_flatten((args, kwargs or {}))
     sig = []
@@ -56,9 +64,11 @@ def _abstract_signature(args, kwargs=None) -> tuple:
         if shape is None and dtype is None:
             sig.append(repr(lv))  # static python leaf
         else:
-            sig.append((tuple(shape) if shape is not None else None,
-                        str(dtype)))
-    return (str(treedef), tuple(sig))
+            name = _dtype_names.get(dtype)
+            if name is None:
+                name = _dtype_names[dtype] = str(dtype)
+            sig.append((tuple(shape) if shape is not None else None, name))
+    return (treedef, tuple(sig))
 
 
 def record_compile(name: str, key, seconds: float, n_compiles: int):

@@ -135,6 +135,9 @@ SERVING_HOST_PREFIX_PROMOTES = \
     "paddle_tpu_serving_host_prefix_promotes_total"
 SERVING_HOST_PREFIX_PROMOTE_SECONDS = \
     "paddle_tpu_serving_host_prefix_promote_seconds"
+SERVING_MOE_ASSIGNMENTS = "paddle_tpu_serving_moe_assignments_total"
+SERVING_MOE_EXPERTS_TOUCHED = "paddle_tpu_serving_moe_experts_touched_total"
+SERVING_MOE_LOAD_MAX = "paddle_tpu_serving_moe_load_max_total"
 
 
 class QueueFullError(RuntimeError):
@@ -199,7 +202,9 @@ class RequestHandle:
 
     ``result(timeout)`` blocks for the generated token ids (raises the
     request's error instead — CancelledError / DeadlineExceededError /
-    EngineClosedError).  ``tokens`` is the stream-so-far; ``ttft_s`` and
+    EngineClosedError).  ``tokens`` is the stream-so-far and ``logprobs``
+    each token's log-probability under the model's own distribution (before
+    temperature and top-k), computed by the step that chose it; ``ttft_s`` and
     ``token_latencies_s`` carry the latency telemetry the serving bench
     aggregates into p50/p99.
     """
@@ -229,6 +234,7 @@ class RequestHandle:
         self._done = threading.Event()
         self._error: Optional[BaseException] = None
         self._tokens: list[int] = []
+        self._logprobs: list[float] = []
         self.slot: Optional[int] = None
         self._prefix_src = None           # PrefixEntry this request copied
         self._prefix_match = 0            # tokens covered by that copy
@@ -281,6 +287,11 @@ class RequestHandle:
     def generated(self) -> list[int]:
         return list(self._tokens)
 
+    @property
+    def logprobs(self) -> list[float]:
+        """log p(token | context) of each generated token so far."""
+        return list(self._logprobs)
+
     def text(self) -> str:
         """Decode the generated tokens (requires the engine's tokenizer)."""
         tok = self._engine.tokenizer
@@ -290,13 +301,18 @@ class RequestHandle:
 
     # -- engine internals ----------------------------------------------------
     def _finish(self, error: Optional[BaseException] = None):
+        # tokens still held back by the engine reach their consumers before
+        # the outcome does (a request that never emitted, queued or parked,
+        # has none: no callback runs under its caller's locks)
+        if self._tokens:
+            self._engine._flush_streams()
         self._state = "done"
         # readers (result/exception) block on the _done Event before
         # touching _error, so the Event publishes the write
         self._error = error  # tpu-lint: ok(concurrency)
         self._done.set()
 
-    def _emit(self, token: int):
+    def _emit(self, token: int, logprob: float):
         if self._done.is_set() or self._torn:
             # the request was torn off a dead/abandoned engine while a
             # stuck dispatch was still in flight: never stream past the
@@ -304,15 +320,21 @@ class RequestHandle:
             # zero-token or its re-dispatch would duplicate output)
             return
         self._tokens.append(int(token))
+        self._logprobs.append(float(logprob))
         if self._stream is not None:
-            try:
-                self._stream(int(token))
-            except Exception:
-                pass  # a broken stream consumer must not kill the batch
+            self._engine._held_streams.append((self._stream, int(token)))
 
     def __repr__(self):
         return (f"RequestHandle(id={self.request_id}, state={self._state}, "
                 f"slot={self.slot}, tokens={len(self._tokens)})")
+
+
+def _row_logprob(logits_row: np.ndarray, token: int) -> float:
+    """log-softmax of one logits row at `token` (the host sampler's side of
+    `RequestHandle.logprobs`)."""
+    logits = np.asarray(logits_row, np.float32)
+    top = logits.max()
+    return float(logits[token] - top - np.log(np.exp(logits - top).sum()))
 
 
 def _sample_row(logits_row: np.ndarray, temperature: float, top_k: int,
@@ -332,6 +354,14 @@ def _sample_row(logits_row: np.ndarray, temperature: float, top_k: int,
     p = np.exp(logits)
     p /= p.sum()
     return int(rng.choice(len(p), p=p))
+
+
+def _trunk(model):
+    """The model's transformer stack where it exposes trunk + head (`.gpt`
+    of the GPT family, `.decoder` of models/decoder.py), else None: its
+    `config` states the position limit and the head count, and the serving
+    jits run the head on the gathered positions only."""
+    return getattr(model, "gpt", None) or getattr(model, "decoder", None)
 
 
 def _bucket(n: int, lo: int, hi: int) -> int:
@@ -504,12 +534,20 @@ class Engine:
         self.max_len = int(max_len)
         if self.max_slots < 1 or self.max_len < 2:
             raise ValueError("need max_slots >= 1 and max_len >= 2")
-        cfg = getattr(getattr(model, "gpt", model), "config", None)
+        cfg = getattr(_trunk(model) or model, "config", None)
         limit = getattr(cfg, "max_position_embeddings", None)
         if limit is not None and self.max_len > int(limit):
             raise ValueError(
                 f"max_len={self.max_len} exceeds the model's "
                 f"max_position_embeddings={limit}")
+        # what this model's layers cannot serve is refused here, by name,
+        # never answered wrongly: {option: reason} on the model's class
+        asked = {"adapters": adapters is not None,
+                 "decode_kernel='pallas'": decode_kernel == "pallas"}
+        for option, why in getattr(model, "serving_unsupported", {}).items():
+            if asked.get(option):
+                raise ValueError(f"{type(model).__name__} cannot be served "
+                                 f"with {option}: {why}")
         self.max_queue = (2 * self.max_slots if max_queue is None
                           else int(max_queue))
         self.prefill_batch = (min(4, self.max_slots) if prefill_batch is None
@@ -656,6 +694,15 @@ class Engine:
         self._built = False
         self._values = None
         self._pools = None          # (kpools, vpools[, kscales, vscales])
+        # stream callbacks of the tokens last emitted, held back until the
+        # next program is on the device (`_flush_streams`): their consumers
+        # (one gateway thread a stream) then wake while the device works,
+        # not while the scheduler thread prepares the dispatch beside them
+        self._held_streams: deque = deque()
+        self._stream_lock = threading.RLock()
+        self._moe_load = False      # the model's expert layers count load
+        self._load_counters: list = []   # (stats key, registry counter)
+        self._kv_windows: list = []  # per layer: sliding window or None
         self._pool_bytes = 0
         n_rows = self.max_slots + 1           # + scratch row
         self._ids = np.zeros((n_rows, self._spec_width), np.int64)
@@ -688,6 +735,8 @@ class Engine:
                         "prefill_tokens": 0, "prefill_padded_tokens": 0,
                         "decode_kv_live_positions": 0,
                         "decode_kv_read_positions": 0,
+                        "moe_assignments": 0, "moe_experts_touched": 0,
+                        "moe_load_max": 0,
                         "tokens": 0, "resubmitted": 0, "redispatched": 0,
                         "interrupted": 0, "prefix_hits": 0,
                         "prefix_misses": 0, "prefix_evictions": 0,
@@ -1157,16 +1206,42 @@ class Engine:
         on_device = self.sample_on_device
         self._values = state_values(model)
 
+        from ..incubate.distributed.models.moe.dropless import (
+            collect_load as _collect_load)
+
         def _kv_struct():
             def f(vals, ii):
-                with _swapped_state(model, vals):
+                with _swapped_state(model, vals), _collect_load() as load:
                     _, caches = model(Tensor(ii, _internal=True),
                                       use_cache=True)
-                return [(k._value, v._value) for k, v in caches]
+                return ([(k._value, v._value) for k, v in caches],
+                        load.total())
             return jax.eval_shape(f, self._values,
                                   jnp.zeros((1, 1), jnp.int64))
 
-        kv = _kv_struct()
+        # the pools are sized from the cache shapes the model returns (KV
+        # heads, not query heads); a model with expert layers also counts
+        # their load, returned behind each step's tokens
+        kv, load_struct = _kv_struct()
+        self._moe_load = load_struct is not None
+        # the registry's side of the expert load, resolved once: the emit
+        # phase of every step adds to these
+        self._load_counters = [
+            (key, registry().counter(name, what)) for name, key, what in (
+                (SERVING_MOE_ASSIGNMENTS, "moe_assignments",
+                 "token-to-expert assignments computed (tokens x top-k, "
+                 "summed over layers)"),
+                (SERVING_MOE_EXPERTS_TOUCHED, "moe_experts_touched",
+                 "experts with at least one token, summed over layers and "
+                 "steps"),
+                (SERVING_MOE_LOAD_MAX, "moe_load_max",
+                 "largest expert load of each layer, summed over layers "
+                 "and steps"))] if self._moe_load else []
+        trunk = _trunk(model)
+        # per layer: the sliding window its attention reads, None = all
+        self._kv_windows = (list(trunk.attention_windows())
+                            if hasattr(trunk, "attention_windows")
+                            else [None] * len(kv))
 
         def _leaf_bytes(leaves):
             return sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
@@ -1242,21 +1317,47 @@ class Engine:
         else:
             k0 = kv[0][0]
             self._decode_read_block = dense_read_block(
-                heads=int(k0.shape[2]), head_dim=int(k0.shape[3]),
+                heads=int(getattr(getattr(trunk, "config", None),
+                                  "num_attention_heads", k0.shape[2])),
+                kv_heads=int(k0.shape[2]), head_dim=int(k0.shape[3]),
                 dtype=jnp.int8 if quant else k0.dtype,
                 width=self._spec_width, max_len=L)
 
-        def _mstate(values, adp, pk=False):
+        @contextlib.contextmanager
+        def _mstate(values, adp, pk=False, valid=None):
             """Swapped model state, plus the batched-adapter scope when
             the dispatch carries adapter operands, plus the Pallas
-            decode-kernel scope when this jit is the decode step."""
-            st = contextlib.ExitStack()
-            st.enter_context(_swapped_state(model, values))
-            if adp is not None:
-                st.enter_context(_adapter_scope(*adp))
-            if pk:
-                st.enter_context(_pk_scope())
-            return st
+            decode-kernel scope when this jit is the decode step.  Yields
+            the collector of the expert layers' load over the tokens
+            `valid` marks as real."""
+            with contextlib.ExitStack() as st:
+                st.enter_context(_swapped_state(model, values))
+                if adp is not None:
+                    st.enter_context(_adapter_scope(*adp))
+                if pk:
+                    st.enter_context(_pk_scope())
+                yield st.enter_context(_collect_load(valid))
+
+        def _pack(out, load, logits=None):
+            """A step's output as ONE array, so that one fetch brings it
+            all: the device sampler's token ids, behind them each token's
+            log-probability under `logits` (the model's own distribution,
+            before temperature and top-k; float32 bits as integers), and
+            last the expert load's three counts where the model has expert
+            layers.  Host-sampled logits (no `logits` argument) go as they
+            are, the counts beside them as a second, 12-byte array."""
+            tot = load.total()
+            if logits is None:
+                return out if tot is None else (out, tot)
+            l32 = logits.astype(jnp.float32)
+            lp = (jnp.take_along_axis(l32, out[..., None], -1)[..., 0] -
+                  jax.nn.logsumexp(l32, axis=-1))
+            parts = [out.reshape(-1),
+                     jax.lax.bitcast_convert_type(
+                         lp, jnp.int32).reshape(-1).astype(out.dtype)]
+            if tot is not None:
+                parts.append(tot.astype(out.dtype))
+            return jnp.concatenate(parts)
         pool_dtype = jnp.int8 if quant else None
         paged = self.paged_kv
         if paged:
@@ -1361,7 +1462,7 @@ class Engine:
             """(per-row logits at the last real position, new caches); when
             the model exposes trunk + head, the vocab matmul runs on ONLY
             the gathered positions."""
-            inner = getattr(model, "gpt", None)
+            inner = trunk
             head = getattr(model, "lm_head", None)
             if inner is not None and callable(head):
                 x, new_caches = inner(ids_t, caches=caches_t, use_cache=True)
@@ -1381,7 +1482,7 @@ class Engine:
         def _fwd_all(ids_t, caches_t):
             """Logits at EVERY input position — the speculative verify
             needs the model's choice after each drafted prefix."""
-            inner = getattr(model, "gpt", None)
+            inner = trunk
             head = getattr(model, "lm_head", None)
             if inner is not None and callable(head):
                 x, new_caches = inner(ids_t, caches=caches_t, use_cache=True)
@@ -1419,6 +1520,15 @@ class Engine:
             prefill, decode) produced it."""
             return jax.vmap(jax.random.fold_in)(keys, positions)
 
+        park = self._park
+
+        def _tail_valid(ids, lengths, gather_idx):
+            # a hit's tail up to its last real position; rows outside the
+            # wave are parked
+            return ((lengths < park)[:, None] &
+                    (jnp.arange(ids.shape[1])[None, :] <=
+                     gather_idx[:, None]))
+
         def prefill(values, ids, pools, slot_idx, prompt_lens, temps,
                     topks, keys, adp=None):
             # the per-request caches are BUILT inside this jit with a
@@ -1434,7 +1544,10 @@ class Engine:
                  Tensor(jnp.zeros((n, L) + tuple(v.shape[2:]), v.dtype),
                         _internal=True), 0)
                 for k, v in kv]
-            with _mstate(_dq(values), adp):
+            valid = ((jnp.arange(ids.shape[1])[None, :] <
+                      prompt_lens[:, None]) &
+                     (slot_idx < n_rows - 1)[:, None])   # not a padding row
+            with _mstate(_dq(values), adp, valid=valid) as load:
                 logits, new_caches = _fwd_last(
                     Tensor(ids, _internal=True), caches_t,
                     gather_idx=prompt_lens - 1)
@@ -1461,8 +1574,8 @@ class Engine:
             if on_device:
                 toks = _sample_rows(logits, temps, topks,
                                     _step_keys(keys, prompt_lens - 1))
-                return toks, pools
-            return logits, pools
+                return _pack(toks, load, logits), pools
+            return _pack(logits, load), pools
 
         def prefill_paged(values, ids, pools, tables, prompt_lens, temps,
                           topks, keys, adp=None):
@@ -1481,12 +1594,15 @@ class Engine:
                  Tensor(jnp.zeros((n, bucket) + tuple(v.shape[2:]),
                                   v.dtype), _internal=True), 0)
                 for k, v in kv]
-            with _mstate(_dq(values), adp):
+            pos = jnp.arange(bucket)
+            # real positions of real lanes (a padding lane's table is all
+            # sentinel)
+            valid = ((pos[None, :] < prompt_lens[:, None]) &
+                     (tables[:, :1] < NP_))                      # [n, bucket]
+            with _mstate(_dq(values), adp, valid=valid) as load:
                 logits, new_caches = _fwd_last(
                     Tensor(ids, _internal=True), caches_t,
                     gather_idx=prompt_lens - 1)
-            pos = jnp.arange(bucket)
-            valid = pos[None, :] < prompt_lens[:, None]          # [n, bucket]
             pslot = jnp.clip(pos // P_, 0, n_pt - 1)
             pid = jnp.where(valid, tables[:, pslot], NP_)
             off = jnp.broadcast_to((pos % P_)[None, :], pid.shape)
@@ -1513,8 +1629,8 @@ class Engine:
             if on_device:
                 toks = _sample_rows(logits, temps, topks,
                                     _step_keys(keys, prompt_lens - 1))
-                return toks, pools
-            return logits, pools
+                return _pack(toks, load, logits), pools
+            return _pack(logits, load), pools
 
         def decode_paged(values, ids, pools, lengths, tables, temps,
                          topks, keys, adp=None):
@@ -1523,7 +1639,8 @@ class Engine:
             # gather/scatter lives in the model's paged cache branch, so
             # this stays ONE compiled program per engine config
             caches_t = _caches_from(pools, lengths, tables)
-            with _mstate(_dq(values), adp, pk=use_pallas_decode):
+            with _mstate(_dq(values), adp, pk=use_pallas_decode,
+                         valid=lengths < park) as load:
                 logits, new_caches = _fwd_all(
                     Tensor(ids, _internal=True), caches_t)
             pools = _pools_from(new_caches)
@@ -1532,13 +1649,14 @@ class Engine:
                 first = _sample_rows(logits[:, 0], temps, topks,
                                      _step_keys(keys, lengths))
                 toks = greedy.at[:, 0].set(first)
-                return toks, pools
-            return logits, pools
+                return _pack(toks, load, logits), pools
+            return _pack(logits, load), pools
 
         def tail_prefill_paged(values, ids, pools, lengths, tables,
                                gather_idx, temps, topks, keys, adp=None):
             caches_t = _caches_from(pools, lengths, tables)
-            with _mstate(_dq(values), adp):
+            with _mstate(_dq(values), adp,
+                         valid=_tail_valid(ids, lengths, gather_idx)) as load:
                 logits, new_caches = _fwd_last(
                     Tensor(ids, _internal=True), caches_t,
                     gather_idx=gather_idx)
@@ -1546,8 +1664,8 @@ class Engine:
             if on_device:
                 toks = _sample_rows(logits, temps, topks,
                                     _step_keys(keys, lengths + gather_idx))
-                return toks, pools
-            return logits, pools
+                return _pack(toks, load, logits), pools
+            return _pack(logits, load), pools
 
         def copy_pages(pools, src, dst):
             # copy-on-write: clone whole pages (K/V + scale sidecars)
@@ -1568,7 +1686,8 @@ class Engine:
             # W=k the speculative verify — same program shape either way,
             # ONE signature per engine config.
             caches_t = _caches_from(pools, lengths)
-            with _mstate(_dq(values), adp, pk=True):
+            with _mstate(_dq(values), adp, pk=True,
+                         valid=lengths < park) as load:
                 logits, new_caches = _fwd_all(
                     Tensor(ids, _internal=True), caches_t)
             pools = _pools_from(new_caches)
@@ -1577,8 +1696,8 @@ class Engine:
                 first = _sample_rows(logits[:, 0], temps, topks,
                                      _step_keys(keys, lengths))
                 toks = greedy.at[:, 0].set(first)
-                return toks, pools
-            return logits, pools
+                return _pack(toks, load, logits), pools
+            return _pack(logits, load), pools
 
         def tail_prefill(values, ids, pools, lengths, gather_idx, temps,
                          topks, keys, adp=None):
@@ -1586,7 +1705,8 @@ class Engine:
             # cached row, only the tail runs through the per-slot branch
             # (rows not in this admit batch park at max_len: writes drop)
             caches_t = _caches_from(pools, lengths)
-            with _mstate(_dq(values), adp):
+            with _mstate(_dq(values), adp,
+                         valid=_tail_valid(ids, lengths, gather_idx)) as load:
                 logits, new_caches = _fwd_last(
                     Tensor(ids, _internal=True), caches_t,
                     gather_idx=gather_idx)
@@ -1594,8 +1714,8 @@ class Engine:
             if on_device:
                 toks = _sample_rows(logits, temps, topks,
                                     _step_keys(keys, lengths + gather_idx))
-                return toks, pools
-            return logits, pools
+                return _pack(toks, load, logits), pools
+            return _pack(logits, load), pools
 
         def copy_rows(pools, src, dst):
             # prefix-cache hit: clone the cached rows (K/V + scales) into
@@ -1645,6 +1765,7 @@ class Engine:
                 # age via health())
                 self._last_progress = time.perf_counter()
             if not did:
+                self._flush_streams()
                 with phase("serving.wait") as idle:
                     if not self._wake.wait(0.02):
                         idle.drop()         # nobody called: no record
@@ -2367,7 +2488,6 @@ class Engine:
     def _prefill_cold(self, batch) -> None:
         """Batched prefill of requests with no cached prefix (the only
         admission path when the prefix cache is off)."""
-        import jax.numpy as jnp
         bucket = _bucket(max(r.prompt.size for r in batch),
                          min(8, self._limit), self._limit)
         P = self.prefill_batch
@@ -2389,22 +2509,20 @@ class Engine:
                              if self._adapters is not None else ())
                     if self.paged_kv:
                         out, self._pools = self._prefill_fn(
-                            self._values, jnp.asarray(ids), self._pools,
-                            jnp.asarray(tables), jnp.asarray(plens),
-                            jnp.asarray(temps), jnp.asarray(topks),
-                            jnp.asarray(keys), *extra)
+                            self._values, ids, self._pools, tables, plens,
+                            temps, topks, keys, *extra)
                     else:
                         out, self._pools = self._prefill_fn(
-                            self._values, jnp.asarray(ids), self._pools,
-                            jnp.asarray(slot_idx), jnp.asarray(plens),
-                            jnp.asarray(temps), jnp.asarray(topks),
-                            jnp.asarray(keys), *extra)
+                            self._values, ids, self._pools, slot_idx, plens,
+                            temps, topks, keys, *extra)
+                    self._dispatched(out)
                 with phase("serving.prefill.fetch"):
-                    out = np.asarray(out)
+                    out, lps, load = self._fetch(out, (P,))
             finally:
                 if self._decode_timeout_s is not None:
                     _watchdog.disarm()
-            with phase("serving.prefill.emit"):
+            with phase("serving.prefill.emit", **self._load_stats(load)):
+                self._count_load(load)
                 dt = time.perf_counter() - t0
                 with self._lock:
                     self._counts["prefill_batches"] += 1
@@ -2419,7 +2537,7 @@ class Engine:
                         req.journey.phase("prefill", t0, dt, n=len(batch),
                                           bucket=bucket,
                                           prompt=int(req.prompt.size))
-                self._emit_first_tokens(batch, out, by_slot=False)
+                self._emit_first_tokens(batch, out, lps, by_slot=False)
 
     def _prefill_rows(self, batch, bucket: int):
         """The host arrays of one cold prefill dispatch: ``prefill_batch``
@@ -2518,12 +2636,15 @@ class Engine:
                             jnp.asarray(self._temps),
                             jnp.asarray(self._topks),
                             jnp.asarray(self._keys), *extra)
+                    self._dispatched(out)
                 with phase("serving.tail_prefill.fetch"):
-                    out = np.asarray(out)
+                    out, lps, load = self._fetch(out, (n_rows,))
             finally:
                 if self._decode_timeout_s is not None:
                     _watchdog.disarm()
-            with phase("serving.tail_prefill.emit"):
+            with phase("serving.tail_prefill.emit",
+                       **self._load_stats(load)):
+                self._count_load(load)
                 t_end = time.perf_counter()
                 dt = t_end - t0
                 with self._lock:
@@ -2550,7 +2671,7 @@ class Engine:
                         cached_tokens=m, tail=int(req.prompt.size - m),
                         zero_copy=bool(paged and
                                        req.request_id not in cow_set))
-                self._emit_first_tokens(hits, out, by_slot=True)
+                self._emit_first_tokens(hits, out, lps, by_slot=True)
 
     def _tail_rows(self, hits, tb: int):
         """The host arrays of one prefix-hit wave: copy sources and
@@ -2595,14 +2716,16 @@ class Engine:
             aids_snap = np.array(self._aids)
         return src, dst, n_copy, cow_ids, ids, lens, gidx, tables, aids_snap
 
-    def _emit_first_tokens(self, batch, out, by_slot: bool):
+    def _emit_first_tokens(self, batch, out, lps, by_slot: bool):
         """Shared tail of both admission paths: record TTFT and emit each
-        request's first token (``out`` is device-sampled token ids, or
-        logits rows when ``sample_on_device=False``)."""
+        request's first token (``out`` is device-sampled token ids with
+        their log-probabilities ``lps``, or logits rows when
+        ``sample_on_device=False``)."""
         now = time.perf_counter()
         finishers = []
         for i, req in enumerate(batch):
-            row = out[req.slot] if by_slot else out[i]
+            at = req.slot if by_slot else i
+            row = out[at]
             req.ttft_s = now - req.t_submit
             req._t_last_token = now
             registry().histogram(SERVING_TTFT,
@@ -2616,9 +2739,11 @@ class Engine:
                 continue
             token = (int(row) if self.sample_on_device else
                      _sample_row(row, req.temperature, req.top_k, req._rng))
+            logprob = (lps[at] if self.sample_on_device else
+                       _row_logprob(row, token))
             if req.journey is not None:
                 req.journey.mark_first_token(now)
-            finished = self._emit_one(req, token)
+            finished = self._emit_one(req, token, logprob)
             if req.adapter is not None:
                 registry().counter(
                     SERVING_ADAPTER_TOKENS,
@@ -2645,7 +2770,6 @@ class Engine:
             active = self._pool.active()
             if not active:
                 return False
-        import jax.numpy as jnp
         with span("serving.decode", active=len(active)):
             with phase("serving.decode.build"):
                 (drafts, ids, lengths, temps, topks, keys, aids,
@@ -2661,46 +2785,117 @@ class Engine:
                                       self._decode_timeout_s)
                     extra = ((self._adp_args(aids),)
                              if self._adapters is not None else ())
+                    # the slot-state snapshots go in as numpy: the jit call
+                    # transfers them itself, without a Python-level
+                    # `device_put` apiece
                     if self.paged_kv:
                         out, self._pools = self._decode_fn(
-                            self._values, jnp.asarray(ids), self._pools,
-                            jnp.asarray(lengths), jnp.asarray(tables),
-                            jnp.asarray(temps), jnp.asarray(topks),
-                            jnp.asarray(keys), *extra)
+                            self._values, ids, self._pools, lengths, tables,
+                            temps, topks, keys, *extra)
                     else:
                         out, self._pools = self._decode_fn(
-                            self._values, jnp.asarray(ids), self._pools,
-                            jnp.asarray(lengths), jnp.asarray(temps),
-                            jnp.asarray(topks), jnp.asarray(keys), *extra)
+                            self._values, ids, self._pools, lengths, temps,
+                            topks, keys, *extra)
+                    self._dispatched(out)
                 with phase("serving.decode.fetch"):
-                    out = np.asarray(out)
+                    out, lps, load = self._fetch(out, ids.shape)
             finally:
                 if self._decode_timeout_s is not None:
                     _watchdog.disarm()
-            with phase("serving.decode.emit"):
-                self._decode_emit(active, drafts, lengths, out, t0)
+            with phase("serving.decode.emit", **self._load_stats(load)):
+                self._count_load(load)
+                self._decode_emit(active, drafts, lengths, out, lps, t0)
         return True
 
+    def _flush_streams(self):
+        """Hand every held-back token to its stream callback, in the order
+        emitted.  Called once a program is on the device, before any request
+        finishes (`RequestHandle._finish`) and when the scheduler goes idle;
+        the lock keeps two flushing threads from reordering a stream."""
+        with self._stream_lock:
+            while self._held_streams:
+                stream, token = self._held_streams.popleft()
+                try:
+                    stream(token)
+                except Exception:
+                    pass  # a broken stream consumer must not kill the batch
+
+    def _dispatched(self, out):
+        """The tail of every dispatch phase: ask for the step's one
+        device-to-host copy now, so that it starts when the program ends and
+        not when `_fetch` comes to ask; then let the last step's tokens go."""
+        for a in (out if isinstance(out, tuple) else (out,)):
+            a.copy_to_host_async()
+        self._flush_streams()
+
+    def _fetch(self, out, shape):
+        """The one device-to-host transfer of a step: (token ids `shape`, or
+        logits rows; the tokens' log-probabilities, None beside logits rows;
+        the expert layers' load counts, None for a model without them).  On
+        the device sampler all three are one array (`_pack`)."""
+        if isinstance(out, tuple):          # host sampling: logits, counts
+            return np.asarray(out[0]), None, np.asarray(out[1])
+        out = np.asarray(out)
+        if not self.sample_on_device:
+            return out, None, None
+        n = int(np.prod(shape))
+        lps = out[n:2 * n].astype(np.int32).view(np.float32).reshape(shape)
+        return (out[:n].reshape(shape), lps,
+                out[2 * n:] if self._moe_load else None)
+
+    @staticmethod
+    def _load_stats(load) -> dict:
+        """One step's expert load as the short scalar stats of its emit
+        span ({} for a model without expert layers): `moe_assignments` real
+        tokens x top-k, `moe_experts_touched` experts with at least one of
+        them, `moe_load_max` the largest expert load, each summed over the
+        layers."""
+        if load is None:
+            return {}
+        return {"moe_assignments": int(load[0]),
+                "moe_experts_touched": int(load[1]),
+                "moe_load_max": int(load[2])}
+
+    def _count_load(self, load):
+        """Add one step's expert load to `stats()` and the registry (inside
+        the step's emit phase)."""
+        stats = self._load_stats(load)
+        if not stats:
+            return
+        with self._lock:
+            for k, v in stats.items():
+                self._counts[k] += v
+        for k, counter in self._load_counters:
+            counter.inc(float(stats[k]))
+
     def _decode_kv_positions(self, active: dict, lengths):
-        """Per layer, the KV positions one decode dispatch needs
-        (`kv_live`: each active slot's context and its new span) and the
-        positions its attention read streams (`kv_read`): every row whole
-        on an XLA read, each row's live blocks or pages on a kernel."""
+        """Summed over the layers, the KV positions one decode dispatch
+        needs (`kv_live`: each active slot's context and its new span; on a
+        sliding-window layer only what the window admits) and the positions
+        its attention read streams (`kv_read`): every row whole on an XLA
+        read, each row's live blocks or pages on a kernel (on a window
+        layer from the window's first block on)."""
         from ..kernels.paged_attention import live_blocks
         W = self._spec_width
-        kv_live = int(sum(int(lengths[s]) + W for s in active))
         span = (self._max_pages_per_slot * self._page_alloc.page_size
                 if self.paged_kv else self.max_len)
         P = self._decode_read_block
-        if P is None:
-            kv_read = len(lengths) * span
-        else:
-            nb = live_blocks(lengths, W, span, P)
-            if self.paged_kv:
-                # the paged kernel's index map stands on one clamped page
-                # for a parked row
-                nb = np.maximum(nb, 1)
-            kv_read = int(nb.sum()) * P
+        ctx = np.asarray([int(lengths[s]) + W for s in active], np.int64)
+        kv_live = kv_read = 0
+        for window in set(self._kv_windows):
+            n_layers = self._kv_windows.count(window)
+            live = ctx if window is None else np.minimum(ctx, window + W - 1)
+            if P is None:
+                read = len(lengths) * span
+            else:
+                nb = live_blocks(lengths, W, span, P, window)
+                if self.paged_kv:
+                    # the paged kernel's index map stands on one clamped
+                    # page for a parked row
+                    nb = np.maximum(nb, 1)
+                read = int(nb.sum()) * P
+            kv_live += n_layers * int(live.sum())
+            kv_read += n_layers * read
         with self._lock:
             self._counts["decode_kv_live_positions"] += kv_live
             self._counts["decode_kv_read_positions"] += kv_read
@@ -2740,7 +2935,8 @@ class Engine:
                       else None)
         return drafts, ids, lengths, temps, topks, keys, aids, tables
 
-    def _decode_emit(self, active: dict, drafts: dict, lengths, out, t0):
+    def _decode_emit(self, active: dict, drafts: dict, lengths, out, lps,
+                     t0):
         """`serving.decode.emit`: accept, stream and account the tokens
         of one fetched decode batch; retire what finished."""
         W = self._spec_width
@@ -2763,6 +2959,7 @@ class Engine:
                 continue
             if self.sample_on_device:
                 toks_row = out[slot]                      # [W] token ids
+                lps_row = lps[slot]
             else:
                 row_logits = out[slot]                    # [W, V] logits
                 first = _sample_row(row_logits[0], req.temperature,
@@ -2770,6 +2967,8 @@ class Engine:
                 toks_row = np.concatenate(
                     [[first], row_logits[1:].argmax(-1)]) \
                     if W > 1 else np.array([first])
+                lps_row = [_row_logprob(r, int(t))
+                           for r, t in zip(row_logits, toks_row)]
             # acceptance: the draft at position j (ids[slot, j]) is kept
             # iff it equals the model's choice at position j-1; the run
             # t_0..t_m then emits m+1 tokens for this one pool read
@@ -2787,8 +2986,8 @@ class Engine:
             req._t_last_token = now
             emitted = 0
             finished = False
-            for token in run:
-                finished = self._emit_one(req, token)
+            for token, logprob in zip(run, lps_row):
+                finished = self._emit_one(req, token, logprob)
                 emitted += 1
                 if finished:
                     break
@@ -2839,11 +3038,12 @@ class Engine:
         with self._lock:
             self._gauges_locked()
 
-    def _emit_one(self, req: RequestHandle, token: int) -> bool:
+    def _emit_one(self, req: RequestHandle, token: int,
+                  logprob: float) -> bool:
         """Stream one token to the request; returns whether the request
         is now finished (budget or EOS)."""
         faults.fault_point("serving.stream", request=req.request_id)
-        req._emit(token)
+        req._emit(token, logprob)
         registry().counter(SERVING_TOKENS, "tokens generated").inc(1.0)
         return (len(req._tokens) >= req.max_new_tokens or
                 (req.eos_token_id is not None and

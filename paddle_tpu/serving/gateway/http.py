@@ -422,9 +422,9 @@ class _Handler(BaseHTTPRequestHandler):
 
     # -- streaming -----------------------------------------------------------
     def _write_chunk(self, data: bytes):
-        self.wfile.write(f"{len(data):x}\r\n".encode("ascii"))
-        self.wfile.write(data)
-        self.wfile.write(b"\r\n")
+        # one write, one system call: the socket file is unbuffered, and every
+        # call gives up the interpreter lock the scheduler thread waits for
+        self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
 
     def _end_chunks(self):
         self.wfile.write(b"0\r\n\r\n")

@@ -228,3 +228,84 @@ def test_paged_kernel_interprets_only_on_cpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "some-new-chip")
     assert pa._interpret_now() is False          # unknown is not cpu
     assert pa._INTERPRET is None                 # decided by backend, not pinned
+
+
+# -- grouped-query heads and sliding windows (ISSUE 27) ----------------------
+
+_GQA_FLASH = [  # (id, seq, window): 28 query / 4 KV heads of 128
+    ("smallthinker-global-16k", 16384, None),
+    ("smallthinker-window-16k", 16384, 4096),
+    ("smallthinker-window-1k", 1024, 4096),
+    ("smallthinker-global-256", 256, None),
+]
+
+
+@pytest.mark.parametrize("t,window", [c[1:] for c in _GQA_FLASH],
+                         ids=[c[0] for c in _GQA_FLASH])
+def test_flash_grouped_window_fwd_compiles(v5e, t, window):
+    """The prefill call of a decoder with 7 query heads per KV head: a grid
+    step holds one KV head's tile and its whole group of query heads."""
+    def fwd(q, k, v):
+        return fa.flash_attention_bthd(q, k, v, causal=True, window=window)
+
+    c = _compile(fwd, v5e, jax.ShapeDtypeStruct((1, t, 28, 128), bf16),
+                 jax.ShapeDtypeStruct((1, t, 4, 128), bf16),
+                 jax.ShapeDtypeStruct((1, t, 4, 128), bf16))
+    assert _mosaic_calls(c) == 1
+
+
+def test_flash_grouped_window_bwd_compiles(v5e):
+    def loss(q, k, v):
+        out = fa.flash_attention_bthd(q, k, v, causal=True, window=512)
+        return jnp.sum(out.astype(f32))
+
+    c = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), v5e,
+                 jax.ShapeDtypeStruct((1, 2048, 28, 128), bf16),
+                 jax.ShapeDtypeStruct((1, 2048, 4, 128), bf16),
+                 jax.ShapeDtypeStruct((1, 2048, 4, 128), bf16))
+    assert _mosaic_calls(c) >= 3          # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
+@pytest.mark.parametrize("W", [1, 4])
+def test_dense_decode_read_grouped_compiles(v5e, compiled_paged, window, W):
+    """`dense_decode_attention` at the SmallThinker cell's shape: 16 slots +
+    scratch x 16384, 28 query / 4 KV heads of 128; the block is 512
+    positions (4 x DENSE_BLOCK: a KV block of 4 heads holds the bytes of
+    128 positions of 16)."""
+    B, L, H, Hkv, D = 17, 16384, 28, 4, 128
+    blk = pa.dense_read_block(heads=H, kv_heads=Hkv, head_dim=D, dtype=bf16,
+                              width=W, max_len=L)
+    assert blk == 4 * pa.DENSE_BLOCK
+
+    def read(q, k, v, ln):
+        return pa.dense_decode_attention(q, k, v, ln, block=blk,
+                                         window=window)
+
+    pool = jax.ShapeDtypeStruct((B, L, Hkv, D), bf16)
+    c = _compile(read, v5e, jax.ShapeDtypeStruct((B, W, H, D), bf16), pool,
+                 pool, jax.ShapeDtypeStruct((B,), jnp.int32))
+    assert _mosaic_calls(c) == 1
+
+
+@pytest.mark.parametrize("tokens", [17, 16384], ids=["decode", "prefill"])
+def test_dropless_experts_compile_as_grouped_matmul(v5e, tokens):
+    """The expert layer at published widths: XLA:TPU lowers `ragged_dot` to
+    its grouped-matmul kernel from 128 rows on (the layer pads to that), so
+    a decode step's 102 assignments do not become 64 dense products."""
+    from paddle_tpu.incubate.distributed.models.moe.dropless import (
+        dropless_moe)
+    h, f, e = 2560, 768, 64
+
+    def layer(x, wr, wg, wu, wd):
+        return dropless_moe.raw(x, x, wr, wg, wu, wd, top_k=6)
+
+    c = _compile(layer, v5e, jax.ShapeDtypeStruct((tokens, h), bf16),
+                 jax.ShapeDtypeStruct((h, e), bf16),
+                 jax.ShapeDtypeStruct((e, h, f), bf16),
+                 jax.ShapeDtypeStruct((e, h, f), bf16),
+                 jax.ShapeDtypeStruct((e, f, h), bf16))
+    # the group metadata, then gate, up and down (once per chunk of tokens)
+    assert _mosaic_calls(c) == 4
+    ideal = 6.0 * (-(-tokens * 6 // 128) * 128) * h * f
+    assert c.cost_analysis()["flops"] < 1.5 * ideal

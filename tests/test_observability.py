@@ -171,6 +171,29 @@ def test_retrace_sentinel_fires_on_shape_polymorphic_jit(caplog):
     assert payload["fn"] == "poly_fn" and payload["compiles"] == 4
 
 
+@pytest.mark.parametrize("other, same", [
+    ("numpy", True),          # a host array of the same shape and dtype
+    ("shape", False), ("dtype", False), ("tree", False), ("static", False)])
+def test_signature_tells_calls_apart_by_shape_dtype_and_tree(other, same):
+    """The sentinel's key of a call, computed on every dispatch: equal for
+    the same tree of (shape, dtype) whoever holds the arrays, another one as
+    soon as a shape, a dtype, the tree or a static leaf differs; its `str()`
+    (what a storm logs) names the shapes."""
+    import jax.numpy as jnp
+    a = {"w": jnp.ones((2, 3), jnp.bfloat16), "b": [jnp.zeros(4, jnp.int32)]}
+    calls = {
+        "numpy": ({"w": np.ones((2, 3), jnp.bfloat16),
+                   "b": [np.zeros(4, np.int32)]}, 7),
+        "shape": ({**a, "w": jnp.ones((2, 4), jnp.bfloat16)}, 7),
+        "dtype": ({**a, "w": jnp.ones((2, 3), jnp.float32)}, 7),
+        "tree": ({**a, "b": (a["b"][0],)}, 7),
+        "static": (a, 8)}
+    key = retrace._abstract_signature((a, 7))
+    got = retrace._abstract_signature(calls[other])
+    assert (got == key and hash(got) == hash(key)) is same
+    assert "(2, 3)" in str(key) and "bfloat16" in str(key)
+
+
 def test_train_step_compiles_once_and_counts_steps(caplog):
     """Acceptance: a 3-step GPT-small CPU train loop records exactly ONE
     compile for the train step (zero steady-state retraces), nonzero

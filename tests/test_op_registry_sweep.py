@@ -328,6 +328,15 @@ spec("sparse_attention",
               np.tile(np.tile(np.arange(4), 4), (1, 1, 1)).astype(np.int64)),
      grad=False)
 
+spec("rms_norm", lambda: (F(2, 3, 4), F(4)))
+spec("rotary_embedding", lambda: (F(2, 3, 2, 4), I64(1, 3, hi=8)),
+     {"theta": 100.0})
+# routing is piecewise constant in the logits: forward only here, the
+# values are held to the reference in tests/test_decoder_smallthinker.py
+spec("dropless_moe",
+     lambda: (F(5, 8), F(5, 8), F(8, 4), F(4, 8, 6), F(4, 8, 6), F(4, 6, 8)),
+     {"top_k": 2}, grad=False)
+
 # ops exercised via dedicated test files, not callable with simple
 # positional tensors here (reason recorded so the sweep stays exhaustive)
 SKIP = {}

@@ -389,8 +389,8 @@ def test_dense_engine_through_the_kernel(tiny_gpt, monkeypatch, kv_dtype,
 
     base, st0, blk0 = run()
     assert blk0 is None
-    assert (st0["decode_kv_read_positions"] ==
-            st0["decode_steps"] * 4 * 64)           # 3 slots + scratch
+    assert (st0["decode_kv_read_positions"] ==        # summed over layers
+            st0["decode_steps"] * cfg.num_layers * 4 * 64)  # 3 slots + scratch
     monkeypatch.setattr(pa, "DENSE_BLOCK", 16)
     pa.use_interpret_mode(True)
     got, st1, blk1 = run()
