@@ -72,6 +72,7 @@ concurrently with in-flight requests.
 from __future__ import annotations
 
 import atexit
+import functools
 import itertools
 import os
 import threading
@@ -138,6 +139,8 @@ SERVING_HOST_PREFIX_PROMOTE_SECONDS = \
 SERVING_MOE_ASSIGNMENTS = "paddle_tpu_serving_moe_assignments_total"
 SERVING_MOE_EXPERTS_TOUCHED = "paddle_tpu_serving_moe_experts_touched_total"
 SERVING_MOE_LOAD_MAX = "paddle_tpu_serving_moe_load_max_total"
+SERVING_DECODE_SAMPLED_STEPS = "paddle_tpu_serving_decode_sampled_steps_total"
+SERVING_DECODE_TOPK_STEPS = "paddle_tpu_serving_decode_topk_steps_total"
 
 
 class QueueFullError(RuntimeError):
@@ -356,6 +359,56 @@ def _sample_row(logits_row: np.ndarray, temperature: float, top_k: int,
     return int(rng.choice(len(p), p=p))
 
 
+def _sample_rows(lg, temps, topks, keys, positions, live):
+    """Device sampler, one row each: greedy at temp 0, else temperature +
+    optional top-k via Gumbel-max (categorical sampling without
+    materializing probabilities).  It branches on what its ``live`` rows ask
+    for: the draw runs only in a step where one of them has ``temp > 0``,
+    the sort behind the top-k mask only where such a row also has
+    ``top_k > 0`` (then every row sorts).  Both predicates are scalars over
+    the batch, taken outside the ``vmap`` — a batched predicate would lower
+    to a select that runs every side — so a step of greedy rows costs the
+    argmax alone.  ``live`` keeps a freed slot's leftover parameters out."""
+    import jax
+    import jax.numpy as jnp
+    greedy = jnp.argmax(lg, axis=-1)
+    hot = live & (temps > 0)
+
+    def draw(mask_topk: bool):
+        def row(l_row, temp, k, key):
+            l32 = l_row.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
+            if mask_topk:
+                v = l_row.shape[-1]
+                srt = jnp.sort(l32)                 # ascending
+                kth = srt[jnp.clip(v - k, 0, v - 1)]
+                l32 = jnp.where((k <= 0) | (l32 >= kth), l32, -1e30)
+            g = jax.random.gumbel(key, l32.shape, jnp.float32)
+            return jnp.argmax(l32 + g)
+
+        # counter-based per-draw keys: the row's base key folded with the
+        # position its logits sit at — stateless, so no key state ever
+        # returns to the host, and the draw for 'token after position p' is
+        # identical whichever path (cold prefill, tail prefill, decode)
+        # produced it
+        step_keys = jax.vmap(jax.random.fold_in)(keys, positions)
+        sampled = jax.vmap(row)(lg, temps, topks, step_keys)
+        return jnp.where(temps > 0, sampled, greedy)
+
+    which = (jnp.any(hot).astype(jnp.int32) +
+             jnp.any(hot & (topks > 0)).astype(jnp.int32))
+    return jax.lax.switch(which, [lambda: greedy,
+                                  functools.partial(draw, False),
+                                  functools.partial(draw, True)])
+
+
+def _sampling_rows(batch) -> tuple[int, int]:
+    """Of one admission wave: the requests that draw (`temperature > 0`) and
+    those of them that mask (`top_k > 0`) — what the device sampler's two
+    branches of that wave's prefill program turn on."""
+    hot = [r for r in batch if r.temperature > 0]
+    return len(hot), sum(r.top_k > 0 for r in hot)
+
+
 def _trunk(model):
     """The model's transformer stack where it exposes trunk + head (`.gpt`
     of the GPT family, `.decoder` of models/decoder.py), else None: its
@@ -489,7 +542,14 @@ class Engine:
             only ``[B(, k)]`` token ids cross the host boundary per step.
             False restores the host sampler (``_sample_row``) — the
             per-request numpy RNG stream, at a ``[B, V]`` logits transfer
-            per step.
+            per step.  The device sampler branches on what its live rows
+            ask for (``_sample_rows``): all greedy, a step runs the
+            argmax alone; a live row with ``temperature > 0`` turns on
+            the Gumbel draw, and only one that also has ``top_k > 0`` the
+            full-vocabulary sort (a freed slot's leftover parameters
+            count for nothing: parked rows are masked out).  ``stats()``
+            ``decode_sampled_steps`` / ``decode_topk_steps`` count the
+            decode steps that took each branch.
         adapters: an :class:`~paddle_tpu.serving.adapters.AdapterRegistry`
             — serve many LoRA-fine-tuned variants of the base model from
             this one engine (docs/serving.md "Multi-LoRA serving"):
@@ -735,6 +795,7 @@ class Engine:
                         "prefill_tokens": 0, "prefill_padded_tokens": 0,
                         "decode_kv_live_positions": 0,
                         "decode_kv_read_positions": 0,
+                        "decode_sampled_steps": 0, "decode_topk_steps": 0,
                         "moe_assignments": 0, "moe_experts_touched": 0,
                         "moe_load_max": 0,
                         "tokens": 0, "resubmitted": 0, "redispatched": 0,
@@ -1493,33 +1554,6 @@ class Engine:
                 logits = lg._value
             return logits, new_caches
 
-        def _sample_rows(lg, temps, topks, keys):
-            """Device sampler, one row each: greedy at temp 0, else
-            temperature + optional top-k via Gumbel-max (categorical
-            sampling without materializing probabilities)."""
-            greedy = jnp.argmax(lg, axis=-1)
-
-            def row(l_row, temp, k, key):
-                l32 = l_row.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
-                v = l_row.shape[-1]
-                srt = jnp.sort(l32)                 # ascending
-                kth = srt[jnp.clip(v - k, 0, v - 1)]
-                keep = (k <= 0) | (l32 >= kth)
-                masked = jnp.where(keep, l32, -1e30)
-                g = jax.random.gumbel(key, masked.shape, jnp.float32)
-                return jnp.argmax(masked + g)
-
-            sampled = jax.vmap(row)(lg, temps, topks, keys)
-            return jnp.where(temps > 0, sampled, greedy)
-
-        def _step_keys(keys, positions):
-            """Counter-based per-draw keys: fold the row's base key with
-            the position its logits sit at — stateless, so no key state
-            ever returns to the host, and the draw for 'token after
-            position p' is identical whichever path (cold prefill, tail
-            prefill, decode) produced it."""
-            return jax.vmap(jax.random.fold_in)(keys, positions)
-
         park = self._park
 
         def _tail_valid(ids, lengths, gather_idx):
@@ -1572,8 +1606,8 @@ class Engine:
                            for vp, c in zip(vpools_, new_caches)]
                 pools = (kpools_, vpools_)
             if on_device:
-                toks = _sample_rows(logits, temps, topks,
-                                    _step_keys(keys, prompt_lens - 1))
+                toks = _sample_rows(logits, temps, topks, keys,
+                                    prompt_lens - 1, slot_idx < n_rows - 1)
                 return _pack(toks, load, logits), pools
             return _pack(logits, load), pools
 
@@ -1627,8 +1661,8 @@ class Engine:
                            for vp, c in zip(vpools_, new_caches)]
                 pools = (kpools_, vpools_)
             if on_device:
-                toks = _sample_rows(logits, temps, topks,
-                                    _step_keys(keys, prompt_lens - 1))
+                toks = _sample_rows(logits, temps, topks, keys,
+                                    prompt_lens - 1, tables[:, 0] < NP_)
                 return _pack(toks, load, logits), pools
             return _pack(logits, load), pools
 
@@ -1646,8 +1680,8 @@ class Engine:
             pools = _pools_from(new_caches)
             if on_device:
                 greedy = jnp.argmax(logits, axis=-1)
-                first = _sample_rows(logits[:, 0], temps, topks,
-                                     _step_keys(keys, lengths))
+                first = _sample_rows(logits[:, 0], temps, topks, keys,
+                                     lengths, lengths < park)
                 toks = greedy.at[:, 0].set(first)
                 return _pack(toks, load, logits), pools
             return _pack(logits, load), pools
@@ -1662,8 +1696,8 @@ class Engine:
                     gather_idx=gather_idx)
             pools = _pools_from(new_caches)
             if on_device:
-                toks = _sample_rows(logits, temps, topks,
-                                    _step_keys(keys, lengths + gather_idx))
+                toks = _sample_rows(logits, temps, topks, keys,
+                                    lengths + gather_idx, lengths < park)
                 return _pack(toks, load, logits), pools
             return _pack(logits, load), pools
 
@@ -1693,8 +1727,8 @@ class Engine:
             pools = _pools_from(new_caches)
             if on_device:
                 greedy = jnp.argmax(logits, axis=-1)        # [B, W]
-                first = _sample_rows(logits[:, 0], temps, topks,
-                                     _step_keys(keys, lengths))
+                first = _sample_rows(logits[:, 0], temps, topks, keys,
+                                     lengths, lengths < park)
                 toks = greedy.at[:, 0].set(first)
                 return _pack(toks, load, logits), pools
             return _pack(logits, load), pools
@@ -1712,8 +1746,8 @@ class Engine:
                     gather_idx=gather_idx)
             pools = _pools_from(new_caches)
             if on_device:
-                toks = _sample_rows(logits, temps, topks,
-                                    _step_keys(keys, lengths + gather_idx))
+                toks = _sample_rows(logits, temps, topks, keys,
+                                    lengths + gather_idx, lengths < park)
                 return _pack(toks, load, logits), pools
             return _pack(logits, load), pools
 
@@ -2492,12 +2526,14 @@ class Engine:
                          min(8, self._limit), self._limit)
         P = self.prefill_batch
         prompt_tokens = sum(int(r.prompt.size) for r in batch)
+        sampled, topk = _sampling_rows(batch)
         with span("serving.prefill", n=len(batch), bucket=bucket):
             try:
                 with phase("serving.prefill.dispatch", rows=len(batch),
                            batch_rows=P, bucket=bucket,
                            prompt_tokens=prompt_tokens,
-                           padded_tokens=P * bucket):
+                           padded_tokens=P * bucket,
+                           sampled=sampled, topk=topk):
                     (ids, slot_idx, plens, temps, topks, keys, aid_rows,
                      tables) = self._prefill_rows(batch, bucket)
                     t0 = time.perf_counter()
@@ -2586,6 +2622,7 @@ class Engine:
         tails = [r.prompt.size - r._prefix_match for r in hits]
         tail_tokens = int(sum(tails))
         tb = _bucket(max(tails), 1, self._limit)
+        sampled, topk = _sampling_rows(hits)
         with span("serving.tail_prefill", n=len(hits), bucket=tb):
             try:
                 # `serving.prefix_copy`, the copy program's dispatch, is a
@@ -2593,7 +2630,8 @@ class Engine:
                 with phase("serving.tail_prefill.dispatch", rows=len(hits),
                            batch_rows=n_rows, bucket=tb,
                            prompt_tokens=tail_tokens,
-                           padded_tokens=n_rows * tb):
+                           padded_tokens=n_rows * tb,
+                           sampled=sampled, topk=topk):
                     (src, dst, n_copy, cow_ids, ids, lens, gidx, tables,
                      aids_snap) = self._tail_rows(hits, tb)
                     t0 = time.perf_counter()
@@ -2775,9 +2813,12 @@ class Engine:
                 (drafts, ids, lengths, temps, topks, keys, aids,
                  tables) = self._decode_inputs(active)
                 kv_live, kv_read = self._decode_kv_positions(active, lengths)
+                sampled, topk = self._decode_sampling_rows(lengths, temps,
+                                                           topks)
             try:
                 with phase("serving.decode.dispatch", active=len(active),
-                           kv_live=kv_live, kv_read=kv_read):
+                           kv_live=kv_live, kv_read=kv_read,
+                           sampled=sampled, topk=topk):
                     t0 = time.perf_counter()
                     faults.fault_point("serving.decode", active=len(active))
                     if self._decode_timeout_s is not None:
@@ -2900,6 +2941,32 @@ class Engine:
             self._counts["decode_kv_live_positions"] += kv_live
             self._counts["decode_kv_read_positions"] += kv_read
         return kv_live, kv_read
+
+    def _decode_sampling_rows(self, lengths, temps, topks):
+        """The live rows of one decode dispatch that draw (`sampled`:
+        `temperature > 0`) and those of them that mask (`topk`:
+        `top_k > 0`) — the device sampler's two predicates (`_sample_rows`)
+        over the same snapshot; a freed slot keeps its last request's
+        parameters and is parked, so it counts on neither side."""
+        hot = (lengths < self._park) & (temps > 0)
+        sampled = int(hot.sum())
+        if not sampled:
+            return 0, 0
+        topk = int((hot & (topks > 0)).sum())
+        if not self.sample_on_device:    # the host sampler: no branch taken
+            return sampled, topk
+        with self._lock:
+            self._counts["decode_sampled_steps"] += 1
+            self._counts["decode_topk_steps"] += bool(topk)
+        reg = registry()
+        reg.counter(SERVING_DECODE_SAMPLED_STEPS,
+                    "decode steps whose sampler drew (a live row with "
+                    "temperature > 0)").inc(1.0)
+        if topk:
+            reg.counter(SERVING_DECODE_TOPK_STEPS,
+                        "decode steps whose sampler sorted (a live drawing "
+                        "row with top_k > 0)").inc(1.0)
+        return sampled, topk
 
     def _decode_inputs(self, active: dict):
         """`serving.decode.build`: the drafts and the locked snapshot of

@@ -397,6 +397,140 @@ def test_device_sampling_parity_vs_eager_reference(tiny_gpt):
     np.testing.assert_array_equal(a, ref)
 
 
+def _prims(jaxpr, in_cond=False):
+    """(primitive name, under a conditional?) of every equation, nested
+    programs included."""
+    import jax
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        yield name, in_cond
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _prims(sub, in_cond or name == "cond")
+
+
+def _decode_calls(eng, submit):
+    """The argument tuples of every decode dispatch `submit()` causes, and
+    the decode program (the engine must have built it already)."""
+    fn = eng._decode_fn
+    program, calls = fn._fn, []
+    fn._fn = lambda *a: (calls.append(a), program(*a))[1]
+    try:
+        out = submit()
+    finally:
+        fn._fn = program
+    return out, calls, program
+
+
+def _sampler_branch(program, args):
+    """The branch index the decode program's sampler computes for `args`:
+    the traced program up to its conditional, evaluated."""
+    import jax
+    closed = program.trace(*args).jaxpr
+    eqns = closed.jaxpr.eqns
+    at, = [i for i, e in enumerate(eqns) if e.primitive.name == "cond" and
+           any(n == "sort" for n, _ in _prims(e.params["branches"][-1]))]
+    head = closed.jaxpr.replace(eqns=eqns[:at],
+                                outvars=[eqns[at].invars[0]])
+    flat = jax.tree_util.tree_leaves(args)
+    return int(jax.core.eval_jaxpr(head, closed.consts, *flat)[0])
+
+
+@pytest.mark.parametrize("kw", [{}, {"paged_kv": True, "page_size": 8},
+                                {"speculative_k": 3}, {"kv_dtype": "int8"}],
+                         ids=["dense", "paged", "speculative", "int8"])
+def test_decode_program_sorts_and_draws_only_under_a_conditional(tiny_gpt,
+                                                                 kw):
+    """The one decode program of an engine holds the sampler's sort and
+    its random bits inside conditional branches only: a step of greedy
+    rows runs neither (the predicate is a scalar, outside the vmap)."""
+    model, cfg = tiny_gpt
+    p = np.arange(5, 13).astype(np.int64)
+    eng = Engine(model, max_slots=2, max_len=64, **kw)
+    eng.submit(p, max_new_tokens=2).result(timeout=300)     # builds
+    _, calls, program = _decode_calls(
+        eng, lambda: eng.submit(p, max_new_tokens=3).result(timeout=300))
+    st = eng.stats()
+    eng.shutdown()
+    seen = list(_prims(program.trace(*calls[0]).jaxpr.jaxpr))
+    for prim in ("sort", "random_bits"):
+        where = [in_cond for name, in_cond in seen if name == prim]
+        assert where and all(where), (prim, where)
+    assert {_sampler_branch(program, a) for a in calls} == {0}
+    assert st["decode_compiles"] == 1
+    assert st["decode_sampled_steps"] == st["decode_topk_steps"] == 0
+
+
+@pytest.mark.parametrize("kw", [{}, {"paged_kv": True, "page_size": 8}],
+                         ids=["dense", "paged"])
+def test_mixed_sampling_batch_gives_each_row_its_own_tokens(tiny_gpt, kw):
+    """A greedy row, a temperature-only row and a temperature + top-k row
+    decoding together get the tokens each gets alone at the same seed; the
+    step counters say which branch the shared program took."""
+    model, cfg = tiny_gpt
+    rs = np.random.RandomState(12)
+    prompts = [rs.randint(0, cfg.vocab_size, 7).astype(np.int64)
+               for _ in range(3)]
+    params = [dict(), dict(temperature=0.8, seed=5),
+              dict(temperature=0.9, top_k=8, seed=11)]
+    eng = Engine(model, max_slots=4, max_len=64, **kw)
+    alone, steps = [], []
+    for p, kw_ in zip(prompts, params):
+        before = eng.stats()
+        alone.append(eng.submit(p, max_new_tokens=8, **kw_)
+                        .result(timeout=300))
+        after = eng.stats()
+        steps.append([after[k] - before[k] for k in
+                      ("decode_steps", "decode_sampled_steps",
+                       "decode_topk_steps")])
+    # alone: greedy draws in no step, temperature-only sorts in none
+    n, = {s[0] for s in steps}
+    assert steps == [[n, 0, 0], [n, n, 0], [n, n, n]]
+    before = eng.stats()
+    handles = [eng.submit(p, max_new_tokens=8, **kw_)
+               for p, kw_ in zip(prompts, params)]
+    together = [h.result(timeout=300) for h in handles]
+    st = eng.stats()
+    eng.shutdown()
+    for a, t in zip(alone, together):
+        np.testing.assert_array_equal(a, t)
+    assert len({tuple(o) for o in together}) == 3
+    grew = st["decode_steps"] - before["decode_steps"]
+    assert 0 < st["decode_topk_steps"] - before["decode_topk_steps"] <= grew
+    assert st["decode_compiles"] == 1
+
+
+def test_stale_slot_parameters_do_not_switch_the_sort_on(tiny_gpt):
+    """A freed slot keeps its last request's temperature and top-k.  A
+    greedy request decoding beside such slots (and in one of them) takes
+    the cheap branch on the device and on the counters, and matches the
+    host sampler."""
+    model, cfg = tiny_gpt
+    rs = np.random.RandomState(8)
+    a, b, c = (rs.randint(0, cfg.vocab_size, 7).astype(np.int64)
+               for _ in range(3))
+    eng = Engine(model, max_slots=2, max_len=64)
+    hs = [eng.submit(p, max_new_tokens=4, temperature=0.9, top_k=8, seed=2)
+          for p in (a, b)]
+    for h in hs:
+        h.result(timeout=300)
+    before = eng.stats()
+    assert before["decode_topk_steps"] > 0
+    assert (eng._temps[:2] > 0).all() and (eng._topks[:2] > 0).all()
+    got, calls, program = _decode_calls(
+        eng, lambda: eng.submit(c, max_new_tokens=6).result(timeout=300))
+    st = eng.stats()
+    assert (eng._temps[:2] > 0).any()           # one slot is still stale
+    eng.shutdown()
+    assert calls and {_sampler_branch(program, x) for x in calls} == {0}
+    for k in ("decode_sampled_steps", "decode_topk_steps"):
+        assert st[k] == before[k], k
+    assert st["decode_compiles"] == 1
+    host = Engine(model, max_slots=2, max_len=64, sample_on_device=False)
+    want = host.submit(c, max_new_tokens=6).result(timeout=300)
+    host.shutdown()
+    np.testing.assert_array_equal(got, want)
+
+
 # -- composition + telemetry -------------------------------------------------
 
 def test_all_flags_compose_one_decode_signature(tiny_gpt):

@@ -229,6 +229,37 @@ def test_engine_options_keep_the_greedy_tokens(tiny, kw):
     assert st["moe_assignments"] > 0
 
 
+def test_engine_mixed_sampling_rows_share_one_program(tiny):
+    """ISSUE 28: a greedy, a temperature-only and a top-k request decoding
+    together on this model get the tokens each gets alone at its seed, the
+    greedy row's are the reference's, and the step counters name the branch
+    the one decode program took (greedy steps draw and sort nothing)."""
+    model, cfg = tiny
+    prompts = _prompts((5, 12, 20), seed=4)
+    params = [{}, {"temperature": 0.8, "seed": 7},
+              {"temperature": 0.9, "top_k": 6, "seed": 9}]
+    keys = ("decode_steps", "decode_sampled_steps", "decode_topk_steps")
+    eng = Engine(model, max_slots=3, max_len=64, prefill_batch=2)
+    try:
+        alone, steps = [], []
+        for p, kw in zip(prompts, params):
+            st0 = eng.stats()
+            alone.append(eng.submit(p, max_new_tokens=12, **kw)
+                            .result(timeout=600))
+            steps.append([eng.stats()[k] - st0[k] for k in keys])
+        hs = [eng.submit(p, max_new_tokens=12, **kw)
+              for p, kw in zip(prompts, params)]
+        together = [h.result(timeout=600) for h in hs]
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert steps == [[11, 0, 0], [11, 11, 0], [11, 11, 11]]
+    for a, t in zip(alone, together):
+        np.testing.assert_array_equal(a, t)
+    assert _deficit(model, cfg, prompts[:1], together[:1]) == (True, 0.0)
+    assert st["decode_compiles"] == 1
+
+
 def test_engine_prefix_cache_prefills_the_tail_only(tiny):
     """A shared 16-token head: the second wave copies the cached rows and
     prefills tails through the per-slot branch (window mask, RoPE at the

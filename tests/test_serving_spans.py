@@ -76,9 +76,11 @@ def _traced(trace_dir, body):
     return _host_events(str(trace_dir))
 
 
-def _run(model, cfg, tmp, prompts, **engine_kw):
+def _run(model, cfg, tmp, prompts, params=None, **engine_kw):
     """One traced stretch of an engine: what the profiler, the span ring,
-    the flight ring and `Engine.stats()` each saw of it."""
+    the flight ring and `Engine.stats()` each saw of it (`params`: each
+    prompt's sampling arguments; greedy without)."""
+    params = params or [{}] * len(prompts)
     eng = Engine(model, max_slots=4, max_len=64, max_queue=32, **engine_kw)
     try:
         # build the pool and compile outside the stretch under test
@@ -97,7 +99,8 @@ def _run(model, cfg, tmp, prompts, **engine_kw):
             # twentieth of it: stretch a step to the ~30 ms it has on a chip
             with faults.inject("serving.decode", mode="delay", seconds=0.02,
                                times=None):
-                hs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+                hs = [eng.submit(p, max_new_tokens=5, **kw)
+                      for p, kw in zip(prompts, params)]
                 for h in hs:
                     h.result(timeout=300)
         r.path, r.events = _traced(tmp, body)
@@ -106,7 +109,8 @@ def _run(model, cfg, tmp, prompts, **engine_kw):
                    for k in ("prefill_batches", "prefill_tokens",
                              "prefill_padded_tokens", "decode_steps",
                              "decode_kv_live_positions",
-                             "decode_kv_read_positions")}
+                             "decode_kv_read_positions",
+                             "decode_sampled_steps", "decode_topk_steps")}
         r.flight_spans = _span_begins_since(flight0)
         r.ring = trace.spans()
         r.prefill_batch = eng.prefill_batch
@@ -135,6 +139,25 @@ def hits(tiny_gpt, tmp_path_factory):
         for _ in range(4)]
     return _run(model, cfg, tmp_path_factory.mktemp("hits"), prompts,
                 prefix_cache=True, prefix_block=8)
+
+
+SAMPLED_PARAMS = [{}, {"temperature": 0.8, "seed": 1},
+                  {"temperature": 0.9, "top_k": 8, "seed": 2}, {},
+                  {"temperature": 0.7, "top_k": 4, "seed": 3}]
+
+
+@pytest.fixture(scope="module")
+def sampled(tiny_gpt, tmp_path_factory):
+    """Greedy, temperature-only and top-k requests side by side, cold and
+    through the prefix-hit path."""
+    model, cfg = tiny_gpt
+    rs = np.random.RandomState(2)
+    head = rs.randint(0, cfg.vocab_size, 16)
+    prompts = [np.concatenate(
+        [head, rs.randint(0, cfg.vocab_size, rs.randint(2, 9))])
+        for _ in SAMPLED_PARAMS]
+    return _run(model, cfg, tmp_path_factory.mktemp("sampled"), prompts,
+                params=SAMPLED_PARAMS, prefix_cache=True, prefix_block=8)
 
 
 def _flight_mark() -> int:
@@ -235,6 +258,36 @@ def test_decode_dispatch_kv_counts_agree_with_stats(run, request):
             r.delta["decode_kv_live_positions"])
     assert (sum(e[3]["kv_read"] for e in dec) ==
             r.delta["decode_kv_read_positions"])
+
+
+@pytest.mark.parametrize("run", ["cold", "hits", "sampled"])
+def test_dispatch_sampling_counts_agree_with_stats(run, request):
+    """ISSUE 28: `sampled` / `topk` on every dispatch phase are the live
+    rows that draw and those of them that mask; the decode steps in which
+    either is above 0 are `Engine.stats()` `decode_sampled_steps` /
+    `decode_topk_steps` — the steps in which the device sampler leaves its
+    greedy branch."""
+    r = request.getfixturevalue(run)
+    dec = _named(r.events, "serving.decode.dispatch")
+    assert len(dec) == r.delta["decode_steps"] > 0
+    for e in dec:
+        assert 0 <= e[3]["topk"] <= e[3]["sampled"] <= e[3]["active"]
+    assert (sum(e[3]["sampled"] > 0 for e in dec) ==
+            r.delta["decode_sampled_steps"])
+    assert (sum(e[3]["topk"] > 0 for e in dec) ==
+            r.delta["decode_topk_steps"])
+    pre = (_named(r.events, "serving.prefill.dispatch") +
+           _named(r.events, "serving.tail_prefill.dispatch"))
+    want = SAMPLED_PARAMS if run == "sampled" else []
+    assert (sum(e[3]["sampled"] for e in pre) ==
+            sum("temperature" in kw for kw in want))
+    assert sum(e[3]["topk"] for e in pre) == sum("top_k" in kw for kw in want)
+    if run == "sampled":
+        assert _named(r.events, "serving.tail_prefill.dispatch")
+        assert 0 < r.delta["decode_topk_steps"] <= \
+            r.delta["decode_sampled_steps"] <= r.delta["decode_steps"]
+    else:
+        assert r.delta["decode_sampled_steps"] == 0
 
 
 def test_tail_dispatch_counts_agree_with_stats(hits):
