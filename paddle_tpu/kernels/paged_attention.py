@@ -50,20 +50,19 @@ tier-1 parity gates; :func:`use_interpret_mode` pins it either way);
 on every other backend it is compiled by Mosaic, and what Mosaic
 cannot take is refused by :func:`check_supported` when the engine is
 built (tests/test_kernels_tpu_aot.py keeps the accepted matrix
-compiling between chip runs).  The serving engine routes decode through
-here only inside :func:`decode_kernel_scope` (``Engine(decode_kernel="pallas")``), the
-same trace-local mechanism the multi-LoRA adapter path uses.
+compiling between chip runs).  The serving engine chooses the decode
+program's read once, when it is built, and hands it to the model as the
+static ``read`` field of the layer caches (`models/kv_cache.py`
+``KernelRead``): this kernel for ``Engine(decode_kernel="pallas")``.
 
 The **dense** pool's decode read (:func:`dense_decode_attention`, at the
-end of this file) shares the scope and the interpret-mode rule: one pass
-over each row's live blocks, routed by :func:`dense_read_block`.
+end of this file) shares the interpret-mode rule: one pass over each row's
+live blocks, chosen where :func:`dense_read_block` says it applies.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
-import threading
 
 import jax
 import jax.numpy as jnp
@@ -115,32 +114,6 @@ def check_supported(*, page_size: int, max_pages_per_slot: int, heads: int,
             f"{scratch / 2**20:.0f} MiB (x2 for the softmax), over the "
             f"{_VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit — use a larger "
             f"page_size (fewer, fuller pages) or a shorter virtual length")
-
-
-# -- trace-local routing scope ------------------------------------------------
-#
-# The engine enters this scope inside its decode jit (and only there), so
-# the model's paged cache branch routes its attention read through the
-# kernel for exactly that program — prefill/tail-prefill keep the XLA
-# read, and the decode signature count stays at ONE per config (the scope
-# is a trace-time routing decision, not an operand).
-
-_TLS = threading.local()
-
-
-@contextlib.contextmanager
-def decode_kernel_scope():
-    prev = getattr(_TLS, "active", False)
-    _TLS.active = True
-    try:
-        yield
-    finally:
-        _TLS.active = prev
-
-
-def active() -> bool:
-    """True while tracing inside :func:`decode_kernel_scope`."""
-    return getattr(_TLS, "active", False)
 
 
 # -- analytic cost registration (observability/perfscope.py) ------------------
@@ -261,7 +234,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         lengths: ``[B]`` int32 per-row start positions (parked rows sit
             at ``n_pt * page_size``).
         k_scale / v_scale: ``[num_pages, page_size]`` f32 absmax scales,
-            required iff the pools are int8 (serving/kv_quant.py).
+            required iff the pools are int8 (models/kv_cache.py).
 
     Returns:
         ``[B, W, heads, head_dim]`` attention output in ``q.dtype``.

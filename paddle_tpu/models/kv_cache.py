@@ -17,40 +17,62 @@ returns ``(out [B, t, heads, head_dim], new_cache)``.  The cache forms:
   under an explicit validity mask: every decode step is ONE compiled program
   with donated buffers (the AnalysisPredictor zero-copy run analog,
   analysis_predictor.cc:1618).  A python-int ``length == 0`` is the static
-  prefill: no past, the prompt keeps the causal flash path.
-* PER-SLOT (continuous batching, serving.Engine): ``length`` is a ``[B]``
-  vector — every row owns a slot in a shared pool and sits at its own
-  position, so the new keys/values scatter to per-row offsets and attention
-  runs under a per-row validity mask.  Rows whose write would fall off the
-  buffer end (an inactive slot parked at max_len) are dropped by the
-  scatter, never clipped onto a live row.  t may be > 1 (speculative
-  verification / prefix-tail prefill): position j of a row writes at its own
-  offset + j and attends causally within the new span.
-* The 5-tuple ``(k_buf, v_buf, lengths, k_scale, v_scale)`` is the
-  int8-quantized pool (serving kv_dtype="int8"): buffers store int8, scales
-  ``[B, L]`` carry one absmax scale per cached row; writes quantize, the
-  attention read dequantizes inline (kv_quant helpers).
-* The PAGED forms (serving paged_kv=True) add an int32 page table at index
-  3: 4-tuple ``(k_pages, v_pages, lengths, page_table)`` and 6-tuple
-  ``(..., k_scale, v_scale)``.  K/V live as ``[num_pages, page_size,
-  kv_heads, head_dim]`` pages; position p of row b maps to
-  ``pages[page_table[b, p // P], p % P]``.  Writes scatter through the table
-  (sentinel/out-of-range entries DROP — unallocated virtual positions are
-  unwritable), reads gather the row's pages back into a ``[B, L_virt, ...]``
-  view under the same validity mask as the dense pool — the page table is
-  just one more fixed-shape operand, so decode keeps its ONE compiled
-  signature.
+  prefill: no past, the prompt keeps the causal flash path.  A ``length``
+  that is a ``[B]`` vector is the dense, unquantised :class:`SlotCache`
+  spelled as a triple (triple in, triple out).
+* :class:`SlotCache` — PER-SLOT (continuous batching, serving.Engine): every
+  row owns a slot of a shared :class:`KVPool` and sits at its own position.
+  The type names what the engine decided for the pool: the layout (``dense``
+  rows, or ``paged`` through an int32 page table), the precision (int8
+  storage when it carries scale sidecars) and the decode read (the masked
+  XLA read, or a Pallas kernel).
 
 `cache_positions(cache, t)` gives the positions of the ``t`` new tokens of a
 layer's cache in any of these forms (learned position ids, RoPE angles).
+
+int8 storage (``Engine(kv_dtype="int8")``): one float32 scale per *cached
+position* (the absmax over that position's ``[kv_heads, head_dim]`` vector),
+so a new token's K/V is quantised against its OWN absmax at write time,
+nothing resident ever rescales and the pool update stays a pure scatter.
+The paged pool keeps the same granularity as a ``[num_pages, page_size]``
+sidecar written by the scatter that writes the int8 page, so a page shared by
+reference (prefix COW) shares its scales, and the quantised paged pool's
+values are bitwise the quantised dense pool's.  Symmetric absmax int8 keeps
+the worst per-element error at ``absmax / 254``.
 """
 from __future__ import annotations
+
+import dataclasses
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..nn import functional as F
+
+INT8_MAX = 127.0
+# floor for the per-row scale: an all-zero row (unwritten pool padding)
+# quantizes to zeros with a tiny finite scale instead of dividing by 0
+_SCALE_EPS = 1e-8
+
+
+def quantize_rows(x, eps: float = _SCALE_EPS):
+    """``x [..., heads, head_dim]`` float → ``(q int8 same shape,
+    scales [...] float32)``: symmetric absmax over the trailing two dims,
+    one scale per leading index (= per cached row position)."""
+    amax = jnp.max(jnp.abs(x), axis=(-2, -1))
+    scale = jnp.maximum(amax.astype(jnp.float32) / INT8_MAX, eps)
+    q = jnp.clip(jnp.round(x / scale[..., None, None].astype(x.dtype)),
+                 -INT8_MAX, INT8_MAX).astype(jnp.int8)
+    return q, scale
+
+
+def dequantize_pool(q, scale, dtype):
+    """Inverse of :func:`quantize_rows`: ``q [..., heads, head_dim]`` int8
+    + ``scale [...]`` → float ``dtype``.  Runs inside the attention read,
+    so XLA fuses it with the QK^T consumer — HBM sees int8 bytes."""
+    return q.astype(dtype) * scale[..., None, None].astype(dtype)
 
 
 def _raw(x):
@@ -59,22 +81,6 @@ def _raw(x):
 
 def _wrap(x):
     return Tensor(x, _internal=True)
-
-
-def cache_positions(cache, t: int):
-    """int64 positions ``[B or 1, t]`` of the ``t`` new tokens: from 0 with
-    no cache, offset by the cached key length otherwise (a python int, a
-    traced scalar or the per-slot ``[B]`` vector of the static forms; the
-    buffer length of a growing-concat pair)."""
-    if cache is None:
-        past = 0
-    elif len(cache) in (3, 4, 5, 6):
-        past = jnp.asarray(cache[2], jnp.int64)
-        if past.ndim == 1:        # per-slot: each row at its own position
-            return past[:, None] + jnp.arange(t, dtype=jnp.int64)
-    else:
-        past = cache[0].shape[1]
-    return (past + jnp.arange(t, dtype=jnp.int64)).reshape(1, t)
 
 
 def _span_mask(cols, att_len: int, window):
@@ -87,12 +93,295 @@ def _span_mask(cols, att_len: int, window):
     return mask[:, None]
 
 
+def _page_address(table, cols, num_pages: int, page_size: int):
+    """``(page id, offset)`` of position ``cols[r, j]`` of row ``r``:
+    ``pages[table[r, p // P], p % P]``.  A sentinel table entry
+    (``>= num_pages``) or a position past the table's reach gives page id
+    ``num_pages``, which a ``mode="drop"`` scatter discards: an unallocated
+    or parked position is unwritable."""
+    n_pt = table.shape[1]
+    rows = jnp.arange(table.shape[0])[:, None]
+    pslot = jnp.clip(cols // page_size, 0, n_pt - 1)
+    pid = jnp.where(cols < n_pt * page_size, table[rows, pslot], num_pages)
+    return pid, cols % page_size
+
+
+class KernelRead(NamedTuple):
+    """The Pallas kernel one program's attention read goes through
+    (`kernels/paged_attention.py`), chosen once by the engine when it is
+    built: ``dense`` streams each row's live ``block``-position blocks of
+    the dense pool, ``paged`` walks the page table (``block`` = the page
+    size)."""
+    kernel: str
+    block: int
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotCache:
+    """One layer's per-slot cache.  ``k`` / ``v``: the pool's storage,
+    ``[rows, L, kv_heads, hd]`` (``dense``) or ``[num_pages, page_size,
+    kv_heads, hd]`` (``paged``, with ``table [rows, n_pt]`` int32: position
+    p of row r lives at ``pages[table[r, p // P], p % P]``); ``lengths
+    [rows]``: each row's cached positions (a row parked at the addressable
+    end writes nothing); ``k_scale`` / ``v_scale``: the int8 storage's
+    per-position float32 scales, shaped like the storage's two leading
+    dims.  Static: ``layout`` and ``read`` (None = the masked XLA read over
+    every row whole; a :class:`KernelRead` in the engine's decode
+    program).  The new span may be wider than one position (speculative
+    verification, prefix-tail prefill): position j of a row writes at its
+    own offset + j and attends causally within the span."""
+    k: Any
+    v: Any
+    lengths: Any
+    table: Any = None
+    k_scale: Any = None
+    v_scale: Any = None
+    layout: str = "dense"
+    read: Optional[KernelRead] = None
+
+    @property
+    def quantized(self) -> bool:
+        return self.k_scale is not None
+
+    @property
+    def span(self) -> int:
+        """Positions a row can address."""
+        return (self.table.shape[1] * self.k.shape[1]
+                if self.layout == "paged" else self.k.shape[1])
+
+    def cols(self, t: int):
+        """``[rows, t]``: the positions a span of ``t`` new tokens takes."""
+        return self.lengths[:, None] + jnp.arange(t)[None, :]
+
+    def put(self, at, k, v):
+        """The cache with ``k`` / ``v`` stored at storage index ``at``
+        (quantised where the storage has scales); an index out of range
+        DROPS its write."""
+        if not self.quantized:
+            return dataclasses.replace(
+                self,
+                k=self.k.at[at].set(k.astype(self.k.dtype), mode="drop"),
+                v=self.v.at[at].set(v.astype(self.v.dtype), mode="drop"))
+        (kq, ksc), (vq, vsc) = quantize_rows(k), quantize_rows(v)
+        return dataclasses.replace(
+            self,
+            k=self.k.at[at].set(kq, mode="drop"),
+            v=self.v.at[at].set(vq, mode="drop"),
+            k_scale=self.k_scale.at[at].set(ksc, mode="drop"),
+            v_scale=self.v_scale.at[at].set(vsc, mode="drop"))
+
+    def written(self, k, v, cols):
+        """The cache with the new positions' K/V scattered in at ``cols``
+        (``lengths`` as they were).  A write past a dense row's end, or
+        through a sentinel page, drops: never clipped onto a live row."""
+        if self.layout == "paged":
+            return self.put(_page_address(
+                jnp.asarray(self.table, jnp.int32), cols, self.k.shape[0],
+                self.k.shape[1]), k, v)
+        return self.put((jnp.arange(self.k.shape[0])[:, None], cols), k, v)
+
+    def _rows(self, dtype):
+        """K and V as ``[rows, span, kv_heads, hd]`` of ``dtype``: the pages
+        gathered through the table (a sentinel entry gathers a clamped
+        garbage page that the validity mask excludes), int8 dequantised."""
+        k, v, ks, vs = self.k, self.v, self.k_scale, self.v_scale
+        if self.layout == "paged":
+            pt = jnp.clip(jnp.asarray(self.table, jnp.int32), 0,
+                          k.shape[0] - 1)
+            n = pt.shape[0]
+            k = k[pt].reshape((n, self.span) + k.shape[2:])
+            v = v[pt].reshape((n, self.span) + v.shape[2:])
+            if self.quantized:
+                ks = ks[pt].reshape(n, self.span)
+                vs = vs[pt].reshape(n, self.span)
+        if self.quantized:
+            k, v = dequantize_pool(k, ks, dtype), dequantize_pool(v, vs, dtype)
+        return k, v
+
+    def attend(self, q, cols, window=None):
+        """Attention of the span's queries ``q [rows, t, heads, hd]`` at
+        ``cols`` over the (already written) cache, through the read this
+        program was given."""
+        if self.read is None:
+            k, v = self._rows(_raw(q).dtype)
+            return F.scaled_dot_product_attention(
+                q, _wrap(k), _wrap(v),
+                attn_mask=_wrap(_span_mask(cols, self.span, window)),
+                dropout_p=0.0, is_causal=False, training=False)
+        from ..kernels import paged_attention as pk
+        if self.read.kernel == "dense":
+            return _wrap(pk.dense_decode_attention(
+                _raw(q), self.k, self.v, self.lengths, block=self.read.block,
+                window=window))
+        if window is not None or self.k.shape[2] != q.shape[2]:
+            raise ValueError(
+                "the paged decode kernel reads neither a sliding window "
+                "nor grouped-query heads; serve this model with "
+                "decode_kernel='xla'")
+        return _wrap(pk.paged_decode_attention(
+            _raw(q), self.k, self.v, jnp.asarray(self.table, jnp.int32),
+            self.lengths, k_scale=self.k_scale, v_scale=self.v_scale))
+
+
+jax.tree_util.register_dataclass(
+    SlotCache,
+    data_fields=["k", "v", "lengths", "table", "k_scale", "v_scale"],
+    meta_fields=["layout", "read"])
+
+
+@dataclasses.dataclass(frozen=True)
+class KVPool:
+    """The serving engine's KV storage, every layer's: the donated operand
+    of its programs.  ``dense``: ``rows`` slot rows of ``row_len``
+    positions, the LAST row the scratch row that padding lanes write;
+    ``paged``: ``rows`` pages of ``row_len`` positions, addressed through
+    per-slot page tables whose sentinel entry is ``rows``.  ``dtype`` is
+    the precision K/V are computed in; storage is int8 with float32 scale
+    sidecars when ``k_scale`` is there."""
+    k: list
+    v: list
+    k_scale: Optional[list] = None
+    v_scale: Optional[list] = None
+    layout: str = "dense"
+    dtype: Any = None
+
+    @classmethod
+    def zeros(cls, kv, *, layout: str, rows: int, row_len: int,
+              quantized: bool):
+        """Sized from the per-layer ``(k, v)`` cache shapes the model
+        reports (``[.., .., kv_heads, hd]``: KV heads, not query heads)."""
+        def buf(s):
+            return jnp.zeros((rows, row_len) + tuple(s.shape[2:]),
+                             jnp.int8 if quantized else s.dtype)
+
+        def scales():
+            return ([jnp.zeros((rows, row_len), jnp.float32) for _ in kv]
+                    if quantized else None)
+        return cls([buf(k) for k, _ in kv], [buf(v) for _, v in kv],
+                   scales(), scales(), layout, jnp.dtype(kv[0][0].dtype))
+
+    @property
+    def quantized(self) -> bool:
+        return self.k_scale is not None
+
+    @property
+    def rows(self) -> int:
+        return self.k[0].shape[0]
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(p.size) * p.dtype.itemsize
+                   for p in jax.tree_util.tree_leaves(self))
+
+    def groups(self) -> tuple:
+        """The per-layer buffer lists: K, V and, for int8 storage, their
+        scale sidecars (the order of a host-tier payload)."""
+        return tuple(g for g in (self.k, self.v, self.k_scale, self.v_scale)
+                     if g is not None)
+
+    def with_groups(self, groups):
+        return dataclasses.replace(self, **dict(zip(
+            ("k", "v", "k_scale", "v_scale"), groups)))
+
+    def caches(self, lengths, tables=None, read=None) -> list:
+        """The per-layer caches of one step (``tables`` with the paged
+        layout)."""
+        none = [None] * len(self.k)
+        return [SlotCache(k, v, lengths, tables, ks, vs, self.layout, read)
+                for k, v, ks, vs in zip(self.k, self.v, self.k_scale or none,
+                                        self.v_scale or none)]
+
+    def updated(self, caches):
+        """The pool holding the buffers of the caches a model returned."""
+        return dataclasses.replace(
+            self, k=[c.k for c in caches], v=[c.v for c in caches],
+            k_scale=[c.k_scale for c in caches] if self.quantized else None,
+            v_scale=[c.v_scale for c in caches] if self.quantized else None)
+
+    def real(self, addr):
+        """``[n]`` bool: which lanes of a prefill's address operand name a
+        request (slot indices below the scratch row; page tables whose
+        first entry is a page)."""
+        if self.layout == "paged":
+            return addr[:, 0] < self.rows
+        return addr < self.rows - 1
+
+    def prompt_caches(self, n: int, bucket: int) -> list:
+        """Static caches (python-int length 0, so the prompt keeps the
+        causal flash path) for ``n`` fresh prompts of up to ``bucket``
+        positions, in the compute precision: a dense pool takes whole rows
+        back, a paged pool the bucket's positions."""
+        length = bucket if self.layout == "paged" else self.k[0].shape[1]
+        return [(_wrap(jnp.zeros((n, length) + k.shape[2:], self.dtype)),
+                 _wrap(jnp.zeros((n, length) + v.shape[2:], self.dtype)), 0)
+                for k, v in zip(self.k, self.v)]
+
+    def with_prompts(self, caches, addr, prompt_lens):
+        """The pool with freshly prefilled :meth:`prompt_caches` written
+        where ``addr`` says: whole rows at slot indices (``dense``; padding
+        lanes target the scratch row), or every real position through its
+        lane's page-table row (``paged``; padding positions and sentinel
+        lanes drop).  int8 storage quantises here: the prompt math itself
+        stays full precision."""
+        at = addr
+        if self.layout == "paged":
+            pos = jnp.arange(caches[0][0].shape[1])
+            pid, off = _page_address(addr, jnp.broadcast_to(
+                pos[None, :], (addr.shape[0], pos.shape[0])),
+                self.rows, self.k[0].shape[1])
+            live = ((pos[None, :] < prompt_lens[:, None]) &
+                    self.real(addr)[:, None])
+            at = (jnp.where(live, pid, self.rows), off)
+        return self.updated([
+            view.put(at, _raw(c[0]), _raw(c[1]))
+            for view, c in zip(self.caches(None), caches)])
+
+    def copied(self, src, dst):
+        """Rows (a prefix hit's cached row into the hitting request's
+        slot) or pages (copy-on-write of a shared boundary page) cloned
+        ``src -> dst`` with their scales, bitwise.  A sentinel page lane
+        gathers a clamped page and drops its scatter; a dense padding lane
+        copies the scratch row onto itself."""
+        return jax.tree_util.tree_map(
+            lambda p: p.at[dst].set(p[jnp.clip(src, 0, p.shape[0] - 1)],
+                                    mode="drop"), self)
+
+
+jax.tree_util.register_dataclass(
+    KVPool, data_fields=["k", "v", "k_scale", "v_scale"],
+    meta_fields=["layout", "dtype"])
+
+
+def cache_positions(cache, t: int):
+    """int64 positions ``[B or 1, t]`` of the ``t`` new tokens: from 0 with
+    no cache, offset by the cached key length otherwise (a python int, a
+    traced scalar or the per-slot ``[B]`` vector; the buffer length of a
+    growing-concat pair)."""
+    if cache is None:
+        past = 0
+    elif isinstance(cache, SlotCache) or len(cache) == 3:
+        past = jnp.asarray(
+            cache.lengths if isinstance(cache, SlotCache) else cache[2],
+            jnp.int64)
+        if past.ndim == 1:        # per-slot: each row at its own position
+            return past[:, None] + jnp.arange(t, dtype=jnp.int64)
+    else:
+        past = cache[0].shape[1]
+    return (past + jnp.arange(t, dtype=jnp.int64)).reshape(1, t)
+
+
 def cached_attention(q, k, v, cache=None, *, window=None, dropout_p=0.0,
                      training=False, owner="models.kv_cache"):
     """See the module docstring.  Returns ``(out, new_cache)``; ``out`` is a
     Tensor ``[B, t, heads, head_dim]``."""
     t = q.shape[1]
-    if cache is None or len(cache) not in (3, 4, 5, 6):
+    if isinstance(cache, SlotCache):
+        cols = cache.cols(t)
+        cache = cache.written(_raw(k), _raw(v), cols)
+        out = cache.attend(q, cols, window)
+        return out, dataclasses.replace(cache, lengths=cache.lengths + t)
+
+    if cache is None or len(cache) != 3:
         if cache is not None:
             from ..observability.retrace import note_dynamic_cache_growth
             from ..ops.manipulation import concat
@@ -112,141 +401,31 @@ def cached_attention(q, k, v, cache=None, *, window=None, dropout_p=0.0,
                 training=training, window=window)
         return out, (k, v)
 
-    k_buf, v_buf, pos0 = cache[0], cache[1], cache[2]
-    quantized = len(cache) in (5, 6)
-    paged = len(cache) in (4, 6)
-    k_raw, v_raw = _raw(k_buf), _raw(v_buf)
+    k_buf, v_buf, pos0 = cache
     start = jnp.asarray(pos0, jnp.int32)
-    if (quantized or paged) and start.ndim != 1:
-        raise ValueError(
-            "int8 (5/6-tuple) and paged (4/6-tuple) KV caches are supported "
-            "only in the per-slot vector-length form the serving engine "
-            "uses")
-    if start.ndim != 1:
-        z = jnp.zeros((), jnp.int32)
-        k_raw = jax.lax.dynamic_update_slice(
-            k_raw, _raw(k).astype(k_raw.dtype), (z, start, z, z))
-        v_raw = jax.lax.dynamic_update_slice(
-            v_raw, _raw(v).astype(v_raw.dtype), (z, start, z, z))
-        if isinstance(pos0, int) and pos0 == 0:
-            # static prefill (the engine builds the cache inside the prefill
-            # jit with a PYTHON-int length 0): no past to attend over, so
-            # the prompt keeps the causal flash-attention path instead of
-            # dense masked attention over the zero-padded buffer
-            out = F.scaled_dot_product_attention(
-                q, k, v, dropout_p=0.0, is_causal=True, training=False,
-                window=window)
-        else:
-            cols = (start + jnp.arange(t))[None, :]
-            out = F.scaled_dot_product_attention(
-                q, _wrap(k_raw), _wrap(v_raw),
-                attn_mask=_wrap(_span_mask(cols, k_raw.shape[1], window)),
-                dropout_p=0.0, is_causal=False, training=False)
-        return out, (_wrap(k_raw), _wrap(v_raw), start + t)
-
-    # -- per-slot lengths ----------------------------------------------------
-    scale_i = 4 if paged else 3
-    att_out = None
-    cols = start[:, None] + jnp.arange(t)[None, :]
-    if quantized:
-        from ..serving.kv_quant import dequantize_pool, quantize_rows
-        ks_raw, vs_raw = _raw(cache[scale_i]), _raw(cache[scale_i + 1])
-        kq, ksc = quantize_rows(_raw(k))
-        vq, vsc = quantize_rows(_raw(v))
-    if paged:
-        # gather/scatter through the page table: position p of row r lives
-        # at pages[table[r, p // P], p % P].  Sentinel table entries
-        # (>= num_pages) make the scatter DROP (an unallocated or parked
-        # position is unwritable) and gather a clamped garbage page that the
-        # validity mask excludes from attention.
-        pt = jnp.asarray(_raw(cache[3]), jnp.int32)
-        n_pages, psz = k_raw.shape[0], k_raw.shape[1]
-        n_pt = pt.shape[1]
-        virt = n_pt * psz
-        rows = jnp.arange(pt.shape[0])[:, None]
-        pslot = jnp.clip(cols // psz, 0, n_pt - 1)
-        pid = jnp.where(cols < virt, pt[rows, pslot], n_pages)
-        off = cols % psz
-        if quantized:
-            k_raw = k_raw.at[pid, off].set(kq, mode="drop")
-            v_raw = v_raw.at[pid, off].set(vq, mode="drop")
-            ks_raw = ks_raw.at[pid, off].set(ksc, mode="drop")
-            vs_raw = vs_raw.at[pid, off].set(vsc, mode="drop")
-        else:
-            k_raw = k_raw.at[pid, off].set(
-                _raw(k).astype(k_raw.dtype), mode="drop")
-            v_raw = v_raw.at[pid, off].set(
-                _raw(v).astype(v_raw.dtype), mode="drop")
-        # serving decode with Engine(decode_kernel="pallas"): the attention
-        # READ runs as the fused Pallas kernel — page-table walk + (int8)
-        # dequant + masked softmax in one custom call, no [B, virt, ...]
-        # gather temp.  The write scatter above is unchanged, so the kernel
-        # attends over the post-write pool exactly like the XLA read.
-        from ..kernels.paged_attention import (active as _paged_kernel_active,
-                                               paged_decode_attention)
-        if _paged_kernel_active():
-            if window is not None or k_raw.shape[2] != q.shape[2]:
-                raise ValueError(
-                    "the paged decode kernel reads neither a sliding window "
-                    "nor grouped-query heads; serve this model with "
-                    "decode_kernel='xla'")
-            att_out = paged_decode_attention(
-                _raw(q), k_raw, v_raw, pt, start,
-                k_scale=ks_raw if quantized else None,
-                v_scale=vs_raw if quantized else None)
-        else:
-            pt_safe = jnp.clip(pt, 0, n_pages - 1)
-            k_att = k_raw[pt_safe].reshape(
-                (pt.shape[0], virt) + k_raw.shape[2:])
-            v_att = v_raw[pt_safe].reshape(
-                (pt.shape[0], virt) + v_raw.shape[2:])
-            if quantized:
-                k_att = dequantize_pool(
-                    k_att, ks_raw[pt_safe].reshape(pt.shape[0], virt),
-                    _raw(k).dtype)
-                v_att = dequantize_pool(
-                    v_att, vs_raw[pt_safe].reshape(pt.shape[0], virt),
-                    _raw(v).dtype)
-        att_len = virt
-    else:
-        rows = jnp.arange(k_raw.shape[0])[:, None]
-        if quantized:
-            k_raw = k_raw.at[rows, cols].set(kq, mode="drop")
-            v_raw = v_raw.at[rows, cols].set(vq, mode="drop")
-            ks_raw = ks_raw.at[rows, cols].set(ksc, mode="drop")
-            vs_raw = vs_raw.at[rows, cols].set(vsc, mode="drop")
-            k_att = dequantize_pool(k_raw, ks_raw, _raw(k).dtype)
-            v_att = dequantize_pool(v_raw, vs_raw, _raw(v).dtype)
-        else:
-            k_raw = k_raw.at[rows, cols].set(
-                _raw(k).astype(k_raw.dtype), mode="drop")
-            v_raw = v_raw.at[rows, cols].set(
-                _raw(v).astype(v_raw.dtype), mode="drop")
-            k_att, v_att = k_raw, v_raw
-            # the engine's decode step on the TPU: the read streams each
-            # row's live blocks only (kernels/paged_attention.py "the dense
-            # pool's decode read"; on a window layer from the window's
-            # first block on); everywhere else, and for tail_prefill's long
-            # spans, the masked XLA read below
-            from ..kernels import paged_attention as _pk
-            blk = (_pk.dense_read_block(
-                heads=q.shape[2], kv_heads=k_raw.shape[2],
-                head_dim=q.shape[3], dtype=k_raw.dtype, width=t,
-                max_len=k_raw.shape[1]) if _pk.active() else None)
-            if blk is not None:
-                att_out = _pk.dense_decode_attention(
-                    _raw(q), k_raw, v_raw, start, block=blk, window=window)
-        att_len = k_raw.shape[1]
-    if att_out is not None:
-        out = _wrap(att_out)
-    else:
+    if start.ndim == 1:
+        out, new = cached_attention(
+            q, k, v, SlotCache(_raw(k_buf), _raw(v_buf), start),
+            window=window)
+        return out, (_wrap(new.k), _wrap(new.v), new.lengths)
+    k_raw, v_raw = _raw(k_buf), _raw(v_buf)
+    z = jnp.zeros((), jnp.int32)
+    k_raw = jax.lax.dynamic_update_slice(
+        k_raw, _raw(k).astype(k_raw.dtype), (z, start, z, z))
+    v_raw = jax.lax.dynamic_update_slice(
+        v_raw, _raw(v).astype(v_raw.dtype), (z, start, z, z))
+    if isinstance(pos0, int) and pos0 == 0:
+        # static prefill (the engine builds the cache inside the prefill
+        # jit with a PYTHON-int length 0): no past to attend over, so
+        # the prompt keeps the causal flash-attention path instead of
+        # dense masked attention over the zero-padded buffer
         out = F.scaled_dot_product_attention(
-            q, _wrap(k_att), _wrap(v_att),
-            attn_mask=_wrap(_span_mask(cols, att_len, window)),
+            q, k, v, dropout_p=0.0, is_causal=True, training=False,
+            window=window)
+    else:
+        cols = (start + jnp.arange(t))[None, :]
+        out = F.scaled_dot_product_attention(
+            q, _wrap(k_raw), _wrap(v_raw),
+            attn_mask=_wrap(_span_mask(cols, k_raw.shape[1], window)),
             dropout_p=0.0, is_causal=False, training=False)
-    new_cache = (_wrap(k_raw), _wrap(v_raw), start + t)
-    if paged:
-        new_cache = new_cache + (cache[3],)
-    if quantized:
-        new_cache = new_cache + (_wrap(ks_raw), _wrap(vs_raw))
-    return out, new_cache
+    return out, (_wrap(k_raw), _wrap(v_raw), start + t)

@@ -43,7 +43,7 @@ composable attacks on that bound ride the same single-signature loop:
   prefix is accepted, so each pool read yields up to ``k`` tokens.
   Greedy output stays token-identical to the plain path by construction.
 * ``kv_dtype="int8"`` — pools stored int8 with per-row scales
-  (kv_quant), dequantized inside the attention read: half the pool bytes,
+  (models/kv_cache.py), dequantized inside the attention read: half the pool bytes,
   double the slots in the same HBM.
 
 **Multi-LoRA serving** (docs/serving.md "Multi-LoRA serving"):
@@ -488,7 +488,7 @@ class Engine:
         kv_dtype: None (model dtype) or ``"int8"`` — store the K/V pools
             quantized with per-row scales, dequantized inside the
             attention read (half the pool bytes → 2x slots in the same
-            HBM; see serving/kv_quant.py).
+            HBM; see models/kv_cache.py).
         paged_kv: store K/V in fixed-size **pages** instead of dense
             per-slot rows (docs/serving.md "Paged KV").  A host-side
             :class:`~paddle_tpu.serving.paged_kv.PageAllocator` owns the
@@ -753,7 +753,7 @@ class Engine:
         self._spawning = False
         self._built = False
         self._values = None
-        self._pools = None          # (kpools, vpools[, kscales, vscales])
+        self._kv_pool = None        # models.kv_cache.KVPool, every layer's
         # stream callbacks of the tokens last emitted, held back until the
         # next program is on the device (`_flush_streams`): their consumers
         # (one gateway thread a stream) then wake while the device works,
@@ -1258,12 +1258,11 @@ class Engine:
         import jax
         import jax.numpy as jnp
 
+        from ..models.kv_cache import KernelRead, KVPool
         from ..nn.functional_call import _swapped_state, state_values
-        from .kv_quant import quantize_rows
 
         model = self.model
         n_rows, L = self.max_slots + 1, self.max_len
-        quant = self._kv_quant
         on_device = self.sample_on_device
         self._values = state_values(model)
 
@@ -1357,46 +1356,41 @@ class Engine:
             with self._lock:
                 self._ledger_rows.append(brow)
 
-        # Pallas decode kernels (kernels/paged_attention.py): the scope is
-        # entered inside the DECODE jit only, so that one program's
-        # attention read traces through a kernel while prefill /
-        # tail-prefill keep the XLA read — a trace-time routing
-        # decision, not an operand, so the signature count is unchanged.
-        # The paged pool takes its kernel when asked to
-        # (decode_kernel="pallas"); the dense pool's decode enters the
-        # scope always, and the model routes the read where
-        # `dense_read_block` says the kernel applies (the TPU; never an
-        # int8 pool).  `_decode_read_block`: the positions per block or
-        # page of the kernel this engine's decode reads through, None on
-        # an XLA read (the kv_read count of `_decode_step`).
-        from ..kernels.paged_attention import (
-            decode_kernel_scope as _pk_scope, dense_read_block)
-        use_pallas_decode = self.decode_kernel == "pallas"
+        # The decode program's attention read, chosen here once and carried
+        # to the model as the static `read` field of its layer caches
+        # (prefill and tail-prefill keep the masked XLA read): the paged
+        # pool takes its Pallas kernel when asked to
+        # (decode_kernel="pallas"); the dense pool its kernel wherever
+        # `dense_read_block` says it applies (the TPU; never an int8
+        # pool).  `_decode_read_block`: the positions per block or page
+        # of that kernel, None on an XLA read (the kv_read count of
+        # `_decode_step`).
         if self.paged_kv:
-            self._decode_read_block = (self._page_alloc.page_size
-                                       if use_pallas_decode else None)
+            decode_read = (KernelRead("paged", self._page_alloc.page_size)
+                           if self.decode_kernel == "pallas" else None)
         else:
+            from ..kernels.paged_attention import dense_read_block
             k0 = kv[0][0]
-            self._decode_read_block = dense_read_block(
+            blk = dense_read_block(
                 heads=int(getattr(getattr(trunk, "config", None),
                                   "num_attention_heads", k0.shape[2])),
                 kv_heads=int(k0.shape[2]), head_dim=int(k0.shape[3]),
-                dtype=jnp.int8 if quant else k0.dtype,
+                dtype=jnp.int8 if self._kv_quant else k0.dtype,
                 width=self._spec_width, max_len=L)
+            decode_read = None if blk is None else KernelRead("dense", blk)
+        self._decode_read_block = (None if decode_read is None
+                                   else decode_read.block)
 
         @contextlib.contextmanager
-        def _mstate(values, adp, pk=False, valid=None):
+        def _mstate(values, adp, valid=None):
             """Swapped model state, plus the batched-adapter scope when
-            the dispatch carries adapter operands, plus the Pallas
-            decode-kernel scope when this jit is the decode step.  Yields
-            the collector of the expert layers' load over the tokens
-            `valid` marks as real."""
+            the dispatch carries adapter operands.  Yields the collector
+            of the expert layers' load over the tokens `valid` marks as
+            real."""
             with contextlib.ExitStack() as st:
                 st.enter_context(_swapped_state(model, values))
                 if adp is not None:
                     st.enter_context(_adapter_scope(*adp))
-                if pk:
-                    st.enter_context(_pk_scope())
                 yield st.enter_context(_collect_load(valid))
 
         def _pack(out, load, logits=None):
@@ -1419,41 +1413,18 @@ class Engine:
             if tot is not None:
                 parts.append(tot.astype(out.dtype))
             return jnp.concatenate(parts)
-        pool_dtype = jnp.int8 if quant else None
+
+        # dense: a row of max_len positions per slot + the scratch row.
+        # paged: [num_pages, page_size, kv_heads, head_dim] per layer — HBM
+        # holds pages, slots address them through int32 page tables (just
+        # another decode operand)
         paged = self.paged_kv
-        if paged:
-            # block-granular pool: [num_pages, page_size, heads, head_dim]
-            # per layer — HBM holds pages, slots address them through
-            # int32 page tables (just another decode operand).  int8
-            # scales ride the page as a [page_size] f32 sidecar: one
-            # absmax per written position, so writes stay strictly
-            # incremental (nothing resident ever rescales).
-            NP_ = self._page_alloc.num_pages
-            P_ = self._page_alloc.page_size
-            n_pt = self._max_pages_per_slot
-            kpools = [jnp.zeros((NP_, P_) + tuple(k.shape[2:]),
-                                pool_dtype or k.dtype) for k, _ in kv]
-            vpools = [jnp.zeros((NP_, P_) + tuple(v.shape[2:]),
-                                pool_dtype or v.dtype) for _, v in kv]
-            if quant:
-                kscales = [jnp.zeros((NP_, P_), jnp.float32) for _ in kv]
-                vscales = [jnp.zeros((NP_, P_), jnp.float32) for _ in kv]
-                self._pools = (kpools, vpools, kscales, vscales)
-            else:
-                self._pools = (kpools, vpools)
-        else:
-            kpools = [jnp.zeros((n_rows, L) + tuple(k.shape[2:]),
-                                pool_dtype or k.dtype) for k, _ in kv]
-            vpools = [jnp.zeros((n_rows, L) + tuple(v.shape[2:]),
-                                pool_dtype or v.dtype) for _, v in kv]
-            if quant:
-                kscales = [jnp.zeros((n_rows, L), jnp.float32) for _ in kv]
-                vscales = [jnp.zeros((n_rows, L), jnp.float32) for _ in kv]
-                self._pools = (kpools, vpools, kscales, vscales)
-            else:
-                self._pools = (kpools, vpools)
-        total = sum(int(np.prod(p.shape)) * p.dtype.itemsize
-                    for grp in self._pools for p in grp)
+        self._kv_pool = KVPool.zeros(
+            kv, layout="paged" if paged else "dense",
+            rows=self._page_alloc.num_pages if paged else n_rows,
+            row_len=self._page_alloc.page_size if paged else L,
+            quantized=self._kv_quant)
+        total = self._kv_pool.nbytes
         led = _perfscope.ledger()
         krow = led.register(
             "kv_pool", total,
@@ -1470,7 +1441,8 @@ class Engine:
             self._pool_bytes = total
             self._ledger_rows.append(krow)
             if paged:
-                self._page_alloc.bytes_per_page = total // max(NP_, 1)
+                self._page_alloc.bytes_per_page = total // max(
+                    self._kv_pool.rows, 1)
             else:
                 self._row_bytes = total // n_rows
             if prow is not None:
@@ -1480,44 +1452,6 @@ class Engine:
             SERVING_KV_POOL_BYTES,
             "device bytes of the serving KV pools (incl. int8 scales)"
         ).set(float(total))
-
-        def _caches_from(pools, lengths, tables=None):
-            """Pool arrays → the models' per-slot static-cache protocol:
-            3-tuple dense, 5-tuple dense-int8, or the paged 4/6-tuple
-            forms with the page-table operand at index 3."""
-            if paged:
-                if quant:
-                    kps, vps, kss, vss = pools
-                    return [(Tensor(kp, _internal=True),
-                             Tensor(vp, _internal=True), lengths, tables,
-                             Tensor(ks, _internal=True),
-                             Tensor(vs, _internal=True))
-                            for kp, vp, ks, vs in zip(kps, vps, kss, vss)]
-                kps, vps = pools
-                return [(Tensor(kp, _internal=True),
-                         Tensor(vp, _internal=True), lengths, tables)
-                        for kp, vp in zip(kps, vps)]
-            if quant:
-                kps, vps, kss, vss = pools
-                return [(Tensor(kp, _internal=True),
-                         Tensor(vp, _internal=True), lengths,
-                         Tensor(ks, _internal=True),
-                         Tensor(vs, _internal=True))
-                        for kp, vp, ks, vs in zip(kps, vps, kss, vss)]
-            kps, vps = pools
-            return [(Tensor(kp, _internal=True),
-                     Tensor(vp, _internal=True), lengths)
-                    for kp, vp in zip(kps, vps)]
-
-        def _pools_from(new_caches):
-            if quant:
-                si = 4 if paged else 3      # scale slots in the cache tuple
-                return ([c[0]._value for c in new_caches],
-                        [c[1]._value for c in new_caches],
-                        [c[si]._value for c in new_caches],
-                        [c[si + 1]._value for c in new_caches])
-            return ([c[0]._value for c in new_caches],
-                    [c[1]._value for c in new_caches])
 
         def _fwd_last(ids_t, caches_t, gather_idx=None):
             """(per-row logits at the last real position, new caches); when
@@ -1563,220 +1497,88 @@ class Engine:
                     (jnp.arange(ids.shape[1])[None, :] <=
                      gather_idx[:, None]))
 
-        def prefill(values, ids, pools, slot_idx, prompt_lens, temps,
-                    topks, keys, adp=None):
+        def prefill(values, ids, pool, addr, prompt_lens, temps, topks,
+                    keys, adp=None):
             # the per-request caches are BUILT inside this jit with a
             # python-int length 0 (static prefill: the prompt keeps the
-            # causal flash path), then the filled rows scatter into the
-            # pool at each request's slot; padding rows target the scratch
-            # slot.  int8 pools quantize at the scatter (the prompt math
-            # itself stays full precision).
-            n = ids.shape[0]
-            caches_t = [
-                (Tensor(jnp.zeros((n, L) + tuple(k.shape[2:]), k.dtype),
-                        _internal=True),
-                 Tensor(jnp.zeros((n, L) + tuple(v.shape[2:]), v.dtype),
-                        _internal=True), 0)
-                for k, v in kv]
+            # causal flash path — the prompt math is the same whatever the
+            # pool, so greedy outputs match across layouts bitwise), then
+            # the pool writes them where `addr` says: each request's slot
+            # index, or its page-table row; padding lanes name the scratch
+            # row or carry an all-sentinel table.
+            caches_t = pool.prompt_caches(*ids.shape)
             valid = ((jnp.arange(ids.shape[1])[None, :] <
                       prompt_lens[:, None]) &
-                     (slot_idx < n_rows - 1)[:, None])   # not a padding row
+                     pool.real(addr)[:, None])           # not a padding row
             with _mstate(_dq(values), adp, valid=valid) as load:
                 logits, new_caches = _fwd_last(
                     Tensor(ids, _internal=True), caches_t,
                     gather_idx=prompt_lens - 1)
-            if quant:
-                kpools_, vpools_, kscales_, vscales_ = pools
-                kq = [quantize_rows(c[0]._value) for c in new_caches]
-                vq = [quantize_rows(c[1]._value) for c in new_caches]
-                kpools_ = [kp.at[slot_idx].set(q)
-                           for kp, (q, _) in zip(kpools_, kq)]
-                vpools_ = [vp.at[slot_idx].set(q)
-                           for vp, (q, _) in zip(vpools_, vq)]
-                kscales_ = [ks.at[slot_idx].set(s)
-                            for ks, (_, s) in zip(kscales_, kq)]
-                vscales_ = [vs.at[slot_idx].set(s)
-                            for vs, (_, s) in zip(vscales_, vq)]
-                pools = (kpools_, vpools_, kscales_, vscales_)
-            else:
-                kpools_, vpools_ = pools
-                kpools_ = [kp.at[slot_idx].set(c[0]._value)
-                           for kp, c in zip(kpools_, new_caches)]
-                vpools_ = [vp.at[slot_idx].set(c[1]._value)
-                           for vp, c in zip(vpools_, new_caches)]
-                pools = (kpools_, vpools_)
+            pool = pool.with_prompts(new_caches, addr, prompt_lens)
             if on_device:
                 toks = _sample_rows(logits, temps, topks, keys,
-                                    prompt_lens - 1, slot_idx < n_rows - 1)
-                return _pack(toks, load, logits), pools
-            return _pack(logits, load), pools
+                                    prompt_lens - 1, pool.real(addr))
+                return _pack(toks, load, logits), pool
+            return _pack(logits, load), pool
 
-        def prefill_paged(values, ids, pools, tables, prompt_lens, temps,
-                          topks, keys, adp=None):
-            # paged cold prefill: the per-request caches are built inside
-            # this jit exactly as in the dense path (python-int length 0
-            # keeps the causal flash path — the prompt math is IDENTICAL,
-            # so greedy outputs match the dense pool bitwise), then every
-            # written position scatters into its slot's pages through the
-            # batch page tables.  Padding positions (and padding lanes,
-            # whose tables are all-sentinel) resolve to page id
-            # num_pages, which mode="drop" discards.
-            n, bucket = ids.shape
-            caches_t = [
-                (Tensor(jnp.zeros((n, bucket) + tuple(k.shape[2:]),
-                                  k.dtype), _internal=True),
-                 Tensor(jnp.zeros((n, bucket) + tuple(v.shape[2:]),
-                                  v.dtype), _internal=True), 0)
-                for k, v in kv]
-            pos = jnp.arange(bucket)
-            # real positions of real lanes (a padding lane's table is all
-            # sentinel)
-            valid = ((pos[None, :] < prompt_lens[:, None]) &
-                     (tables[:, :1] < NP_))                      # [n, bucket]
-            with _mstate(_dq(values), adp, valid=valid) as load:
-                logits, new_caches = _fwd_last(
-                    Tensor(ids, _internal=True), caches_t,
-                    gather_idx=prompt_lens - 1)
-            pslot = jnp.clip(pos // P_, 0, n_pt - 1)
-            pid = jnp.where(valid, tables[:, pslot], NP_)
-            off = jnp.broadcast_to((pos % P_)[None, :], pid.shape)
-            if quant:
-                kpools_, vpools_, kscales_, vscales_ = pools
-                kq = [quantize_rows(c[0]._value) for c in new_caches]
-                vq = [quantize_rows(c[1]._value) for c in new_caches]
-                kpools_ = [kp.at[pid, off].set(q, mode="drop")
-                           for kp, (q, _) in zip(kpools_, kq)]
-                vpools_ = [vp.at[pid, off].set(q, mode="drop")
-                           for vp, (q, _) in zip(vpools_, vq)]
-                kscales_ = [ks.at[pid, off].set(s, mode="drop")
-                            for ks, (_, s) in zip(kscales_, kq)]
-                vscales_ = [vs.at[pid, off].set(s, mode="drop")
-                            for vs, (_, s) in zip(vscales_, vq)]
-                pools = (kpools_, vpools_, kscales_, vscales_)
-            else:
-                kpools_, vpools_ = pools
-                kpools_ = [kp.at[pid, off].set(c[0]._value, mode="drop")
-                           for kp, c in zip(kpools_, new_caches)]
-                vpools_ = [vp.at[pid, off].set(c[1]._value, mode="drop")
-                           for vp, c in zip(vpools_, new_caches)]
-                pools = (kpools_, vpools_)
-            if on_device:
-                toks = _sample_rows(logits, temps, topks, keys,
-                                    prompt_lens - 1, tables[:, 0] < NP_)
-                return _pack(toks, load, logits), pools
-            return _pack(logits, load), pools
-
-        def decode_paged(values, ids, pools, lengths, tables, temps,
-                         topks, keys, adp=None):
-            # the paged decode is the dense decode with the page tables
-            # riding along as one more int32 operand — the per-slot
-            # gather/scatter lives in the model's paged cache branch, so
-            # this stays ONE compiled program per engine config
-            caches_t = _caches_from(pools, lengths, tables)
-            with _mstate(_dq(values), adp, pk=use_pallas_decode,
-                         valid=lengths < park) as load:
-                logits, new_caches = _fwd_all(
-                    Tensor(ids, _internal=True), caches_t)
-            pools = _pools_from(new_caches)
-            if on_device:
-                greedy = jnp.argmax(logits, axis=-1)
-                first = _sample_rows(logits[:, 0], temps, topks, keys,
-                                     lengths, lengths < park)
-                toks = greedy.at[:, 0].set(first)
-                return _pack(toks, load, logits), pools
-            return _pack(logits, load), pools
-
-        def tail_prefill_paged(values, ids, pools, lengths, tables,
-                               gather_idx, temps, topks, keys, adp=None):
-            caches_t = _caches_from(pools, lengths, tables)
-            with _mstate(_dq(values), adp,
-                         valid=_tail_valid(ids, lengths, gather_idx)) as load:
-                logits, new_caches = _fwd_last(
-                    Tensor(ids, _internal=True), caches_t,
-                    gather_idx=gather_idx)
-            pools = _pools_from(new_caches)
-            if on_device:
-                toks = _sample_rows(logits, temps, topks, keys,
-                                    lengths + gather_idx, lengths < park)
-                return _pack(toks, load, logits), pools
-            return _pack(logits, load), pools
-
-        def copy_pages(pools, src, dst):
-            # copy-on-write: clone whole pages (K/V + scale sidecars)
-            # src->dst — the writer gets a private copy of a shared page,
-            # the readers' bytes are untouched.  Sentinel-padded lanes
-            # gather a clamped page and then DROP the scatter: no-ops.
-            return tuple([p.at[dst].set(p[jnp.clip(src, 0, NP_ - 1)],
-                                        mode="drop") for p in grp]
-                         for grp in pools)
-
-        def decode(values, ids, pools, lengths, temps, topks, keys,
+        def decode(values, ids, pool, lengths, tables, temps, topks, keys,
                    adp=None):
-            # ONE batched step over every slot row (+ scratch): vector
-            # lengths route the per-slot static-cache branch; idle rows
-            # are parked at max_len so their writes DROP (a prefix-cached
-            # row is never clobbered) and their logits are garbage that
-            # is never read.  ids is [n_rows, W]: W=1 is the plain decode,
-            # W=k the speculative verify — same program shape either way,
-            # ONE signature per engine config.
-            caches_t = _caches_from(pools, lengths)
-            with _mstate(_dq(values), adp, pk=True,
-                         valid=lengths < park) as load:
+            # ONE batched step over every slot row (+ scratch): idle rows
+            # are parked at the addressable end so their writes DROP (a
+            # prefix-cached row is never clobbered) and their logits are
+            # garbage that is never read.  ids is [n_rows, W]: W=1 is the
+            # plain decode, W=k the speculative verify — same program
+            # shape either way.  `tables` (None on the dense pool) rides
+            # along as one more int32 operand: ONE signature per engine
+            # config.
+            with _mstate(_dq(values), adp, valid=lengths < park) as load:
                 logits, new_caches = _fwd_all(
-                    Tensor(ids, _internal=True), caches_t)
-            pools = _pools_from(new_caches)
+                    Tensor(ids, _internal=True),
+                    pool.caches(lengths, tables, read=decode_read))
+            pool = pool.updated(new_caches)
             if on_device:
                 greedy = jnp.argmax(logits, axis=-1)        # [B, W]
                 first = _sample_rows(logits[:, 0], temps, topks, keys,
                                      lengths, lengths < park)
                 toks = greedy.at[:, 0].set(first)
-                return _pack(toks, load, logits), pools
-            return _pack(logits, load), pools
+                return _pack(toks, load, logits), pool
+            return _pack(logits, load), pool
 
-        def tail_prefill(values, ids, pools, lengths, gather_idx, temps,
-                         topks, keys, adp=None):
-            # prefix-cache hit path: the prompt HEAD was copied from a
-            # cached row, only the tail runs through the per-slot branch
-            # (rows not in this admit batch park at max_len: writes drop)
-            caches_t = _caches_from(pools, lengths)
+        def tail_prefill(values, ids, pool, lengths, tables, gather_idx,
+                         temps, topks, keys, adp=None):
+            # prefix-cache hit path: the prompt HEAD is already in the
+            # row (copied, or shared by page reference), only the tail
+            # runs through the per-slot caches (rows not in this admit
+            # batch park at the addressable end: writes drop)
             with _mstate(_dq(values), adp,
                          valid=_tail_valid(ids, lengths, gather_idx)) as load:
                 logits, new_caches = _fwd_last(
-                    Tensor(ids, _internal=True), caches_t,
-                    gather_idx=gather_idx)
-            pools = _pools_from(new_caches)
+                    Tensor(ids, _internal=True),
+                    pool.caches(lengths, tables), gather_idx=gather_idx)
+            pool = pool.updated(new_caches)
             if on_device:
                 toks = _sample_rows(logits, temps, topks, keys,
                                     lengths + gather_idx, lengths < park)
-                return _pack(toks, load, logits), pools
-            return _pack(logits, load), pools
+                return _pack(toks, load, logits), pool
+            return _pack(logits, load), pool
 
-        def copy_rows(pools, src, dst):
-            # prefix-cache hit: clone the cached rows (K/V + scales) into
-            # the hitting requests' slots — a pure device-side gather/
-            # scatter, bitwise-preserving; padding lanes copy scratch onto
-            # itself
-            return tuple([p.at[dst].set(p[src]) for p in grp]
-                         for grp in pools)
+        def prefix_copy(pool, src, dst):
+            return pool.copied(src, dst)
 
-        # cache pools are donated: prefill/decode update HBM in place (no
+        # the pool is donated: prefill/decode update HBM in place (no
         # donation on CPU — it only warns there)
         on_cpu = jax.default_backend() == "cpu"
         self._prefill_fn = instrument_jit(
-            jax.jit(prefill_paged if paged else prefill,
-                    donate_argnums=() if on_cpu else (2,)),
+            jax.jit(prefill, donate_argnums=() if on_cpu else (2,)),
             "serving.prefill")
         self._decode_fn = instrument_jit(
-            jax.jit(decode_paged if paged else decode,
-                    donate_argnums=() if on_cpu else (2,)),
+            jax.jit(decode, donate_argnums=() if on_cpu else (2,)),
             "serving.decode")
         self._tail_fn = instrument_jit(
-            jax.jit(tail_prefill_paged if paged else tail_prefill,
-                    donate_argnums=() if on_cpu else (2,)),
+            jax.jit(tail_prefill, donate_argnums=() if on_cpu else (2,)),
             "serving.tail_prefill")
         self._copy_fn = instrument_jit(
-            jax.jit(copy_pages if paged else copy_rows,
-                    donate_argnums=() if on_cpu else (0,)),
+            jax.jit(prefix_copy, donate_argnums=() if on_cpu else (0,)),
             "serving.prefix_copy")
         with self._lock:
             self._built = True
@@ -2073,16 +1875,16 @@ class Engine:
         The gather (``pool[pages]`` per layer per pool group) is EAGER
         and runs here, under the lock, BEFORE the pages are deref'd:
         the engine's jits donate the pools operand on device, so a raw
-        ``self._pools`` snapshot is invalidated by the very next
+        ``self._kv_pool`` snapshot is invalidated by the very next
         dispatch — fresh gathered arrays are the only thing the spill
         worker can safely ``device_get`` later, off this hot path."""
-        if self._pools is None or not e.pages:
+        if self._kv_pool is None or not e.pages:
             return
         try:
             import jax.numpy as jnp
             idx = jnp.asarray(np.asarray(e.pages, np.int32))
             gathered = [[pool[idx] for pool in grp]
-                        for grp in self._pools]
+                        for grp in self._kv_pool.groups()]
         except Exception:  # noqa: BLE001 — a dying device must not
             return         # turn an eviction into an engine failure
         self._host_tier.demote_async(e.ns, e.tokens, gathered)
@@ -2446,10 +2248,10 @@ class Engine:
                     tier.release(hentry)
                 continue
             idx = jnp.asarray(np.asarray(pids, np.int32))
-            self._pools = tuple(
+            self._kv_pool = self._kv_pool.with_groups(
                 [pool.at[idx].set(jnp.asarray(arr, pool.dtype))
                  for pool, arr in zip(grp, host_grp)]
-                for grp, host_grp in zip(self._pools, payload))
+                for grp, host_grp in zip(self._kv_pool.groups(), payload))
             dt = time.perf_counter() - t0
             nbytes = sum(a.nbytes for g in payload for a in g)
             with self._lock:
@@ -2534,8 +2336,8 @@ class Engine:
                            prompt_tokens=prompt_tokens,
                            padded_tokens=P * bucket,
                            sampled=sampled, topk=topk):
-                    (ids, slot_idx, plens, temps, topks, keys, aid_rows,
-                     tables) = self._prefill_rows(batch, bucket)
+                    (ids, addr, plens, temps, topks, keys,
+                     aid_rows) = self._prefill_rows(batch, bucket)
                     t0 = time.perf_counter()
                     faults.fault_point("serving.prefill", n=len(batch))
                     if self._decode_timeout_s is not None:
@@ -2543,14 +2345,9 @@ class Engine:
                                       self._decode_timeout_s)
                     extra = ((self._adp_args(aid_rows),)
                              if self._adapters is not None else ())
-                    if self.paged_kv:
-                        out, self._pools = self._prefill_fn(
-                            self._values, ids, self._pools, tables, plens,
-                            temps, topks, keys, *extra)
-                    else:
-                        out, self._pools = self._prefill_fn(
-                            self._values, ids, self._pools, slot_idx, plens,
-                            temps, topks, keys, *extra)
+                    out, self._kv_pool = self._prefill_fn(
+                        self._values, ids, self._kv_pool, addr, plens,
+                        temps, topks, keys, *extra)
                     self._dispatched(out)
                 with phase("serving.prefill.fetch"):
                     out, lps, load = self._fetch(out, (P,))
@@ -2577,7 +2374,9 @@ class Engine:
 
     def _prefill_rows(self, batch, bucket: int):
         """The host arrays of one cold prefill dispatch: ``prefill_batch``
-        rows of ``bucket`` positions, the rows past ``batch`` padding."""
+        rows of ``bucket`` positions, the rows past ``batch`` padding.  The
+        second is where the pool writes each row: its slot index, or on the
+        paged pool its page-table row."""
         P = self.prefill_batch
         ids = np.zeros((P, bucket), np.int64)
         slot_idx = np.full(P, self.max_slots, np.int32)
@@ -2606,7 +2405,8 @@ class Engine:
                               prompt_len=int(req.prompt.size),
                               queue_wait_ms=round(
                                   1e3 * (req.t_admit - req.t_submit), 3))
-        return ids, slot_idx, plens, temps, topks, keys, aid_rows, tables
+        return (ids, slot_idx if tables is None else tables, plens, temps,
+                topks, keys, aid_rows)
 
     def _prefill_hits(self, hits) -> None:
         """Prefix-cache hit path.  Dense pool: device-copy the cached
@@ -2644,8 +2444,8 @@ class Engine:
                         # COW'd boundary pages (usually zero — block ==
                         # page size makes every shared page a full page)
                         with span("serving.prefix_copy", n=n_copy):
-                            self._pools = self._copy_fn(
-                                self._pools, jnp.asarray(src),
+                            self._kv_pool = self._copy_fn(
+                                self._kv_pool, jnp.asarray(src),
                                 jnp.asarray(dst))
                         if paged and n_copy:
                             with self._lock:
@@ -2660,20 +2460,11 @@ class Engine:
                     t_copy_end = time.perf_counter()
                     extra = ((self._adp_args(aids_snap),)
                              if self._adapters is not None else ())
-                    if paged:
-                        out, self._pools = self._tail_fn(
-                            self._values, jnp.asarray(ids), self._pools,
-                            jnp.asarray(lens), jnp.asarray(tables),
-                            jnp.asarray(gidx), jnp.asarray(self._temps),
-                            jnp.asarray(self._topks),
-                            jnp.asarray(self._keys), *extra)
-                    else:
-                        out, self._pools = self._tail_fn(
-                            self._values, jnp.asarray(ids), self._pools,
-                            jnp.asarray(lens), jnp.asarray(gidx),
-                            jnp.asarray(self._temps),
-                            jnp.asarray(self._topks),
-                            jnp.asarray(self._keys), *extra)
+                    out, self._kv_pool = self._tail_fn(
+                        self._values, ids, self._kv_pool, lens, tables,
+                        gidx, jnp.asarray(self._temps),
+                        jnp.asarray(self._topks), jnp.asarray(self._keys),
+                        *extra)
                     self._dispatched(out)
                 with phase("serving.tail_prefill.fetch"):
                     out, lps, load = self._fetch(out, (n_rows,))
@@ -2829,14 +2620,9 @@ class Engine:
                     # the slot-state snapshots go in as numpy: the jit call
                     # transfers them itself, without a Python-level
                     # `device_put` apiece
-                    if self.paged_kv:
-                        out, self._pools = self._decode_fn(
-                            self._values, ids, self._pools, lengths, tables,
-                            temps, topks, keys, *extra)
-                    else:
-                        out, self._pools = self._decode_fn(
-                            self._values, ids, self._pools, lengths, temps,
-                            topks, keys, *extra)
+                    out, self._kv_pool = self._decode_fn(
+                        self._values, ids, self._kv_pool, lengths, tables,
+                        temps, topks, keys, *extra)
                     self._dispatched(out)
                 with phase("serving.decode.fetch"):
                     out, lps, load = self._fetch(out, ids.shape)

@@ -132,8 +132,8 @@ def test_prefix_cache_hit_bitwise_kv_and_outputs(tiny_gpt):
     h2 = cold.submit(prompts[0], max_new_tokens=6)
     h2.result(timeout=300)
     m = h._prefix_match
-    kpools, vpools = eng._pools[0], eng._pools[1]
-    ck, cv = cold._pools[0], cold._pools[1]
+    kpools, vpools = eng._kv_pool.k, eng._kv_pool.v
+    ck, cv = cold._kv_pool.k, cold._kv_pool.v
     for li in range(len(kpools)):
         np.testing.assert_array_equal(
             np.asarray(kpools[li][h.slot, :m]),
@@ -410,7 +410,9 @@ def _prims(jaxpr, in_cond=False):
 
 def _decode_calls(eng, submit):
     """The argument tuples of every decode dispatch `submit()` causes, and
-    the decode program (the engine must have built it already)."""
+    the decode program (the engine must have built it already).  Whatever
+    the pool, the one signature: `(values, ids, pool, lengths, tables,
+    temps, topks, keys)`, `tables` None on the dense layout."""
     fn = eng._decode_fn
     program, calls = fn._fn, []
     fn._fn = lambda *a: (calls.append(a), program(*a))[1]
@@ -418,6 +420,11 @@ def _decode_calls(eng, submit):
         out = submit()
     finally:
         fn._fn = program
+    for a in calls:
+        assert len(a) == 8 and a[2] is not eng._kv_pool
+        assert a[2].layout == ("paged" if eng.paged_kv else "dense")
+        assert (a[4] is None) == (a[2].layout == "dense")
+        assert a[2].quantized == (eng.kv_dtype == "int8")
     return out, calls, program
 
 

@@ -25,6 +25,7 @@ from paddle_tpu.incubate.distributed.models.moe.dropless import (
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.models import build_decoder, build_gpt, gpt_config
+from paddle_tpu.models.kv_cache import KernelRead, SlotCache
 from paddle_tpu.nn.functional.attention import _sdpa_ref
 from paddle_tpu.observability import trace
 from paddle_tpu.serving import Engine
@@ -126,12 +127,26 @@ def test_per_slot_cache_logits_match_reference(tiny, read):
         ids[r, :len(p)] = p
 
     def step(ids_, lens):
-        caches = [(Tensor(k, _internal=True), Tensor(v, _internal=True),
-                   jnp.asarray(lens)) for k, v in pools]
-        with pa.decode_kernel_scope():
+        if read == "xla":
+            # the Paddle-shaped spelling: a static triple whose length is a
+            # [B] vector is the dense, unquantised per-slot cache
+            caches = [(Tensor(k, _internal=True), Tensor(v, _internal=True),
+                       jnp.asarray(lens)) for k, v in pools]
             lg, new = model(paddle.to_tensor(ids_), caches=caches)
-        return np.asarray(lg._value), [(c[0]._value, c[1]._value)
-                                       for c in new]
+            return np.asarray(lg._value), [(c[0]._value, c[1]._value)
+                                           for c in new]
+        # the kernel is asked for as the engine asks: the cache's static
+        # `read` field, the block from `dense_read_block`
+        blk = pa.dense_read_block(heads=4, kv_heads=2, head_dim=16,
+                                  dtype=jnp.float32, width=ids_.shape[1],
+                                  max_len=L)
+        assert blk == L
+        caches = [SlotCache(k, v, jnp.asarray(lens),
+                            read=KernelRead("dense", blk)) for k, v in pools]
+        lg, new = model(paddle.to_tensor(ids_), caches=caches)
+        assert all(c.read == caches[0].read and c.layout == "dense"
+                   for c in new)
+        return np.asarray(lg._value), [(c.k, c.v) for c in new]
 
     # prefill through the per-slot branch (a tail prefill from position 0)
     lg, pools = step(ids, lengths)
@@ -200,7 +215,8 @@ def test_engine_sizes_come_from_the_cache_shapes(tiny):
     pa.use_interpret_mode(True)
     outs, st, eng = _serve(model, _prompts((30,)), new=20, max_slots=1,
                            max_len=64, prefill_batch=1)
-    assert eng._pools[0][0].shape == (2, 64, 2, 16)
+    assert eng._kv_pool.k[0].shape == (2, 64, 2, 16)
+    assert (eng._kv_pool.layout, eng._kv_pool.quantized) == ("dense", False)
     assert eng._kv_windows == [None, 8, 8, 8]
     # 19 decode steps at lengths 30..48: a global layer admits length + 1,
     # each of the three window layers 8

@@ -441,16 +441,25 @@ def test_gateway_model_routing(tiny_gpt, adapters):
 
         item = gw.admit(creq(model="tenant-a"), "t1")
         toks, _ = gw.result(item, timeout=300)
+        lps = np.asarray(item.handle.logprobs)
         merged = _merged_model(cfg, adapters["tenant-a"])
         ref = Engine(merged, max_slots=2, max_len=64)
-        want = ref.submit(np.asarray(p), max_new_tokens=6).result(
-            timeout=300)
+        h = ref.submit(np.asarray(p), max_new_tokens=6)
+        want, want_lps = h.result(timeout=300), np.asarray(h.logprobs)
         ref.shutdown()
         np.testing.assert_array_equal(toks, want)
-        # base-model requests: absent model= or the base name -> id 0
+        np.testing.assert_allclose(lps, want_lps, atol=1e-4, rtol=0)
+        # base-model requests: absent model= or the base name -> id 0.
+        # This tiny model's greedy tokens are the same with and without
+        # the adapter ([12] * 6), so the tokens cannot tell the two routes
+        # apart; each token's log-probability can: the base request's lie
+        # far from the adapter's, which lie on the merged reference's
+        # (5e-7 apart)
         item = gw.admit(creq(model="base"), "t1")
         toks_base, _ = gw.result(item, timeout=300)
-        assert not np.array_equal(toks, toks_base)
+        lps_base = np.asarray(item.handle.logprobs)
+        assert len(toks_base) == len(toks)
+        assert np.abs(lps - lps_base).min() > 0.05      # 0.12-0.48 here
         with pytest.raises(ProtocolError) as ei:
             gw.admit(creq(model="nope"), "t1")
         assert ei.value.status == 404 and ei.value.code == "model_not_found"

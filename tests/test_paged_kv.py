@@ -169,7 +169,7 @@ def test_cow_share_then_diverge_reader_bytes_unchanged(tiny_gpt):
     eng.submit(shared, max_new_tokens=4).result(timeout=300)
     entry = next(iter(eng._prefix._entries.values()))
     idx = np.asarray(entry.pages)
-    kpools, vpools = eng._pools[0], eng._pools[1]
+    kpools, vpools = eng._kv_pool.k, eng._kv_pool.v
     k_before = [np.asarray(p)[idx] for p in kpools]
     v_before = [np.asarray(p)[idx] for p in vpools]
 
@@ -178,7 +178,7 @@ def test_cow_share_then_diverge_reader_bytes_unchanged(tiny_gpt):
     h = eng.submit(p2, max_new_tokens=4)
     out = h.result(timeout=300)
     st = eng.stats()
-    kpools, vpools = eng._pools[0], eng._pools[1]
+    kpools, vpools = eng._kv_pool.k, eng._kv_pool.v
     for li in range(len(kpools)):
         np.testing.assert_array_equal(
             np.asarray(kpools[li])[idx], k_before[li],
