@@ -233,7 +233,8 @@ jax.tree_util.register_dataclass(
 class KVPool:
     """The serving engine's KV storage, every layer's: the donated operand
     of its programs.  ``dense``: ``rows`` slot rows of ``row_len``
-    positions, the LAST row the scratch row that padding lanes write;
+    positions, the LAST row the scratch row that a row copy's padding
+    lanes name;
     ``paged``: ``rows`` pages of ``row_len`` positions, addressed through
     per-slot page tables whose sentinel entry is ``rows``.  ``dtype`` is
     the precision K/V are computed in; storage is int8 with float32 scale
@@ -298,14 +299,6 @@ class KVPool:
             k_scale=[c.k_scale for c in caches] if self.quantized else None,
             v_scale=[c.v_scale for c in caches] if self.quantized else None)
 
-    def real(self, addr):
-        """``[n]`` bool: which lanes of a prefill's address operand name a
-        request (slot indices below the scratch row; page tables whose
-        first entry is a page)."""
-        if self.layout == "paged":
-            return addr[:, 0] < self.rows
-        return addr < self.rows - 1
-
     def prompt_caches(self, n: int, bucket: int) -> list:
         """Static caches (python-int length 0, so the prompt keeps the
         causal flash path) for ``n`` fresh prompts of up to ``bucket``
@@ -318,19 +311,17 @@ class KVPool:
 
     def with_prompts(self, caches, addr, prompt_lens):
         """The pool with freshly prefilled :meth:`prompt_caches` written
-        where ``addr`` says: whole rows at slot indices (``dense``; padding
-        lanes target the scratch row), or every real position through its
-        lane's page-table row (``paged``; padding positions and sentinel
-        lanes drop).  int8 storage quantises here: the prompt math itself
-        stays full precision."""
+        where ``addr`` says: whole rows at slot indices (``dense``), or
+        every real position through its lane's page-table row (``paged``;
+        padding positions drop).  Every lane names a request.  int8 storage
+        quantises here: the prompt math itself stays full precision."""
         at = addr
         if self.layout == "paged":
             pos = jnp.arange(caches[0][0].shape[1])
             pid, off = _page_address(addr, jnp.broadcast_to(
                 pos[None, :], (addr.shape[0], pos.shape[0])),
                 self.rows, self.k[0].shape[1])
-            live = ((pos[None, :] < prompt_lens[:, None]) &
-                    self.real(addr)[:, None])
+            live = pos[None, :] < prompt_lens[:, None]
             at = (jnp.where(live, pid, self.rows), off)
         return self.updated([
             view.put(at, _raw(c[0]), _raw(c[1]))

@@ -9,11 +9,12 @@ production LLM servers (vLLM/Orca-style continuous batching) converged on:
 * a **slot pool**: ONE set of ``[max_slots+1, max_len, heads, head_dim]``
   per-layer cache buffers; each in-flight request owns a slot row, freed on
   completion and recycled for the next request (SlotPool).  Row max_slots
-  is a scratch slot that absorbs prefill padding writes.
+  is a scratch slot: the padding lanes of a prefix-hit row copy name it.
 * a **scheduler loop** (daemon thread): each iteration sweeps
-  cancellations/deadlines, admits queued requests into free slots with ONE
-  batched prefill (prompts padded to a power-of-two bucket, so compile
-  count stays logarithmic), then runs ONE batched decode step for ALL
+  cancellations/deadlines, admits queued requests into free slots and
+  prefills each with the ONE-ROW prefill program of its prompt's
+  power-of-two bucket (one program per bucket, so compile count stays
+  logarithmic), then runs ONE batched decode step for ALL
   active slots — fixed shapes, so after the first iteration the decode is
   a single compiled program forever, regardless of request churn
   (asserted via the retrace sentinel's signature count).
@@ -141,6 +142,7 @@ SERVING_MOE_EXPERTS_TOUCHED = "paddle_tpu_serving_moe_experts_touched_total"
 SERVING_MOE_LOAD_MAX = "paddle_tpu_serving_moe_load_max_total"
 SERVING_DECODE_SAMPLED_STEPS = "paddle_tpu_serving_decode_sampled_steps_total"
 SERVING_DECODE_TOPK_STEPS = "paddle_tpu_serving_decode_topk_steps_total"
+SERVING_PREFILL_WAVES = "paddle_tpu_serving_prefill_waves_total"
 
 
 class QueueFullError(RuntimeError):
@@ -441,8 +443,11 @@ class Engine:
             ``len(prompt) + max_new_tokens <= max_len``.
         max_queue: admission-queue bound; submits beyond it raise
             :class:`QueueFullError` (default ``2 * max_slots``).
-        prefill_batch: new slots admitted per batched prefill call
-            (default ``min(4, max_slots)``).
+        prefill_batch: the most requests admitted between two decode
+            steps (default ``min(4, max_slots)``).  Not a shape of the
+            prefill: each admitted request is one dispatch of the one-row
+            prefill program of its own bucket (only the prefix-hit row
+            copy still has ``prefill_batch`` lanes).
         eos_token_id: default end-of-sequence id for requests.
         auto_start: start the scheduler thread on first submit (tests set
             False to stage a queue deterministically, then call start()).
@@ -792,6 +797,7 @@ class Engine:
         self._counts = {"submitted": 0, "completed": 0, "rejected": 0,
                         "cancelled": 0, "deadline_expired": 0, "failed": 0,
                         "decode_steps": 0, "prefill_batches": 0,
+                        "prefill_waves": 0,
                         "prefill_tokens": 0, "prefill_padded_tokens": 0,
                         "decode_kv_live_positions": 0,
                         "decode_kv_read_positions": 0,
@@ -1503,13 +1509,11 @@ class Engine:
             # python-int length 0 (static prefill: the prompt keeps the
             # causal flash path — the prompt math is the same whatever the
             # pool, so greedy outputs match across layouts bitwise), then
-            # the pool writes them where `addr` says: each request's slot
-            # index, or its page-table row; padding lanes name the scratch
-            # row or carry an all-sentinel table.
+            # the pool writes them where `addr` says: the request's slot
+            # index, or its page-table row.  The engine traces it with ONE
+            # row (`ids` is [1, bucket]): every lane names a request.
             caches_t = pool.prompt_caches(*ids.shape)
-            valid = ((jnp.arange(ids.shape[1])[None, :] <
-                      prompt_lens[:, None]) &
-                     pool.real(addr)[:, None])           # not a padding row
+            valid = jnp.arange(ids.shape[1])[None, :] < prompt_lens[:, None]
             with _mstate(_dq(values), adp, valid=valid) as load:
                 logits, new_caches = _fwd_last(
                     Tensor(ids, _internal=True), caches_t,
@@ -1517,7 +1521,8 @@ class Engine:
             pool = pool.with_prompts(new_caches, addr, prompt_lens)
             if on_device:
                 toks = _sample_rows(logits, temps, topks, keys,
-                                    prompt_lens - 1, pool.real(addr))
+                                    prompt_lens - 1,
+                                    jnp.ones_like(prompt_lens, bool))
                 return _pack(toks, load, logits), pool
             return _pack(logits, load), pool
 
@@ -2322,91 +2327,91 @@ class Engine:
                 self._ascale)
 
     def _prefill_cold(self, batch) -> None:
-        """Batched prefill of requests with no cached prefix (the only
-        admission path when the prefix cache is off)."""
-        bucket = _bucket(max(r.prompt.size for r in batch),
-                         min(8, self._limit), self._limit)
-        P = self.prefill_batch
-        prompt_tokens = sum(int(r.prompt.size) for r in batch)
-        sampled, topk = _sampling_rows(batch)
-        with span("serving.prefill", n=len(batch), bucket=bucket):
+        """Cold prefill of one admission wave: the requests with no cached
+        prefix (the only admission path when the prefix cache is off).  The
+        prefill program has ONE row, so a wave of n requests is n
+        dispatches, each at the bucket of its own prompt — all of them
+        before the first fetch, so that the device runs them back to back
+        while the host prepares the next; then one fetch and one emit per
+        dispatch, in admission order."""
+        with self._lock:
+            self._counts["prefill_waves"] += 1
+        registry().counter(
+            SERVING_PREFILL_WAVES,
+            "admission waves that ran at least one cold prefill").inc(1.0)
+        lo = min(8, self._limit)
+        buckets = [_bucket(r.prompt.size, lo, self._limit) for r in batch]
+        with span("serving.prefill", n=len(batch), bucket=max(buckets)):
+            flying = []
             try:
-                with phase("serving.prefill.dispatch", rows=len(batch),
-                           batch_rows=P, bucket=bucket,
-                           prompt_tokens=prompt_tokens,
-                           padded_tokens=P * bucket,
-                           sampled=sampled, topk=topk):
-                    (ids, addr, plens, temps, topks, keys,
-                     aid_rows) = self._prefill_rows(batch, bucket)
-                    t0 = time.perf_counter()
-                    faults.fault_point("serving.prefill", n=len(batch))
-                    if self._decode_timeout_s is not None:
-                        _watchdog.arm("serving.prefill",
-                                      self._decode_timeout_s)
-                    extra = ((self._adp_args(aid_rows),)
-                             if self._adapters is not None else ())
-                    out, self._kv_pool = self._prefill_fn(
-                        self._values, ids, self._kv_pool, addr, plens,
-                        temps, topks, keys, *extra)
-                    self._dispatched(out)
-                with phase("serving.prefill.fetch"):
-                    out, lps, load = self._fetch(out, (P,))
+                for req, bucket in zip(batch, buckets):
+                    n_prompt = int(req.prompt.size)
+                    sampled, topk = _sampling_rows([req])
+                    with phase("serving.prefill.dispatch", rows=1,
+                               batch_rows=1, bucket=bucket,
+                               prompt_tokens=n_prompt, padded_tokens=bucket,
+                               sampled=sampled, topk=topk):
+                        (ids, addr, plens, temps, topks, keys,
+                         aid_rows) = self._prefill_rows(req, bucket)
+                        t0 = time.perf_counter()
+                        faults.fault_point("serving.prefill", n=len(batch))
+                        if self._decode_timeout_s is not None:
+                            # each dispatch moves the deadline on: it times
+                            # the programs still queued behind this one
+                            _watchdog.arm("serving.prefill",
+                                          self._decode_timeout_s)
+                        extra = ((self._adp_args(aid_rows),)
+                                 if self._adapters is not None else ())
+                        out, self._kv_pool = self._prefill_fn(
+                            self._values, ids, self._kv_pool, addr, plens,
+                            temps, topks, keys, *extra)
+                        self._dispatched(out)
+                    flying.append((req, bucket, n_prompt, t0, out))
+                for req, bucket, n_prompt, t0, out in flying:
+                    with phase("serving.prefill.fetch"):
+                        out, lps, load = self._fetch(out, (1,))
+                    with phase("serving.prefill.emit",
+                               **self._load_stats(load)):
+                        self._count_load(load)
+                        dt = time.perf_counter() - t0
+                        with self._lock:
+                            self._counts["prefill_batches"] += 1
+                            self._counts["prefill_tokens"] += n_prompt
+                            self._counts["prefill_padded_tokens"] += bucket
+                        registry().histogram(
+                            SERVING_BATCH_SECONDS,
+                            "prefill/decode batch wall time").observe(
+                            dt, labels={"phase": "prefill"})
+                        if req.journey is not None:
+                            req.journey.phase("prefill", t0, dt,
+                                              n=len(batch), bucket=bucket,
+                                              prompt=n_prompt)
+                        self._emit_first_tokens([req], out, lps,
+                                                by_slot=False)
             finally:
                 if self._decode_timeout_s is not None:
                     _watchdog.disarm()
-            with phase("serving.prefill.emit", **self._load_stats(load)):
-                self._count_load(load)
-                dt = time.perf_counter() - t0
-                with self._lock:
-                    self._counts["prefill_batches"] += 1
-                    self._counts["prefill_tokens"] += prompt_tokens
-                    self._counts["prefill_padded_tokens"] += P * bucket
-                registry().histogram(
-                    SERVING_BATCH_SECONDS,
-                    "prefill/decode batch wall time").observe(
-                    dt, labels={"phase": "prefill"})
-                for req in batch:
-                    if req.journey is not None:
-                        req.journey.phase("prefill", t0, dt, n=len(batch),
-                                          bucket=bucket,
-                                          prompt=int(req.prompt.size))
-                self._emit_first_tokens(batch, out, lps, by_slot=False)
 
-    def _prefill_rows(self, batch, bucket: int):
-        """The host arrays of one cold prefill dispatch: ``prefill_batch``
-        rows of ``bucket`` positions, the rows past ``batch`` padding.  The
-        second is where the pool writes each row: its slot index, or on the
-        paged pool its page-table row."""
-        P = self.prefill_batch
-        ids = np.zeros((P, bucket), np.int64)
-        slot_idx = np.full(P, self.max_slots, np.int32)
-        plens = np.ones(P, np.int32)
-        temps = np.zeros(P, np.float32)
-        topks = np.zeros(P, np.int32)
-        keys = np.zeros((P, 2), np.uint32)
-        aid_rows = np.zeros(P, np.int32)
-        tables = (np.full((P, self._max_pages_per_slot),
-                          self._page_alloc.num_pages, np.int32)
-                  if self.paged_kv else None)
+    def _prefill_rows(self, req, bucket: int):
+        """The host arrays of one cold prefill dispatch, one lane each: the
+        request's prompt right-padded to ``bucket`` positions, where the
+        pool writes it (its slot index, or on the paged pool its page-table
+        row), its length, sampling parameters, key and adapter bank row."""
+        ids = np.zeros((1, bucket), np.int64)
+        ids[0, :req.prompt.size] = req.prompt
         with self._lock:
-            for i, req in enumerate(batch):
-                ids[i, :req.prompt.size] = req.prompt
-                slot_idx[i] = req.slot
-                plens[i] = req.prompt.size
-                temps[i] = req.temperature
-                topks[i] = req.top_k
-                keys[i] = req._base_key
-                aid_rows[i] = req._adapter_slot
-                if tables is not None:
-                    tables[i] = self._page_tables[req.slot]
-                self._set_slot_params_locked(req)
-                flight.record("serving", "admit", request=req.request_id,
-                              slot=req.slot,
-                              prompt_len=int(req.prompt.size),
-                              queue_wait_ms=round(
-                                  1e3 * (req.t_admit - req.t_submit), 3))
-        return (ids, slot_idx if tables is None else tables, plens, temps,
-                topks, keys, aid_rows)
+            addr = (self._page_tables[req.slot:req.slot + 1].copy()
+                    if self.paged_kv else np.array([req.slot], np.int32))
+            self._set_slot_params_locked(req)
+            flight.record("serving", "admit", request=req.request_id,
+                          slot=req.slot, prompt_len=int(req.prompt.size),
+                          queue_wait_ms=round(
+                              1e3 * (req.t_admit - req.t_submit), 3))
+        return (ids, addr, np.array([req.prompt.size], np.int32),
+                np.array([req.temperature], np.float32),
+                np.array([req.top_k], np.int32),
+                np.asarray(req._base_key, np.uint32)[None],
+                np.array([req._adapter_slot], np.int32))
 
     def _prefill_hits(self, hits) -> None:
         """Prefix-cache hit path.  Dense pool: device-copy the cached

@@ -228,8 +228,10 @@ def test_pool_allocates_what_it_names(form):
 @pytest.mark.parametrize("form", list(FORMS))
 def test_pool_prompts_are_read_back_and_padding_lanes_write_nothing(form):
     """`with_prompts` then the per-slot read: a decode step over freshly
-    written prompts attends to exactly those prompts' K/V; a padding lane
-    (the scratch row, an all-sentinel table) changes no live row."""
+    written prompts attends to exactly those prompts' K/V; a lane that
+    names the scratch row, or whose table is all sentinel (the engine
+    sends none since ISSUE 30: its prefill has one row), changes no live
+    row — by the address alone, with no test of the lane."""
     layout, quantized = FORMS[form]
     pool = _pool(form)
     tables = _tables()
@@ -240,8 +242,6 @@ def test_pool_prompts_are_read_back_and_padding_lanes_write_nothing(form):
         addr = np.stack([tables[0], tables[2], np.full(N_PT, N_PAGES)])
     else:
         addr = np.array([0, 2, 4], np.int32)
-    np.testing.assert_array_equal(np.asarray(pool.real(jnp.asarray(addr))),
-                                  [True, True, False])
     fresh = pool.prompt_caches(3, bucket)
     width = bucket if layout == "paged" else L
     assert fresh[0][2] == 0 and tuple(fresh[0][0].shape) == (3, width, HKV,
