@@ -216,12 +216,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
 
 def _flash_fwd(q, k, v, scale, causal, window=None):
-    """q: [BH, Tq, D], k, v: [BH / group, Tk, D] → (out [BH,Tq,D], lse
-    [BH,Tq,1]).  With group > 1 (grouped-query attention) a grid step holds
-    one KV head and the `group` query heads that read it: the K and V tiles
-    are fetched once for all of them."""
+    """q: [BH, Tq, D], k: [BH / group, Tk, D], v: [BH / group, Tk, Dv] →
+    (out [BH,Tq,Dv], lse [BH,Tq,1]).  With group > 1 (grouped-query
+    attention) a grid step holds one KV head and the `group` query heads
+    that read it: the K and V tiles are fetched once for all of them.  The
+    values' head size may differ from the queries' and keys' (latent
+    attention's expanded form: 192 and 128); forward only."""
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
     bq, bk = _block_sizes(tq, tk)
     if group > 1:
@@ -248,18 +250,18 @@ def _flash_fwd(q, k, v, scale, causal, window=None):
         in_specs=[
             pl.BlockSpec((nb, bq, d), lambda b, i, j: (b, i, j * 0)),
             pl.BlockSpec((nkv, bk, d), lambda b, i, j: (b, j, i * 0)),
-            pl.BlockSpec((nkv, bk, d), lambda b, i, j: (b, j, i * 0)),
+            pl.BlockSpec((nkv, bk, dv), lambda b, i, j: (b, j, i * 0)),
         ],
         out_specs=[
-            pl.BlockSpec((nb, bq, d), lambda b, i, j: (b, i, j * 0)),
+            pl.BlockSpec((nb, bq, dv), lambda b, i, j: (b, i, j * 0)),
             pl.BlockSpec((nb, bq, 1), lambda b, i, j: (b, i, j * 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tqp, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, tqp, 1), jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((nb, bq, d), jnp.float32),
+            pltpu.VMEM((nb, bq, dv), jnp.float32),
             pltpu.VMEM((nb, bq, 128), jnp.float32),
             pltpu.VMEM((nb, bq, 128), jnp.float32),
         ],
@@ -648,6 +650,10 @@ def _flash_fwd_rule(q, k, v, scale, causal, window):
 
 def _flash_bwd_rule(scale, causal, window, res, do):
     q, k, v, out, lse = res
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "flash attention with values of another head size than the "
+            "queries is forward only (serving)")
     return _flash_bwd(q, k, v, out, lse, do, scale, causal, window)
 
 
@@ -657,8 +663,9 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # -- public API ---------------------------------------------------------------
 
 def flash_attention_bhtd(q, k, v, causal=True, scale=None, window=None):
-    """q: [BH, T, D]; k, v: [BH / group, T, D] (group 1, or grouped-query
-    heads: query head i reads KV head i // group).  `window` (causal only)
+    """q: [BH, T, D]; k: [BH / group, T, D]; v: [BH / group, T, Dv] (group
+    1, or grouped-query heads: query head i reads KV head i // group; Dv
+    other than D in the forward pass only).  `window` (causal only)
     keeps keys t - window + 1 .. t for query t; tiles wholly before the
     window are skipped like those above the diagonal."""
     if scale is None:
@@ -677,13 +684,13 @@ def flash_attention_bthd(q, k, v, causal=True, scale=None, window=None):
 
     def raw(qv, kv, vv):
         b, tq, h, d = qv.shape
-        tk, hk = kv.shape[1], kv.shape[2]
+        tk, hk, dv = kv.shape[1], kv.shape[2], vv.shape[3]
         q3 = jnp.transpose(qv, (0, 2, 1, 3)).reshape(b * h, tq, d)
         k3 = jnp.transpose(kv, (0, 2, 1, 3)).reshape(b * hk, tk, d)
-        v3 = jnp.transpose(vv, (0, 2, 1, 3)).reshape(b * hk, tk, d)
+        v3 = jnp.transpose(vv, (0, 2, 1, 3)).reshape(b * hk, tk, dv)
         o3 = flash_attention_bhtd(q3, k3, v3, causal=causal, scale=scale,
                                   window=window)
-        return jnp.transpose(o3.reshape(b, h, tq, d), (0, 2, 1, 3))
+        return jnp.transpose(o3.reshape(b, h, tq, dv), (0, 2, 1, 3))
 
     if isinstance(q, Tensor):
         return apply_op(raw, "flash_attention", (q, k, v), {})

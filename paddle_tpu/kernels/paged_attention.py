@@ -57,7 +57,9 @@ static ``read`` field of the layer caches (`models/kv_cache.py`
 
 The **dense** pool's decode read (:func:`dense_decode_attention`, at the
 end of this file) shares the interpret-mode rule: one pass over each row's
-live blocks, chosen where :func:`dense_read_block` says it applies.
+live blocks, chosen where :func:`dense_read_block` says it applies.  The
+**latent** pool's read (:func:`latent_decode_attention`, below it) is the
+same walk over one shared row per position.
 """
 from __future__ import annotations
 
@@ -583,3 +585,205 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret_now(),
     )(lengths, nb, row, blk, held, first, q, k_pool, v_pool, cols, qrows)
+
+
+# -- the latent pool's decode read --------------------------------------------
+#
+# Multi-head latent attention stores ONE row per position, ``[c | kr]``, that
+# every head reads (`models/kv_cache.py` "The latent kind").  In the absorbed
+# form the queries already carry ``W_UK``, so a block of the pool is a plain
+# matmul operand twice: scores = q [W * heads, width] x block, and the
+# values are the block's own leading ``values`` rows.  A block is fetched
+# once for all heads; the work list of live blocks is the dense kernel's.
+#
+# The kernel reads the pool TRANSPOSED, ``[rows, width, max_len]``, positions
+# in the lanes: that is how the TPU keeps a ``[rows, max_len, 576]`` array
+# anyway (576 is no multiple of 128, so its default layout puts the
+# 16,384-long axis minor), and the transpose is then a relabelling; handed
+# the array as it is named, XLA copied every layer's whole pool to the other
+# layout and back in every decode step (20 copies of 623 MB: PERF.md section
+# 6, PR 31).
+
+# positions per block, timed on the v5e at 33 rows x 16,384 of 576 bf16
+# numbers, contexts of 1k-11k (PERF.md section 6, PR 31): 1.24 / 0.80 / 0.61
+# / 0.52 / 0.50 ms a layer at 128 / 256 / 512 / 1,024 / 2,048 -- the read is
+# compute-bound, so a step's fixed cost counts for more than the rounding;
+# past 1,024 the rounding at the cell's mean context of 3.7k takes the rest
+LATENT_BLOCK = 1024
+
+
+def latent_read_block(*, width: int, dtype, max_len: int):
+    """The block size at which the latent pool's decode read goes through
+    the kernel, or ``None`` where it keeps the XLA read: on the ``cpu``
+    backend (unless a test pinned the mode) or for a ``max_len`` the block
+    does not divide.  `dense_read_block`'s rule for grouped heads does not
+    carry over (one shared row of 1,152 bytes, not ``kv_heads`` rows)."""
+    if _INTERPRET is None and jax.default_backend() == "cpu":
+        return None
+    P = min(LATENT_BLOCK, max_len)
+    return None if max_len % P else P
+
+
+def _latent_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, q_ref,
+                   c_ref, o_ref, m_ref, l_ref, acc_ref, *, P, W, H, V,
+                   scale):
+    t = pl.program_id(0)
+    r, i = row_ref[t], blk_ref[t]
+    n = nb_ref[r]
+    D = q_ref.shape[-1]
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(i < n)
+    def _block():
+        c = c_ref[0]                                        # [D, P]
+        q2 = q_ref[0].reshape(W * H, D).astype(c.dtype)
+        s = jax.lax.dot_general(
+            q2, c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        pos = i * P + jax.lax.broadcasted_iota(jnp.int32, (W * H, P), 1)
+        # the query of a score row: row // H (none to tell apart at W = 1)
+        qw = 0 if W == 1 else jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (W * H, P), 0), jnp.int32(H))
+        s = jnp.where(pos <= len_ref[r] + qw, s, jnp.float32(_NEG_INF))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a dropped pair underflows to exactly 0: position 0 is live for
+        # every query of a live row, so m_new is finite from block 0 on
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(c.dtype), c[:V], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(i + 1 >= n)
+    def _finish():
+        l = l_ref[...]
+        out = jnp.where(l > 0, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0)
+        o_ref[0] = out.reshape(W, H, V).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, pool, lengths, block=None, *, scale: float,
+                            values: int):
+    """Per-slot decode attention over the dense LATENT pool in the absorbed
+    form, streaming live blocks only.
+
+    Args:
+        q: ``[n_rows, W, heads, width]`` absorbed queries (``q_nope W_UK``
+            beside the rotated ``q_rope``) of the step's new positions.
+        pool: ``[n_rows, max_len, width]``, post-write: every head reads
+            the one row of a position; its first ``values`` columns are
+            the values.
+        lengths: ``[n_rows]`` int32 start positions; a parked row sits at
+            ``max_len`` and reads nothing.
+        block: positions per block (default :data:`LATENT_BLOCK`, at most
+            ``max_len``); must divide ``max_len``.
+        scale: the model's ``1 / sqrt(nope + rope)`` — not ``width``.
+
+    Returns:
+        ``[n_rows, W, heads, values]`` in ``q.dtype`` (zeros for a parked
+        row); the caller takes it through ``W_UV``.
+    """
+    B, W, H, D = q.shape
+    L, V = pool.shape[1], int(values)
+    P = min(LATENT_BLOCK, L) if block is None else int(block)
+    if L % P:
+        raise ValueError(f"block={P} does not divide max_len={L}")
+    n_blk = L // P
+    lengths = jnp.asarray(lengths, jnp.int32)
+    nb = live_blocks(lengths, W, L, P)
+    steps, row, blk, held = _work_list(nb, n_blk)
+
+    def _cmap(t, ln, nbr, rw, bk, hd):
+        nblk = jnp.int32(n_blk)
+        return (jax.lax.div(hd[t], nblk), t * 0, jax.lax.rem(hd[t], nblk))
+
+    def _qmap(t, ln, nbr, rw, bk, hd):
+        return (rw[t], t * 0, t * 0, t * 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((1, W, H, D), _qmap),
+                  pl.BlockSpec((1, D, P), _cmap)],
+        out_specs=pl.BlockSpec((1, W, H, V), _qmap),
+        scratch_shapes=[pltpu.VMEM((W * H, 1), jnp.float32),   # running max
+                        pltpu.VMEM((W * H, 1), jnp.float32),   # running sum
+                        pltpu.VMEM((W * H, V), jnp.float32)],  # accumulator
+    )
+    kernel = functools.partial(_latent_kernel, P=P, W=W, H=H, V=V,
+                               scale=float(scale))
+    return pl.pallas_call(
+        kernel,
+        name="latent_decode_read",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, W, H, V), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret_now(),
+    )(lengths, nb, row, blk, held, q, jnp.swapaxes(pool, 1, 2))
+
+
+_WRITE_LANES = 128      # positions of the block a written position lies in
+
+
+def _latent_write_kernel(len_ref, new_ref, c_ref, o_ref, *, P, W, n_blk):
+    b, j = pl.program_id(0), pl.program_id(1)
+    blk = jnp.minimum(jax.lax.div(len_ref[b], jnp.int32(P)) + j,
+                      jnp.int32(n_blk - 1))
+    out = c_ref[0]                                          # [D, P]
+    lane = blk * P + jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+    new = new_ref[0]                                        # [D, W]
+    for w in range(W):
+        out = jnp.where(lane == len_ref[b] + w, new[:, w:w + 1], out)
+    o_ref[0] = out
+
+
+def latent_pool_write(pool, new, lengths):
+    """The latent pool with ``new [n_rows, W, width]`` stored at each row's
+    positions ``length .. length + W - 1``, in place, in the layout the
+    read kernel uses (positions in the lanes; a scatter wants the width
+    there and XLA then copies the whole pool both ways).  One grid step a
+    row (two where a span can cross a block): the 128-position block that
+    holds the position is fetched, the column replaced, the block written
+    back.  A parked row (``length >= max_len``) rewrites its last block as
+    it was: the write drops."""
+    B, L, D = pool.shape
+    W = new.shape[1]
+    P = min(_WRITE_LANES, L)
+    if L % P or W > P:
+        raise ValueError(f"max_len={L} must be a multiple of {P} and the "
+                         f"span {W} at most {P}")
+    n_blk, sub = L // P, (1 if W == 1 else 2)
+
+    def _cmap(b, j, ln):
+        return (b, b * 0, jnp.minimum(jax.lax.div(ln[b], jnp.int32(P)) + j,
+                                      jnp.int32(n_blk - 1)))
+
+    def _nmap(b, j, ln):
+        return (b, b * 0, b * 0)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_write_kernel, P=P, W=W, n_blk=n_blk),
+        name="latent_pool_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, sub),
+            in_specs=[pl.BlockSpec((1, D, W), _nmap),
+                      pl.BlockSpec((1, D, P), _cmap)],
+            out_specs=pl.BlockSpec((1, D, P), _cmap)),
+        out_shape=jax.ShapeDtypeStruct((B, D, L), pool.dtype),
+        # operand 2 (after the prefetched lengths and `new`) is the pool
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret_now(),
+    )(jnp.asarray(lengths, jnp.int32),
+      jnp.swapaxes(new, 1, 2).astype(pool.dtype), jnp.swapaxes(pool, 1, 2))
+    return jnp.swapaxes(out, 1, 2)

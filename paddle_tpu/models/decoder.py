@@ -16,6 +16,23 @@ names.  One layer, x the residual stream [T, hidden], no bias anywhere:
                                                sliding_window_layout[l]
     x' = h + MoE(RMSNorm(h); routed by g)      softmax over the top-k logits
 
+The second is openPangu-Ultra-MoE-718B (FreedomIntelligence, 2025): 61 layers
+of multi-head latent attention (`kv_lora_rank` > 0: the queries through a
+normed rank-1,536 bottleneck, keys and values through a normed rank-512
+latent beside a 64-wide rotated key part every head shares; the CACHE holds
+that latent, 576 numbers a position), sandwich norms (a second RMSNorm on
+each sub-block's OUTPUT before the residual add), a dense SwiGLU MLP on the
+first `first_k_dense_replace` layers and then 256 SwiGLU experts, 8 per
+token by sigmoid scores renormalised over the chosen and scaled by 2.5,
+beside one shared expert; the router reads the normed MLP input:
+
+    a = N(x);  c_q = N(a W_qa);  [qn | qr] = c_q W_qb
+    [c | kr] = a W_kva;  c = N(c);  qr, kr = RoPE(qr, kr)     cache: [c | kr]
+    [kn | v] = c W_kvb;  s_h = (qn_h kn_h + qr_h kr) / sqrt(nope + rope)
+    h = x + N(softmax(s) v W_o);  m = N(h)
+    f = dense MLP(m)  or  sum_{e in top 8, held} p_e E_e(m) + E_shared(m)
+    x' = h + N(f)
+
 Serving rides the same KV-cache protocol as GPT (`models/kv_cache.py`):
 `DecoderForCausalLM(ids, caches=..., use_cache=True)`; the engine finds the
 trunk as `.decoder` and the head as `.lm_head`.  Serving only: no dropout,
@@ -25,6 +42,7 @@ every expert layer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -33,13 +51,14 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor
 from ..incubate.distributed.models.moe.dropless import DroplessMoE
 from ..nn import functional as F
-from ..nn.initializer import Normal
+from ..nn.initializer import Constant, Normal
 from ..nn.layer.common import Embedding
 from ..nn.layer.container import LayerList
 from ..nn.layer.norm import RMSNorm
 from ..nn.layer_base import Layer, ParamAttr
 from ..ops.linalg import matmul
-from .kv_cache import cache_positions, cached_attention
+from .kv_cache import (cache_positions, cached_attention,
+                       cached_latent_attention)
 
 _PERIOD = (0, 1, 1, 1)      # global without positions, then window + RoPE
 
@@ -67,6 +86,34 @@ class DecoderConfig:
     initializer_range: float = 0.02
     # (first, count): build this share of every expert layer; None = all
     experts_held: tuple | None = None
+    # the router: "softmax" over the top-k logits, or "sigmoid" scores
+    # renormalised over the chosen (norm_topk_prob), times the scale; fed
+    # from the layer's input (before attention) or from the normed MLP input
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    router_before_attention: bool = True
+    hidden_act: str = "relu"            # the gate's activation: relu | silu
+    n_shared_experts: int = 0           # shared experts of the experts' width
+    # the first layers carry a dense gated MLP of this width, no experts
+    first_k_dense_replace: int = 0
+    intermediate_size: int = 0
+    # one more RMSNorm on the attention's and on the MLP's output, each
+    # before its residual add
+    sandwich_norm: bool = False
+    # latent attention where kv_lora_rank > 0 (head_dim and
+    # num_key_value_heads are then not read)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # seeded initialisation where it is not Normal(0, initializer_range) and
+    # gains of 1: the embedding's standard deviation, the sandwich norms'
+    # gain, the gain of the latent attention's inner norm on the queries
+    # (it scales every attention score: how peaked a random model attends)
+    embedding_std: float | None = None
+    sandwich_norm_gain: float = 1.0
+    q_norm_gain: float = 1.0
 
     def __post_init__(self):
         self.rope_layout = tuple(self.rope_layout)
@@ -79,8 +126,15 @@ class DecoderConfig:
             raise ValueError(
                 f"{self.num_attention_heads} query heads are no multiple of "
                 f"{self.num_key_value_heads} KV heads")
-        if self.head_dim % 2:
+        if self.head_dim % 2 or self.qk_rope_head_dim % 2:
             raise ValueError("head_dim must be even for half-split RoPE")
+        if self.kv_lora_rank and not (self.q_lora_rank and self.v_head_dim
+                                      and self.qk_nope_head_dim
+                                      and self.qk_rope_head_dim):
+            raise ValueError("latent attention needs q_lora_rank, "
+                             "qk_nope_head_dim, qk_rope_head_dim, v_head_dim")
+        if self.first_k_dense_replace and not self.intermediate_size:
+            raise ValueError("first_k_dense_replace needs intermediate_size")
 
     def window(self, layer: int):
         return (self.sliding_window_size
@@ -98,11 +152,45 @@ DECODER_CONFIGS = {
         sliding_window_layout=_PERIOD, sliding_window_size=8,
         moe_num_primary_experts=8, moe_num_active_primary_experts=2,
         moe_ffn_hidden_size=32),
+    # openPangu-Ultra-MoE-718B as published (config.json of the source;
+    # the multi-token-prediction module is not built)
+    "openpangu-ultra-moe-718b": dict(
+        vocab_size=153600, hidden_size=7680, num_hidden_layers=61,
+        num_attention_heads=128, num_key_value_heads=128,
+        max_position_embeddings=131072, rms_norm_eps=1e-5,
+        rope_theta=25.6e6, rope_layout=(1,) * 61,
+        sliding_window_layout=(0,) * 61, moe_num_primary_experts=256,
+        moe_num_active_primary_experts=8, moe_ffn_hidden_size=2048,
+        scoring_func="sigmoid", routed_scaling_factor=2.5,
+        router_before_attention=False, hidden_act="silu",
+        n_shared_experts=1, first_k_dense_replace=3,
+        intermediate_size=18432, sandwich_norm=True, kv_lora_rank=512,
+        q_lora_rank=1536, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128),
+    # the same block at test size: 1 dense + 4 expert layers
+    "openpangu-tiny": dict(
+        vocab_size=256, hidden_size=64, num_hidden_layers=5,
+        num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=128, rms_norm_eps=1e-5, rope_theta=25.6e6,
+        rope_layout=(1,) * 5, sliding_window_layout=(0,) * 5,
+        moe_num_primary_experts=16, moe_num_active_primary_experts=4,
+        moe_ffn_hidden_size=32, scoring_func="sigmoid",
+        routed_scaling_factor=2.5, router_before_attention=False,
+        hidden_act="silu", n_shared_experts=1, first_k_dense_replace=1,
+        intermediate_size=128, sandwich_norm=True, kv_lora_rank=16,
+        q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16),
 }
 
 
 def decoder_config(name: str, **overrides) -> DecoderConfig:
     return DecoderConfig(**{**DECODER_CONFIGS[name], **overrides})
+
+
+def _matrix(layer: Layer, config: DecoderConfig, shape):
+    """A weight matrix of `layer`, Normal(0, initializer_range)."""
+    return layer.create_parameter(shape, attr=ParamAttr(
+        initializer=Normal(0.0, config.initializer_range)))
 
 
 class DecoderAttention(Layer):
@@ -119,10 +207,7 @@ class DecoderAttention(Layer):
                            if config.rope_layout[layer] else None)
         self.window = config.window(layer)
 
-        def param(shape):
-            return self.create_parameter(shape, attr=ParamAttr(
-                initializer=Normal(0.0, config.initializer_range)))
-
+        param = functools.partial(_matrix, self, config)
         self.q_proj = param((h, self.num_heads * d))
         self.k_proj = param((h, self.num_kv_heads * d))
         self.v_proj = param((h, self.num_kv_heads * d))
@@ -149,27 +234,118 @@ class DecoderAttention(Layer):
         return matmul(out, self.o_proj), new_cache
 
 
+class LatentAttention(Layer):
+    """Multi-head latent attention (module docstring): six projections and
+    two inner norms; the layer's cache is the latent ``[c | kr]``, and
+    `cached_latent_attention` reads it expanded (a prompt with no past) or
+    absorbed (the pool)."""
+
+    def __init__(self, config: DecoderConfig, layer: int):
+        super().__init__()
+        h, heads = config.hidden_size, config.num_attention_heads
+        self.num_heads = heads
+        self.nope, self.rope = config.qk_nope_head_dim, config.qk_rope_head_dim
+        self.rank = config.kv_lora_rank
+        self.rope_theta = float(config.rope_theta)
+        eps = config.rms_norm_eps
+
+        param = functools.partial(_matrix, self, config)
+        self.q_a_proj = param((h, config.q_lora_rank))
+        self.q_a_norm = RMSNorm(
+            config.q_lora_rank, epsilon=eps, weight_attr=ParamAttr(
+                initializer=Constant(config.q_norm_gain)))
+        self.q_b_proj = param((config.q_lora_rank,
+                               heads * (self.nope + self.rope)))
+        self.kv_a_proj = param((h, self.rank + self.rope))
+        self.kv_a_norm = RMSNorm(self.rank, epsilon=eps)
+        self.kv_b_proj = param((self.rank,
+                                heads * (self.nope + config.v_head_dim)))
+        self.o_proj = param((heads * config.v_head_dim, h))
+
+    def forward(self, x, positions, cache=None):
+        b, t = x.shape[0], x.shape[1]
+        q = matmul(self.q_a_norm(matmul(x, self.q_a_proj)),
+                   self.q_b_proj).reshape([b, t, self.num_heads,
+                                           self.nope + self.rope])
+        ckr = matmul(x, self.kv_a_proj)
+        c = self.kv_a_norm(ckr[:, :, :self.rank])
+        kr = ckr[:, :, self.rank:].reshape([b, t, 1, self.rope])
+        with jax.named_scope("attn.latent"):
+            qr = F.rotary_embedding(q[:, :, :, self.nope:], positions,
+                                    theta=self.rope_theta)
+            kr = F.rotary_embedding(kr, positions, theta=self.rope_theta)
+            latent = jnp.concatenate(
+                [c._value, kr._value[:, :, 0].astype(c._value.dtype)], -1)
+            out, new_cache = cached_latent_attention(
+                q[:, :, :, :self.nope], qr, latent, self.kv_b_proj, cache,
+                owner="models.decoder.LatentAttention")
+        out = out.reshape([b, t, -1])
+        return matmul(out, self.o_proj), new_cache
+
+
+class GatedMLP(Layer):
+    """The dense gated MLP of the leading layers: (act(m W_g) * (m W_u))
+    W_d, no biases."""
+
+    def __init__(self, config: DecoderConfig):
+        super().__init__()
+        h, f = config.hidden_size, config.intermediate_size
+        self.act = getattr(F, config.hidden_act)
+
+        param = functools.partial(_matrix, self, config)
+        self.gate_proj, self.up_proj = param((h, f)), param((h, f))
+        self.down_proj = param((f, h))
+
+    def forward(self, m):
+        with jax.named_scope("mlp.dense"):
+            return matmul(self.act(matmul(m, self.gate_proj)) *
+                          matmul(m, self.up_proj), self.down_proj)
+
+
 class DecoderLayer(Layer):
     def __init__(self, config: DecoderConfig, layer: int):
         super().__init__()
         eps = config.rms_norm_eps
         self.input_norm = RMSNorm(config.hidden_size, epsilon=eps)
-        self.self_attn = DecoderAttention(config, layer)
+        self.self_attn = (LatentAttention if config.kv_lora_rank else
+                          DecoderAttention)(config, layer)
         self.post_attn_norm = RMSNorm(config.hidden_size, epsilon=eps)
+        self.sandwich = config.sandwich_norm
+        if self.sandwich:
+            gain = ParamAttr(initializer=Constant(config.sandwich_norm_gain))
+            self.attn_out_norm = RMSNorm(config.hidden_size, epsilon=eps,
+                                         weight_attr=gain)
+            self.mlp_out_norm = RMSNorm(config.hidden_size, epsilon=eps,
+                                        weight_attr=gain)
+        self.router_before_attention = config.router_before_attention
+        if layer < config.first_k_dense_replace:
+            self.moe, self.mlp = None, GatedMLP(config)
+            return
         self.moe = DroplessMoE(
             config.hidden_size, config.moe_ffn_hidden_size,
             config.moe_num_primary_experts,
             config.moe_num_active_primary_experts,
             norm_topk_prob=config.norm_topk_prob,
             experts_held=config.experts_held,
+            scoring=config.scoring_func,
+            routed_scale=config.routed_scaling_factor,
+            activation=config.hidden_act,
+            shared_width=config.n_shared_experts * config.moe_ffn_hidden_size,
             weight_attr=ParamAttr(
                 initializer=Normal(0.0, config.initializer_range)))
 
     def forward(self, x, positions, cache=None):
         y, new_cache = self.self_attn(self.input_norm(x), positions, cache)
-        h = x + y
-        # the router reads the layer's input, before the attention norm
-        return h + self.moe(self.post_attn_norm(h), router_input=x), new_cache
+        h = x + (self.attn_out_norm(y) if self.sandwich else y)
+        m = self.post_attn_norm(h)
+        if self.moe is None:
+            f = self.mlp(m)
+        else:
+            # SmallThinker's router reads the layer's input, before the
+            # attention norm; the latent model's the normed MLP input
+            f = self.moe(m, router_input=x if self.router_before_attention
+                         else m)
+        return h + (self.mlp_out_norm(f) if self.sandwich else f), new_cache
 
 
 class DecoderModel(Layer):
@@ -180,7 +356,8 @@ class DecoderModel(Layer):
         self.config = config
         self.embed_tokens = Embedding(
             config.vocab_size, config.hidden_size, weight_attr=ParamAttr(
-                initializer=Normal(0.0, config.initializer_range)))
+                initializer=Normal(0.0, config.embedding_std or
+                                   config.initializer_range)))
         self.layers = LayerList([DecoderLayer(config, i)
                                  for i in range(config.num_hidden_layers)])
         self.final_norm = RMSNorm(config.hidden_size,
@@ -211,13 +388,31 @@ class DecoderForCausalLM(Layer):
 
     # Engine options these layers cannot serve: refused when the engine is
     # built (serving.Engine reads this), never answered wrongly
-    serving_unsupported = {
+    _UNSUPPORTED = {
         "adapters": "the LoRA banks add their delta to a fused qkv "
                     "projection, which this block does not have",
         "decode_kernel='pallas'": "the paged decode kernel reads neither "
                                   "grouped-query heads nor a sliding "
                                   "window; the dense pool's kernel does",
     }
+    # ... and those a latent cache cannot: it lives on the dense,
+    # unquantised pool, one row per position for all heads
+    _LATENT_UNSUPPORTED = {
+        "paged_kv": "a page of the paged pool holds a K and a V per head; "
+                    "the latent row has no page yet (nor the host tier "
+                    "that stores pages)",
+        "kv_dtype='int8'": "the int8 pool quantises K and V per position "
+                           "over [kv_heads, head_dim]; the latent row has "
+                           "no such pair",
+        "decode_kernel='pallas'": "the paged decode kernel reads K and V "
+                                  "pages; the latent dense pool has its own",
+    }
+
+    @property
+    def serving_unsupported(self) -> dict:
+        if self.decoder.config.kv_lora_rank:
+            return {**self._UNSUPPORTED, **self._LATENT_UNSUPPORTED}
+        return self._UNSUPPORTED
 
     def __init__(self, decoder: DecoderModel):
         super().__init__()

@@ -30,6 +30,18 @@ returns ``(out [B, t, heads, head_dim], new_cache)``.  The cache forms:
 `cache_positions(cache, t)` gives the positions of the ``t`` new tokens of a
 layer's cache in any of these forms (learned position ids, RoPE angles).
 
+**The latent kind** (`cached_latent_attention`, multi-head latent attention).
+A layer stores ONE array per position, ``[c | kr]``: the normed compressed
+KV (``kv_lora_rank`` numbers) beside the rotated key part every head shares,
+not a K and a V per head.  Every cache form above carries it in the ``k``
+place with ``None`` in the ``v`` place (`KVPool.latent`, `SlotCache.latent`):
+no second buffer, no new arity.  A prompt with no past attends in the
+EXPANDED form (keys and values made from the latent by ``W_kvb``, through
+flash with q/k wider than v); a read of the pool attends in the ABSORBED
+form (the query taken through ``W_UK``, scores against the latent itself,
+values = its first ``kv_lora_rank`` columns, the output taken through
+``W_UV``): one shared row per position for all heads.  Dense pool only.
+
 int8 storage (``Engine(kv_dtype="int8")``): one float32 scale per *cached
 position* (the absmax over that position's ``[kv_heads, head_dim]`` vector),
 so a new token's K/V is quantised against its OWN absmax at write time,
@@ -52,6 +64,9 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 
 INT8_MAX = 127.0
+# (position, head) pairs whose expanded keys and values a latent prefill
+# holds at a time (8,192 positions x 16 heads)
+_EXPANDED_PAIRS = 1 << 17
 # floor for the per-row scale: an all-zero row (unwritten pool padding)
 # quantizes to zeros with a tiny finite scale instead of dividing by 0
 _SCALE_EPS = 1e-8
@@ -106,6 +121,21 @@ def _page_address(table, cols, num_pages: int, page_size: int):
     return pid, cols % page_size
 
 
+def _latent_read(q, latent, cols, scale: float, values: int):
+    """The masked XLA read of the latent kind, absorbed form: queries
+    ``q [rows, t, heads, width]`` at positions ``cols`` against every row's
+    whole latent ``[rows, L, width]``, all heads on the one shared row;
+    the values are a row's first ``values`` columns.  ``[rows, t, heads,
+    values]``; softmax in float32."""
+    s = jnp.einsum("bthd,bld->bhtl", q, latent.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * jnp.float32(scale)
+    s = jnp.where(_span_mask(cols, latent.shape[1], None), s,
+                  jnp.float32(-1e30))
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhtl,blc->bthc", p,
+                      latent[..., :values].astype(q.dtype))
+
+
 class KernelRead(NamedTuple):
     """The Pallas kernel one program's attention read goes through
     (`kernels/paged_attention.py`), chosen once by the engine when it is
@@ -129,7 +159,9 @@ class SlotCache:
     every row whole; a :class:`KernelRead` in the engine's decode
     program).  The new span may be wider than one position (speculative
     verification, prefix-tail prefill): position j of a row writes at its
-    own offset + j and attends causally within the span."""
+    own offset + j and attends causally within the span.  The latent kind
+    (module docstring) holds ``[rows, L, width]`` in ``k`` and None in
+    ``v``."""
     k: Any
     v: Any
     lengths: Any
@@ -142,6 +174,10 @@ class SlotCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    @property
+    def latent(self) -> bool:
+        return self.v is None
 
     @property
     def span(self) -> int:
@@ -157,6 +193,10 @@ class SlotCache:
         """The cache with ``k`` / ``v`` stored at storage index ``at``
         (quantised where the storage has scales); an index out of range
         DROPS its write."""
+        if self.latent:
+            return dataclasses.replace(
+                self, k=self.k.at[at].set(k.astype(self.k.dtype),
+                                          mode="drop"))
         if not self.quantized:
             return dataclasses.replace(
                 self,
@@ -173,7 +213,13 @@ class SlotCache:
     def written(self, k, v, cols):
         """The cache with the new positions' K/V scattered in at ``cols``
         (``lengths`` as they were).  A write past a dense row's end, or
-        through a sentinel page, drops: never clipped onto a live row."""
+        through a sentinel page, drops: never clipped onto a live row.  The
+        latent kind in a program that reads through the kernel writes
+        through the kernel's own write (a span from ``cols[:, 0]`` on)."""
+        if self.latent and self.read is not None:
+            from ..kernels import paged_attention as pk
+            return dataclasses.replace(self, k=pk.latent_pool_write(
+                self.k, k, cols[:, 0]))
         if self.layout == "paged":
             return self.put(_page_address(
                 jnp.asarray(self.table, jnp.int32), cols, self.k.shape[0],
@@ -198,10 +244,20 @@ class SlotCache:
             k, v = dequantize_pool(k, ks, dtype), dequantize_pool(v, vs, dtype)
         return k, v
 
-    def attend(self, q, cols, window=None):
+    def attend(self, q, cols, window=None, scale=None, values=None):
         """Attention of the span's queries ``q [rows, t, heads, hd]`` at
         ``cols`` over the (already written) cache, through the read this
-        program was given."""
+        program was given.  The latent kind: ``q [rows, t, heads, width]``
+        absorbed queries, ``scale`` the model's, ``values`` the leading
+        columns of a latent row that are its values."""
+        if self.latent:
+            if self.read is None:
+                return _wrap(_latent_read(_raw(q), self.k, cols, scale,
+                                          values))
+            from ..kernels import paged_attention as pk
+            return _wrap(pk.latent_decode_attention(
+                _raw(q), self.k, self.lengths, block=self.read.block,
+                scale=scale, values=values))
         if self.read is None:
             k, v = self._rows(_raw(q).dtype)
             return F.scaled_dot_product_attention(
@@ -238,9 +294,12 @@ class KVPool:
     ``paged``: ``rows`` pages of ``row_len`` positions, addressed through
     per-slot page tables whose sentinel entry is ``rows``.  ``dtype`` is
     the precision K/V are computed in; storage is int8 with float32 scale
-    sidecars when ``k_scale`` is there."""
+    sidecars when ``k_scale`` is there.  What a layer stores is the model's
+    to say (the cache shapes it reports): a K and a V per KV head, or, the
+    latent kind, one ``[rows, row_len, width]`` array in ``k`` and no ``v``
+    at all (``v is None``; dense, unquantised)."""
     k: list
-    v: list
+    v: Optional[list]
     k_scale: Optional[list] = None
     v_scale: Optional[list] = None
     layout: str = "dense"
@@ -250,7 +309,13 @@ class KVPool:
     def zeros(cls, kv, *, layout: str, rows: int, row_len: int,
               quantized: bool):
         """Sized from the per-layer ``(k, v)`` cache shapes the model
-        reports (``[.., .., kv_heads, hd]``: KV heads, not query heads)."""
+        reports (``[.., .., kv_heads, hd]``: KV heads, not query heads; or
+        ``([.., .., width], None)`` from a layer that stores a latent)."""
+        latent = kv[0][1] is None
+        if latent and (quantized or layout != "dense"):
+            raise ValueError("a latent cache lives on the dense, "
+                             "unquantised pool only")
+
         def buf(s):
             return jnp.zeros((rows, row_len) + tuple(s.shape[2:]),
                              jnp.int8 if quantized else s.dtype)
@@ -258,8 +323,13 @@ class KVPool:
         def scales():
             return ([jnp.zeros((rows, row_len), jnp.float32) for _ in kv]
                     if quantized else None)
-        return cls([buf(k) for k, _ in kv], [buf(v) for _, v in kv],
+        return cls([buf(k) for k, _ in kv],
+                   None if latent else [buf(v) for _, v in kv],
                    scales(), scales(), layout, jnp.dtype(kv[0][0].dtype))
+
+    @property
+    def latent(self) -> bool:
+        return self.v is None
 
     @property
     def quantized(self) -> bool:
@@ -289,13 +359,15 @@ class KVPool:
         layout)."""
         none = [None] * len(self.k)
         return [SlotCache(k, v, lengths, tables, ks, vs, self.layout, read)
-                for k, v, ks, vs in zip(self.k, self.v, self.k_scale or none,
+                for k, v, ks, vs in zip(self.k, self.v or none,
+                                        self.k_scale or none,
                                         self.v_scale or none)]
 
     def updated(self, caches):
         """The pool holding the buffers of the caches a model returned."""
         return dataclasses.replace(
-            self, k=[c.k for c in caches], v=[c.v for c in caches],
+            self, k=[c.k for c in caches],
+            v=None if self.latent else [c.v for c in caches],
             k_scale=[c.k_scale for c in caches] if self.quantized else None,
             v_scale=[c.v_scale for c in caches] if self.quantized else None)
 
@@ -305,9 +377,12 @@ class KVPool:
         positions, in the compute precision: a dense pool takes whole rows
         back, a paged pool the bucket's positions."""
         length = bucket if self.layout == "paged" else self.k[0].shape[1]
-        return [(_wrap(jnp.zeros((n, length) + k.shape[2:], self.dtype)),
-                 _wrap(jnp.zeros((n, length) + v.shape[2:], self.dtype)), 0)
-                for k, v in zip(self.k, self.v)]
+
+        def zeros(p):
+            return None if p is None else _wrap(
+                jnp.zeros((n, length) + p.shape[2:], self.dtype))
+        return [(zeros(k), zeros(v), 0)
+                for k, v in zip(self.k, self.v or [None] * len(self.k))]
 
     def with_prompts(self, caches, addr, prompt_lens):
         """The pool with freshly prefilled :meth:`prompt_caches` written
@@ -420,3 +495,85 @@ def cached_attention(q, k, v, cache=None, *, window=None, dropout_p=0.0,
             attn_mask=_wrap(_span_mask(cols, k_raw.shape[1], window)),
             dropout_p=0.0, is_causal=False, training=False)
     return out, (_wrap(k_raw), _wrap(v_raw), start + t)
+
+
+def cached_latent_attention(q_nope, q_rope, latent, w_kvb, cache=None, *,
+                            owner="models.kv_cache"):
+    """Multi-head latent attention over the cache forms of the module
+    docstring.  ``q_nope [B, t, heads, nope]`` and ``q_rope [B, t, heads,
+    rope]`` (rotated) are the new positions' queries, ``latent [B, t,
+    kv_lora_rank + rope]`` their ``[c | kr]`` rows (normed, rotated: what
+    the cache stores), ``w_kvb [kv_lora_rank, heads * (nope + v)]`` the
+    up-projection whose head slices are ``[W_UK | W_UV]``.  The scale is
+    ``1 / sqrt(nope + rope)`` in both forms.  Returns ``(out [B, t, heads,
+    v], new_cache)``."""
+    b, t, heads, nope = q_nope.shape
+    q_nope, q_rope, latent, w_kvb = (_raw(a) for a in (q_nope, q_rope,
+                                                       latent, w_kvb))
+    rank = w_kvb.shape[0]
+    w = w_kvb.reshape(rank, heads, -1)
+    scale = 1.0 / float(nope + q_rope.shape[-1]) ** 0.5
+
+    def expanded(lat):
+        """Keys and values of every head from the latent rows ``lat``;
+        the new queries attend causally (flash where it applies).  A long
+        prompt goes a group of heads at a time: the expanded keys and
+        values of all 128 heads of 8,192 positions, with the kernel's
+        layout copies, are 3.4 GB."""
+        c, kr = lat[..., :rank], lat[..., rank:]
+        n = max(1, min(heads, lat.shape[1] * heads // _EXPANDED_PAIRS))
+        while heads % n:
+            n -= 1
+
+        def group(g):
+            wg, qn, qr = g                  # [rank, hg, nope+v], [b,t,hg,.]
+            kv = jnp.einsum("blc,chd->blhd", c, wg.astype(c.dtype))
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                kr[:, :, None, :], kv.shape[:3] + (kr.shape[-1],))], -1)
+            return _raw(F.scaled_dot_product_attention(
+                _wrap(jnp.concatenate([qn, qr], -1)), _wrap(k),
+                _wrap(kv[..., nope:]), dropout_p=0.0, is_causal=True,
+                training=False))
+
+        if n == 1:
+            return _wrap(group((w, q_nope, q_rope)))
+
+        def split(a, axis):                 # heads -> [n, heads / n] first
+            a = a.reshape(a.shape[:axis] + (n, heads // n) +
+                          a.shape[axis + 1:])
+            return jnp.moveaxis(a, axis, 0)
+        out = jax.lax.map(group, (split(w, 1), split(q_nope, 2),
+                                  split(q_rope, 2)))     # [n, b, t, hg, v]
+        return _wrap(jnp.moveaxis(out, 0, 2).reshape(
+            out.shape[1:3] + (heads, out.shape[-1])))
+
+    def absorbed(slots: SlotCache):
+        cols = slots.cols(t)
+        slots = slots.written(latent, None, cols)
+        q = jnp.concatenate([
+            jnp.einsum("bthn,chn->bthc", q_nope,
+                       w[..., :nope].astype(q_nope.dtype)), q_rope], -1)
+        o = _raw(slots.attend(_wrap(q), cols, scale=scale, values=rank))
+        out = jnp.einsum("bthc,chv->bthv", o, w[..., nope:].astype(o.dtype))
+        return _wrap(out), dataclasses.replace(slots,
+                                               lengths=slots.lengths + t)
+
+    if isinstance(cache, SlotCache):
+        return absorbed(cache)
+    if cache is None or len(cache) != 3:
+        if cache is not None:
+            from ..observability.retrace import note_dynamic_cache_growth
+            note_dynamic_cache_growth(owner)
+            latent = jnp.concatenate([_raw(cache[0]), latent], axis=1)
+        return expanded(latent), (_wrap(latent), None)
+    buf, _, pos0 = cache
+    if isinstance(pos0, int) and pos0 == 0:
+        # static prefill: no past, so the prompt attends in the expanded
+        # form and keeps the causal flash path
+        new = jax.lax.dynamic_update_slice(
+            _raw(buf), latent.astype(_raw(buf).dtype), (0, 0, 0))
+        return expanded(latent), (_wrap(new), None, t)
+    start = jnp.asarray(pos0, jnp.int32)
+    out, new = absorbed(SlotCache(
+        _raw(buf), None, jnp.broadcast_to(start, (b,))))
+    return out, (_wrap(new.k), None, start + t)

@@ -140,6 +140,7 @@ SERVING_HOST_PREFIX_PROMOTE_SECONDS = \
 SERVING_MOE_ASSIGNMENTS = "paddle_tpu_serving_moe_assignments_total"
 SERVING_MOE_EXPERTS_TOUCHED = "paddle_tpu_serving_moe_experts_touched_total"
 SERVING_MOE_LOAD_MAX = "paddle_tpu_serving_moe_load_max_total"
+SERVING_MOE_ROUTED = "paddle_tpu_serving_moe_routed_total"
 SERVING_DECODE_SAMPLED_STEPS = "paddle_tpu_serving_decode_sampled_steps_total"
 SERVING_DECODE_TOPK_STEPS = "paddle_tpu_serving_decode_topk_steps_total"
 SERVING_PREFILL_WAVES = "paddle_tpu_serving_prefill_waves_total"
@@ -608,7 +609,9 @@ class Engine:
         # what this model's layers cannot serve is refused here, by name,
         # never answered wrongly: {option: reason} on the model's class
         asked = {"adapters": adapters is not None,
-                 "decode_kernel='pallas'": decode_kernel == "pallas"}
+                 "decode_kernel='pallas'": decode_kernel == "pallas",
+                 "paged_kv": bool(paged_kv),
+                 "kv_dtype='int8'": kv_dtype == "int8"}
         for option, why in getattr(model, "serving_unsupported", {}).items():
             if asked.get(option):
                 raise ValueError(f"{type(model).__name__} cannot be served "
@@ -803,7 +806,7 @@ class Engine:
                         "decode_kv_read_positions": 0,
                         "decode_sampled_steps": 0, "decode_topk_steps": 0,
                         "moe_assignments": 0, "moe_experts_touched": 0,
-                        "moe_load_max": 0,
+                        "moe_load_max": 0, "moe_routed": 0,
                         "tokens": 0, "resubmitted": 0, "redispatched": 0,
                         "interrupted": 0, "prefix_hits": 0,
                         "prefix_misses": 0, "prefix_evictions": 0,
@@ -1280,13 +1283,14 @@ class Engine:
                 with _swapped_state(model, vals), _collect_load() as load:
                     _, caches = model(Tensor(ii, _internal=True),
                                       use_cache=True)
-                return ([(k._value, v._value) for k, v in caches],
-                        load.total())
+                return ([(k._value, None if v is None else v._value)
+                         for k, v in caches], load.total())
             return jax.eval_shape(f, self._values,
                                   jnp.zeros((1, 1), jnp.int64))
 
         # the pools are sized from the cache shapes the model returns (KV
-        # heads, not query heads); a model with expert layers also counts
+        # heads, not query heads; one latent array and no V from a
+        # latent-attention layer); a model with expert layers also counts
         # their load, returned behind each step's tokens
         kv, load_struct = _kv_struct()
         self._moe_load = load_struct is not None
@@ -1302,7 +1306,11 @@ class Engine:
                  "steps"),
                 (SERVING_MOE_LOAD_MAX, "moe_load_max",
                  "largest expert load of each layer, summed over layers "
-                 "and steps"))] if self._moe_load else []
+                 "and steps"),
+                (SERVING_MOE_ROUTED, "moe_routed",
+                 "token-to-expert assignments routed (real tokens x top-k, "
+                 "summed over layers), whoever holds the expert"))
+        ] if self._moe_load else []
         trunk = _trunk(model)
         # per layer: the sliding window its attention reads, None = all
         self._kv_windows = (list(trunk.attention_windows())
@@ -1374,6 +1382,11 @@ class Engine:
         if self.paged_kv:
             decode_read = (KernelRead("paged", self._page_alloc.page_size)
                            if self.decode_kernel == "pallas" else None)
+        elif kv[0][1] is None:
+            from ..kernels.paged_attention import latent_read_block
+            blk = latent_read_block(width=int(kv[0][0].shape[-1]),
+                                    dtype=kv[0][0].dtype, max_len=L)
+            decode_read = None if blk is None else KernelRead("dense", blk)
         else:
             from ..kernels.paged_attention import dense_read_block
             k0 = kv[0][0]
@@ -1404,9 +1417,9 @@ class Engine:
             all: the device sampler's token ids, behind them each token's
             log-probability under `logits` (the model's own distribution,
             before temperature and top-k; float32 bits as integers), and
-            last the expert load's three counts where the model has expert
+            last the expert load's four counts where the model has expert
             layers.  Host-sampled logits (no `logits` argument) go as they
-            are, the counts beside them as a second, 12-byte array."""
+            are, the counts beside them as a second, 16-byte array."""
             tot = load.total()
             if logits is None:
                 return out if tot is None else (out, tot)
@@ -2679,14 +2692,16 @@ class Engine:
     def _load_stats(load) -> dict:
         """One step's expert load as the short scalar stats of its emit
         span ({} for a model without expert layers): `moe_assignments` real
-        tokens x top-k, `moe_experts_touched` experts with at least one of
-        them, `moe_load_max` the largest expert load, each summed over the
-        layers."""
+        tokens x top-k on the experts held here, `moe_experts_touched`
+        experts with at least one of them, `moe_load_max` the largest
+        expert load, `moe_routed` real tokens x top-k whoever holds the
+        expert (= `moe_assignments` where all are held), each summed over
+        the layers."""
         if load is None:
             return {}
         return {"moe_assignments": int(load[0]),
                 "moe_experts_touched": int(load[1]),
-                "moe_load_max": int(load[2])}
+                "moe_load_max": int(load[2]), "moe_routed": int(load[3])}
 
     def _count_load(self, load):
         """Add one step's expert load to `stats()` and the registry (inside

@@ -371,8 +371,9 @@ def test_forced_routing_drops_nothing():
     np.testing.assert_allclose(
         got[0], _ref_logits(model, cfg, ids[0], np.arange(24)), atol=TOL,
         rtol=0)
-    # 4 layers: 24 tokens x 2, on 2 experts, 24 on the fullest
-    assert load.total().tolist() == [4 * 48, 4 * 2, 4 * 24]
+    # 4 layers: 24 tokens x 2, on 2 experts, 24 on the fullest; every
+    # expert is held, so all that was routed was assigned here
+    assert load.total().tolist() == [4 * 48, 4 * 2, 4 * 24, 4 * 48]
 
 
 def test_experts_held_shares_add_up_to_the_layer():

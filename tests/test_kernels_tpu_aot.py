@@ -309,3 +309,87 @@ def test_dropless_experts_compile_as_grouped_matmul(v5e, tokens):
     assert _mosaic_calls(c) == 4
     ideal = 6.0 * (-(-tokens * 6 // 128) * 128) * h * f
     assert c.cost_analysis()["flops"] < 1.5 * ideal
+
+
+# -- the latent-attention decoder's kernels at published widths (PR 31) -------
+
+@pytest.mark.parametrize("t", [2048, 8192])
+def test_flash_two_head_sizes_fwd_compiles(v5e, t):
+    """The expanded-form prefill call: 128 heads, q and k of 192 (128 + the
+    shared 64), v of 128."""
+    def fwd(q, k, v):
+        return fa.flash_attention_bthd(q, k, v, causal=True)
+
+    c = _compile(fwd, v5e, jax.ShapeDtypeStruct((1, t, 128, 192), bf16),
+                 jax.ShapeDtypeStruct((1, t, 128, 192), bf16),
+                 jax.ShapeDtypeStruct((1, t, 128, 128), bf16))
+    assert _mosaic_calls(c) == 1
+
+
+@pytest.mark.parametrize("W", [1, 3])
+def test_latent_decode_read_compiles(v5e, compiled_paged, W):
+    """`latent_decode_attention` at the cell's shape: 32 slots + scratch x
+    16384 rows of 576 (512 + 64), 128 absorbed query heads."""
+    B, L, H, D, V = 33, 16384, 128, 576, 512
+    blk = pa.latent_read_block(width=D, dtype=bf16, max_len=L)
+    assert blk == pa.LATENT_BLOCK
+
+    def read(q, pool, ln):
+        return pa.latent_decode_attention(q, pool, ln, block=blk,
+                                          scale=192 ** -0.5, values=V)
+
+    c = _compile(read, v5e, jax.ShapeDtypeStruct((B, W, H, D), bf16),
+                 jax.ShapeDtypeStruct((B, L, D), bf16),
+                 jax.ShapeDtypeStruct((B,), jnp.int32))
+    assert _mosaic_calls(c) == 1
+
+
+@pytest.mark.parametrize("tokens", [33, 8192], ids=["decode", "prefill"])
+def test_dropless_share_compiles_with_a_bounded_buffer(v5e, tokens):
+    """A share of 16 of 256 SwiGLU experts at published widths: the loop's
+    body holds `share_rows` gathered rows, never tokens x 8."""
+    from paddle_tpu.incubate.distributed.models.moe.dropless import (
+        dropless_moe, share_rows)
+    h, f, held, e = 7680, 2048, 16, 256
+
+    def layer(x, wr, wg, wu, wd, sg, su, sd):
+        return dropless_moe.raw(x, x, wr, wg, wu, wd, top_k=8, first=0,
+                                scoring="sigmoid", routed_scale=2.5,
+                                activation="silu", shared=(sg, su, sd))
+
+    c = _compile(layer, v5e, jax.ShapeDtypeStruct((tokens, h), bf16),
+                 jax.ShapeDtypeStruct((h, e), bf16),
+                 jax.ShapeDtypeStruct((held, h, f), bf16),
+                 jax.ShapeDtypeStruct((held, h, f), bf16),
+                 jax.ShapeDtypeStruct((held, f, h), bf16),
+                 jax.ShapeDtypeStruct((h, f), bf16),
+                 jax.ShapeDtypeStruct((h, f), bf16),
+                 jax.ShapeDtypeStruct((f, h), bf16))
+    r = share_rows(tokens * 8, held, e)
+    assert r == (128 if tokens == 33 else 5120)
+    # the gathered rows, their products and the float32 sum: under the
+    # 1.0 GB that tokens x 8 rows of 7,680 alone would take at 8,192 (0.82
+    # GB; 0.51 up to 4,096 rows a turn: past half the tokens the add-back
+    # keeps a second float32 sum.  The whole 8,192 prefill's temporaries
+    # are 2.55 GB either way: its peak is in the attention)
+    assert c.memory_analysis().temp_size_in_bytes < (
+        0.9e9 if tokens == 8192 else 0.05e9)
+
+
+@pytest.mark.parametrize("W", [1, 3])
+def test_latent_pool_write_compiles_in_place(v5e, compiled_paged, W):
+    """The decode step's write into the latent pool: in place (aliased),
+    no copy of the pool to another layout around it."""
+    B, L, D = 33, 16384, 576
+
+    def write(pool, new, ln):
+        return pa.latent_pool_write(pool, new, ln)
+
+    c = jax.jit(write, in_shardings=v5e, out_shardings=v5e,
+                donate_argnums=(0,)).trace(
+        jax.ShapeDtypeStruct((B, L, D), bf16),
+        jax.ShapeDtypeStruct((B, W, D), bf16),
+        jax.ShapeDtypeStruct((B,), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert _mosaic_calls(c) == 1
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
