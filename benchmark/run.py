@@ -8,8 +8,10 @@ cell's configuration, traffic mix and per-layer metric files by name, builds
 the system under test from the seed, warms up every shape the cell's traffic
 reaches (set-up), checks correctness outside the window, measures for
 `--seconds`, and prints the contract's JSON object as the last line of
-stdout.  Notes go to stderr.  With `--trace 0` the metrics are the cell's
-end-to-end metrics, with `--trace 1` its per-layer metrics.
+stdout.  Notes go to stderr; its last lines, and the result's last key
+`checks`, are each number `correct` compared beside its limit.  With
+`--trace 0` the metrics are the cell's end-to-end metrics, with `--trace 1`
+its per-layer metrics.
 
 A `workloads` cell needs a TPU with at least the cell's chips and fails
 without one; nothing here falls back to the CPU.  Only the rehearsal cells of
@@ -33,6 +35,8 @@ import sys  # noqa: E402
 _HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(_HERE)
 sys.path.insert(0, ROOT)
+
+from benchmark.checks import held  # noqa: E402
 
 
 def say(msg: str):
@@ -102,6 +106,7 @@ class Ctx:
         self.t_start = _T_START
         self.trace_dir = os.path.join(ROOT, ".bench_trace", cell["name"])
         self.say = say
+        self.host = None                  # a serve driver's `host.delta`
 
     def start_trace(self):
         import jax
@@ -169,7 +174,12 @@ def main(argv=None) -> int:
               "count": len(devices), "memory_peak_bytes": int(peak)}
     obs = dict(res["observations"], memory_peak_bytes=peak,
                device_kind=d0.device_kind, chips=cell["chips"], config=config)
-    out = {"correct": bool(res["correct"]), "attempted": res["attempted"],
+    # the driver's `correct` is `held` of its own `checks`; a closed loop's
+    # supply is the run's, judged here and only here
+    # (serve_driver.supply_check)
+    supply = res.get("supply", {})
+    out = {"correct": bool(res["correct"]) and held(supply),
+           "attempted": res["attempted"],
            "failed": res["failed"], "metrics": {}, "device": device}
     like = cell["metrics_as"]
     if not ctx.trace:
@@ -204,6 +214,15 @@ def main(argv=None) -> int:
     say(f"set-up {res['setup_s']:.1f}s (compiling or loading programs "
         f"{res['setup_compile_s']:.1f}s; cache hits {res['setup_hits']} of "
         f"{res['setup_requests']}); notes {json.dumps(res['notes'])}")
+    if ctx.host:
+        say(f"host over the window {json.dumps(ctx.host)}")
+    # each number `correct` compared, beside its limit: the last lines of
+    # stderr and the last key of the result
+    out["checks"] = dict(res["checks"], **supply)
+    for k, c in out["checks"].items():
+        say(f"check {k}: {c['value']} {c['holds']} {c['limit']}" +
+            (f" -- {c['why']}" if c.get("why") else ""))
+    say(f"correct {out['correct']}")
     print(json.dumps(out), flush=True)
     return 0
 

@@ -9,10 +9,18 @@ numbers.  CPU only, seconds, no model; not part of tier-1.
   * the FLOP and byte functions on hand-worked shapes;
   * the traffic generator: the same seed gives the same requests, another
     seed the same multiset of sizes in another order;
+  * the closed loop's supply: every shipped closed-loop mix builds a pool
+    at least twice what the newest measured rate consumes; a run whose pool
+    ran dry is refused, one with a block left passes, an open loop is never
+    judged; `order_seed` fixes the closed loop's order too; each serve
+    driver's notes carry `pool_left`;
   * the manifest check accepts the committed manifest.
 """
 from __future__ import annotations
 
+import inspect
+import json
+import math
 import os
 import sys
 import tempfile
@@ -211,6 +219,192 @@ def test_traffic():
         sorted(len(r["prompt"]) for r in second)
     assert traffic.prefill_buckets(a, 8, 2048) == [32, 64, 128, 256, 512,
                                                    1024, 2048]
+
+
+_LEAD_S = 2.0                      # serve_driver._LEAD_S
+_ROOT = os.path.dirname(_HERE)
+
+
+def _newest_levels() -> dict:
+    """traffic mix -> (the newest `serve_tokens_per_s` the driver's ledger
+    holds for a cell of that mix, the higher of its two sides; which line),
+    through the manifest's cells.  Nothing where the checkout has no ledger
+    or the ledger no line: such a mix is not judged by case (a)."""
+    path = os.path.join(_ROOT, "PERF_LEDGER.jsonl")
+    if not os.path.exists(path):
+        return {}
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        mix_of = {c["name"]: c["traffic"] for c in json.load(f)["workloads"]}
+    out = {}
+    with open(path) as f:
+        for line in f:                         # oldest first: the last wins
+            d = json.loads(line)
+            sides = (d.get("end_to_end") or {}).get("serve_tokens_per_s")
+            level = max((v for v in sides or [] if v), default=None)
+            if level and d.get("workload") in mix_of:
+                out[mix_of[d["workload"]]] = (
+                    level, f"ledger, PR {d['pr']}, {d['workload']}")
+    return out
+
+
+def _closed_mixes():
+    d = os.path.join(_HERE, "traffic")
+    for f in sorted(os.listdir(d)):
+        with open(os.path.join(d, f)) as fh:
+            mix = json.load(fh)
+        if mix.get("loop") == "closed":
+            yield f[:-len(".json")], mix
+
+
+def _report(requests, n_sent, seconds, done_until=None):
+    """A client's report in which the first `n_sent` requests of the plan
+    were sent and all but the last few answered in full."""
+    out = []
+    for i, r in enumerate(requests[:n_sent]):
+        t = seconds * i / max(n_sent, 1)
+        done = done_until is None or i < done_until
+        out.append({"id": r["id"], "due": None, "sent": t, "status": 200,
+                    "stamps": [t + 0.01] * r["max_tokens"] if done else [],
+                    "done": done, "error": None})
+    return out
+
+
+def pool_outlasts(mix: dict, seconds: float, tokens_per_s: float):
+    """Whether the pool `mix` builds holds at least twice the requests a
+    system completing `tokens_per_s` sends in lead + ramp + window, its
+    callers' requests in flight counted; with the two numbers."""
+    pool = traffic.make_requests(mix, 7, seconds, 50304)
+    mean_out = sum(r["max_tokens"] for r in pool) / len(pool)
+    used = mix["clients"] + (tokens_per_s / mean_out) * (
+        _LEAD_S + mix["ramp_s"] + seconds)
+    return len(pool) >= 2 * used, len(pool), used
+
+
+def test_closed_loop_pools():
+    """(a) each shipped closed-loop mix, at the manifest's `run_seconds`,
+    against the newest level the ledger holds for a cell of it (no table
+    here: a mix the ledger does not know yet is built and not judged)."""
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        seconds = json.load(f)["run_seconds"]
+    levels = _newest_levels()
+    for name, mix in _closed_mixes():
+        pool = traffic.make_requests(mix, 7, seconds, 50304)
+        assert len(pool) % mix["clients"] == 0 and len(pool) == mix[
+            "clients"] * (1 + math.ceil(mix["max_rps"] * (
+                mix["ramp_s"] + seconds) / mix["clients"])), name
+        if name in levels:
+            ok, size, used = pool_outlasts(mix, seconds, levels[name][0])
+            assert ok, (f"{name}: the pool of {size} is under twice the "
+                        f"{used:.0f} requests that {levels[name]} sends: "
+                        f"a benchmark PR has to raise its max_rps")
+    # the rule itself, on the GPT mix: the level of the ledger's PR 32 lines
+    # (a third over today's) passes, the old pool at today's level did not
+    sat = dict(_closed_mixes())["chat-saturated"]
+    assert pool_outlasts(sat, seconds, 2010.0)[0]
+    assert pool_outlasts(sat, seconds, 2600.0)[0]
+    assert not pool_outlasts(sat, seconds, 2700.0)[0]
+    assert not pool_outlasts(dict(sat, max_rps=12), seconds, 1400.0)[0]
+
+
+def test_closed_loop_supply():
+    """(b) the rule the three serve drivers share."""
+    mix = {"loop": "closed", "clients": 8, "max_rps": 2, "ramp_s": 1,
+           "prompt": {"median": 40, "sigma": 0.9, "min": 4, "max": 180},
+           "output": {"median": 12, "sigma": 0.6, "min": 2, "max": 40}}
+    pool = traffic.make_requests(mix, 3, 3.0, 1000)
+    assert len(pool) == 16                     # 1 + ceil(2 x 4 / 8) blocks
+    # dry: every request of the plan was sent
+    notes, why = traffic.closed_loop_supply(mix, pool, _report(pool, 16, 3.0),
+                                            3.0)
+    assert notes == {"pool_size": 16, "pool_left": 0, "in_flight_end": 0}
+    assert why and "max_rps" in why and "not correct" in why, why
+    # about to run dry: fewer left than callers who would each draw once more
+    notes, why = traffic.closed_loop_supply(mix, pool, _report(pool, 9, 3.0),
+                                            3.0)
+    assert notes["pool_left"] == 7 and why
+    # one block left: every caller could have drawn again
+    notes, why = traffic.closed_loop_supply(
+        mix, pool, _report(pool, 8, 3.0, done_until=3), 3.0)
+    assert why is None and notes["pool_left"] == 8
+    assert notes["in_flight_end"] == 5         # sent, not answered by the end
+    # an open loop sends its whole schedule and is never judged
+    opn = dict(mix, loop="open", rate_rps=4.0)
+    plan = traffic.make_requests(opn, 3, 3.0, 1000)
+    notes, why = traffic.closed_loop_supply(
+        opn, plan, _report(plan, len(plan), 3.0), 3.0)
+    assert why is None and notes["pool_left"] is None
+    assert notes["pool_size"] == len(plan) == 16
+    # a request the client never reached has no `sent`
+    rep = _report(pool, 8, 3.0) + [dict(_report(pool, 9, 3.0)[8], sent=None)]
+    assert traffic.closed_loop_supply(mix, pool, rep, 3.0)[0]["pool_left"] == 8
+
+
+def test_closed_loop_order():
+    """(c) `order_seed`: every seed draws one order of lengths from the
+    pool, with other token ids; without it the seed draws the order."""
+    _, sat = next(m for m in _closed_mixes() if m[0] == "chat-saturated")
+
+    def plan(rs):
+        return [(len(r["prompt"]), r["max_tokens"]) for r in rs]
+    a = traffic.make_requests(sat, 1, 50, 50304)
+    b = traffic.make_requests(sat, 2 ** 31 + 12345, 50, 50304)
+    assert plan(a) == plan(b) and [r["id"] for r in a] == [r["id"] for r in b]
+    assert a[0]["prompt"] != b[0]["prompt"]
+    assert plan(a[:48]) != plan(a[48:96])      # blocks differ in order ...
+    assert sorted(plan(a[:48])) != plan(a[:48])
+    assert sorted(p for p, _ in plan(a[:48])) == \
+        sorted(p for p, _ in plan(a[48:96]))   # ... never in their lengths
+    loose = {k: v for k, v in sat.items() if k != "order_seed"}
+    c = traffic.make_requests(loose, 1, 50, 50304)
+    d = traffic.make_requests(loose, 2, 50, 50304)
+    assert plan(c) != plan(d) != plan(a)
+    for i in (0, 1):                           # the same work in every order
+        assert sorted(x[i] for x in plan(c)) == \
+            sorted(x[i] for x in plan(d)) == sorted(x[i] for x in plan(a))
+
+
+def test_drivers_carry_the_supply():
+    """(d) the three serve `run`s call the one helper, put its notes
+    (`pool_left`, `pool_size`, `in_flight_end`) into theirs, make their
+    `correct` of `held(checks)` alone and return the pool's entry beside it
+    (`supply`), where `run.py` holds the result to it."""
+    from benchmark import run as bench_run
+    from benchmark import (serve_decoder_driver, serve_driver,
+                           serve_latent_driver)
+    from benchmark.checks import check, held
+    for mod in (serve_driver, serve_decoder_driver, serve_latent_driver):
+        src = inspect.getsource(mod.run)
+        assert "supply, dry = traffic.closed_loop_supply(" in src, mod
+        assert "**supply" in src, mod
+        assert "correct=held(checks), checks=checks," in src, mod
+        assert "supply=supply_check(mix, supply, dry)," in src, mod
+    src = inspect.getsource(traffic.closed_loop_supply)
+    assert all(k in src for k in ("pool_left", "pool_size", "in_flight_end"))
+    src = inspect.getsource(bench_run.main)
+    assert 'bool(res["correct"]) and held(supply)' in src
+    mix = {"loop": "closed", "clients": 8}
+    ok = serve_driver.supply_check(mix, {"pool_left": 8}, None)
+    assert ok == {"pool_left": {"value": 8, "limit": 8, "holds": ">="}}
+    assert held(ok)
+    for dry in ({"pool_left": 7}, {"pool_left": 0}):
+        bad = serve_driver.supply_check(mix, dry, "raise `max_rps`")
+        assert not held(bad) and "max_rps" in bad["pool_left"]["why"]
+    assert serve_driver.supply_check({"loop": "open"}, {"pool_left": None},
+                                     None) == {}
+    sound = serve_driver.serve_checks(
+        5, 40, 0, 0, {"logit_deficit_max": (0.04, 0.1)},
+        {"longest_context_checked": (70, 65)})
+    assert held(sound) and "pool_left" not in sound
+    assert sorted(sound) == ["compiles_in_window", "completed", "failed",
+                             "logit_deficit_max", "longest_context_checked",
+                             "tokens_checked"]
+    for k, v in (("failed", 1), ("compiles_in_window", 2), ("completed", 0),
+                 ("tokens_checked", 0), ("logit_deficit_max", 0.11),
+                 ("logit_deficit_max", None),
+                 ("logit_deficit_max", float("nan")),
+                 ("longest_context_checked", 64)):
+        assert not held({**sound, k: dict(sound[k], value=v)}), (k, v)
+    assert not held({"x": check(1.0, None)}) and held({})
 
 
 def test_manifest():

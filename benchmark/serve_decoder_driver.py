@@ -55,7 +55,9 @@ import numpy as np
 from benchmark import flops_smallthinker as fs
 from benchmark import reference_smallthinker as reference
 from benchmark import traffic
-from benchmark.serve_driver import _drive, _warm
+from benchmark.checks import held
+from benchmark.serve_driver import (_drive, _warm, serve_checks,
+                                    supply_check)
 
 _PAD = 2048            # reference sequences are padded to a multiple of this
 _ROWS = 256            # head rows computed at a time (two blocks are held)
@@ -171,6 +173,8 @@ def _check(model, cfg, sample) -> dict:
     engine, yardstick = of.pop("engine"), of[cfg["logit_tolerance_over"]]
     return dict(engine, argmax_matches=agree,
                 within_tolerance=within(engine, yardstick, cfg),
+                logprob_error_limit=(cfg["logit_tolerance"] *
+                                     yardstick["logprob_error_mean"]),
                 tokens_checked=sum(len(t) for _, t, _ in sample),
                 longest_context_checked=longest,
                 controls={k: dict(r, correct=within(r, yardstick, cfg))
@@ -237,6 +241,7 @@ def run(ctx) -> dict:
     ttft = [r["stamps"][0] - r[start] for r in good]
     gaps = [g for r in good for g in np.diff(r["stamps"])]
     in_window = sum(1 for r in results for s in r["stamps"] if 0.0 <= s < T)
+    supply, dry = traffic.closed_loop_supply(mix, requests, results, T)
     delta = {k: s1[k] - s0[k] for k in _COUNTERS}
     # model FLOPs of the window: the prompts whose prefill was dispatched
     # inside it (the engine's clock is the tap's), and the decoded tokens
@@ -252,15 +257,19 @@ def run(ctx) -> dict:
     def p95_ms(v):
         return float(np.percentile(v, 95)) * 1e3 if len(v) else None
 
-    # the last clause: a context past the window was among those checked,
+    # the last entry: a context past the window was among those checked,
     # where the traffic holds one at all
-    correct = (bool(good) and not failed and compiles == 0 and
-               check["tokens_checked"] > 0 and check["within_tolerance"] and
-               (check["longest_context_checked"] > window or
-                not any(len(r["prompt"]) + r["max_tokens"] > window
-                        for r in requests)))
+    past = any(len(r["prompt"]) + r["max_tokens"] > window for r in requests)
+    checks = serve_checks(
+        len(good), check["tokens_checked"], len(failed), compiles,
+        {"logprob_error_mean": (check.get("logprob_error_mean"),
+                                check.get("logprob_error_limit"))},
+        {"longest_context_checked": (check["longest_context_checked"],
+                                     window + 1)} if past else None)
     return dict(
-        setup, correct=correct, attempted=len(counted), failed=len(failed),
+        setup, correct=held(checks), checks=checks,
+        supply=supply_check(mix, supply, dry),
+        attempted=len(counted), failed=len(failed),
         end_to_end={"ttft_p95_ms": p95_ms(ttft), "itl_p95_ms": p95_ms(gaps),
                     "serve_tokens_per_s": in_window / T / ctx.cell["chips"]},
         observations=dict(
@@ -274,6 +283,7 @@ def run(ctx) -> dict:
         notes=dict(
             check, compiles_in_window=compiles, completed=len(good),
             completed_rps=len(good) / T, prefills_in_window=len(admitted),
+            **supply,
             ttft_p50_ms=float(np.median(ttft)) * 1e3 if ttft else None,
             ttft_p95_ms=p95_ms(ttft),
             itl_p50_ms=float(np.median(gaps)) * 1e3 if len(gaps) else None,

@@ -11,10 +11,15 @@ import subprocess
 import sys
 import time
 
-from benchmark import flops, reference, traffic
+from benchmark import flops, host, reference, traffic
+from benchmark.checks import check, held
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _LEAD_S = 2.0          # child start-up before its first request is due
+# the threads whose share of the host `host.delta` lists: the engine's
+# scheduler, the gateway's dispatcher and acceptor, and this one (asleep)
+_THREADS = ("paddle-tpu-serving", "paddle-tpu-gateway",
+            "paddle-tpu-gateway-http", "MainThread")
 
 
 def _build(ctx, handles: list):
@@ -89,7 +94,8 @@ def _check_logits(model, gcfg, sample) -> dict:
 def _drive(ctx, engine, stack, requests):
     """Start the client, wait out ramp and window, read the engine's
     counters at the window's edges; in a traced run profile a stretch of it.
-    Returns the client's report and what was read."""
+    Returns the client's report and what was read; what the host gave the
+    process over the window goes to `ctx.host` (`host.py`; notes only)."""
     import jax
     mix = ctx.mix
     ann = jax.profiler.TraceAnnotation
@@ -98,6 +104,7 @@ def _drive(ctx, engine, stack, requests):
         with ann("bench.client_wait"):
             time.sleep(max(0.0, t - time.monotonic()))
 
+    probe = host.probe_ms()            # the host's pace, the engine idle
     child = subprocess.Popen(
         [sys.executable, os.path.join(_HERE, "client.py")],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE)
@@ -113,7 +120,7 @@ def _drive(ctx, engine, stack, requests):
                  "setup_compile_s": ctx.log.compile_s,
                  "setup_hits": ctx.log.hits,
                  "setup_requests": ctx.log.requests}
-        stats0 = engine.stats()
+        stats0, host0 = engine.stats(), host.snapshot()
         if ctx.trace:
             sleep_until(t0 + mix["trace_at_frac"] * ctx.seconds)
             ctx.start_trace()
@@ -121,17 +128,50 @@ def _drive(ctx, engine, stack, requests):
                 sleep_until(time.monotonic() + mix["trace_s"])
             ctx.stop_trace()
         sleep_until(t0 + ctx.seconds)
-        stats1 = engine.stats()
+        stats1, host1 = engine.stats(), host.snapshot()
         compiles = ctx.log.requests - setup["setup_requests"]
         report = json.loads(child.stdout.read())
         child.wait(timeout=60)
+        client_cpu = (host.snapshot()["children_cpu_s"] -
+                      host0["children_cpu_s"])
     finally:
         if child.poll() is None:
             child.kill()
             child.wait()
     delta = {k: stats1[k] - stats0[k]
              for k in ("tokens", "decode_steps", "slot_allocs", "completed")}
+    # the client's CPU is its whole life's (ramp and the report's dump too)
+    ctx.host = dict(host.delta(host0, host1, _THREADS), probe_ms=probe,
+                    client_cpu_s=client_cpu)
     return report["results"], setup, delta, compiles
+
+
+def serve_checks(completed: int, tokens_checked: int, failed: int,
+                 compiles: int, at_most: dict, at_least=None) -> dict:
+    """Each number a serve driver's `correct` compares, beside its limit;
+    `correct` is `held` of them and nothing else.  `at_most` / `at_least`
+    are the driver's own readings against the reference, `name: (value,
+    limit)`.  The closed loop's pool is not among them (`supply_check`)."""
+    out = {k: check(v, lim) for k, (v, lim) in at_most.items()}
+    out.update((k, check(v, lim, ">="))
+               for k, (v, lim) in (at_least or {}).items())
+    out.update(completed=check(completed, 1, ">="),
+               tokens_checked=check(tokens_checked, 1, ">="),
+               failed=check(failed, 0), compiles_in_window=check(compiles, 0))
+    return out
+
+
+def supply_check(mix, supply, dry) -> dict:
+    """The closed loop's pool, held from below (`supply`, `dry`: what
+    `traffic.closed_loop_supply` returned); nothing for an open loop.  It
+    is the run's and not the system's, so a driver returns it beside its
+    `checks` (key `supply`) and `run.py` holds the result's `correct` to it:
+    a driver's own `correct` says what it compared of the system, and tests
+    that drive a `run` on a pool of their own keep reading that."""
+    if supply["pool_left"] is None:
+        return {}
+    return {"pool_left": check(supply["pool_left"], int(mix["clients"]),
+                               ">=", dry)}
 
 
 def run(ctx) -> dict:
@@ -172,7 +212,7 @@ def run(ctx) -> dict:
         pairs = [(r, hid[r["id"]]) for r in good if r["id"] in hid]
         rs = np.random.RandomState(ctx.seed % 2 ** 32)
         pick = rs.permutation(len(pairs))[:cfg["check_requests"]]
-        check = _check_logits(model, gcfg, [
+        logits = _check_logits(model, gcfg, [
             (by_id[pairs[i][0]["id"]]["prompt"], pairs[i][1].tokens)
             for i in pick])
     finally:
@@ -182,22 +222,24 @@ def run(ctx) -> dict:
     gaps = [g for r in good for g in np.diff(r["stamps"])]
     in_window = sum(1 for r in results for s in r["stamps"] if 0.0 <= s < T)
 
-    def in_flight(t):
-        return sum(1 for r in results if r["sent"] is not None and
-                   r["sent"] <= t and not (r["done"] and r["stamps"][-1] <= t))
+    supply, dry = traffic.closed_loop_supply(mix, requests, results, T)
 
     def p95_ms(v):
         return float(np.percentile(v, 95)) * 1e3 if len(v) else None
 
-    correct = (bool(good) and not failed and compiles == 0 and
-               check["tokens_checked"] > 0 and
-               check["logit_deficit_max"] <= cfg["logit_tolerance"])
+    checks = serve_checks(
+        len(good), logits["tokens_checked"], len(failed), compiles,
+        {"logit_deficit_max": (logits["logit_deficit_max"],
+                               cfg["logit_tolerance"])})
     late = [r["sent"] - r["due"] for r in counted
             if r["sent"] is not None and r["due"] is not None]
     return dict(
-        setup, correct=correct, attempted=len(counted), failed=len(failed),
+        setup, correct=held(checks), checks=checks,
+        supply=supply_check(mix, supply, dry),
+        attempted=len(counted), failed=len(failed),
         # every serve cell computes all three; the manifest says which a
-        # cell is judged by (none by ttft_p95_ms today: PERF.md section 6)
+        # cell is judged by (none by ttft_p95_ms or itl_p95_ms today: PERF.md
+        # section 2; the steady cell's stutter is per layer, `client_gap_s`)
         end_to_end={"ttft_p95_ms": p95_ms(ttft), "itl_p95_ms": p95_ms(gaps),
                     "serve_tokens_per_s": in_window / T / ctx.cell["chips"]},
         observations={
@@ -207,19 +249,23 @@ def run(ctx) -> dict:
                                if h.t_admit is not None],
             "engine_token_latency_s": [g for _, h in pairs
                                        for g in h.token_latencies_s],
+            # every gap between two streamed tokens, clocked at the client
+            "client_gap_s": [float(g) for g in gaps],
             # the first token of each admission comes from its prefill
             "decode_tokens": d["tokens"] - d["slot_allocs"],
             "decode_capacity": d["decode_steps"] * engine.max_slots,
         },
         notes=dict(
-            check, compiles_in_window=compiles, completed=len(good),
+            logits, compiles_in_window=compiles, completed=len(good),
             completed_rps=len(good) / T,
             ttft_p50_ms=float(np.median(ttft)) * 1e3 if ttft else None,
             ttft_p95_ms=p95_ms(ttft),
             itl_p50_ms=float(np.median(gaps)) * 1e3 if len(gaps) else None,
+            itl_p95_ms=p95_ms(gaps),
+            itl_mean_ms=float(np.mean(gaps)) * 1e3 if len(gaps) else None,
             itl_p99_ms=(float(np.percentile(gaps, 99)) * 1e3 if len(gaps)
                         else None),
-            in_flight_mid=in_flight(T / 2), in_flight_end=in_flight(T),
+            in_flight_mid=traffic.in_flight(results, T / 2), **supply,
             late_p95_ms=p95_ms(late), engine=d,
             fail_sample=[(r["id"], r["status"], r["error"])
                          for r in failed[:3]]))

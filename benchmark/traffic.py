@@ -16,7 +16,15 @@ Mix parameters (all optional but `loop`):
   order_seed      draws the order of lengths and gaps in place of `--seed`
   clients         closed loop: callers
   max_rps         closed loop: a completion rate the system cannot reach;
-                  sizes the pool of requests the callers draw from
+                  sizes the pool of requests the callers draw from:
+                  1 + ceil(max_rps x (ramp_s + seconds) / clients) blocks of
+                  `clients` requests.  A system that does reach it finds the
+                  list empty, its callers stop one by one, and the run reads
+                  the pool's size over the window and not what the system
+                  can do (a faster system then reads LOWER: more of the pool
+                  is gone before the window opens).  `closed_loop_supply`
+                  says so and the run is reported as not correct: raise
+                  `max_rps` in the mix file
   ramp_s          seconds of the same traffic before the window opens
                   (counted as set-up), so the window opens on a steady state
   prompt, output  {"median", "sigma", "min", "max"}: clipped lognormal lengths
@@ -96,6 +104,39 @@ def make_requests(mix: dict, seed: int, seconds: float, vocab: int) -> list:
         return out
     raise ValueError(f"traffic loop {mix['loop']!r} is not served by "
                      f"make_requests")
+
+
+def in_flight(results: list, t: float) -> int:
+    """Requests of a client's report sent by `t` (seconds from the window's
+    opening) and not answered in full by then."""
+    return sum(1 for r in results
+               if r["sent"] is not None and r["sent"] <= t and
+               not (r["done"] and r["stamps"] and r["stamps"][-1] <= t))
+
+
+def closed_loop_supply(mix: dict, requests: list, results: list,
+                       seconds: float) -> tuple[dict, str | None]:
+    """What a run left of its requests, from the client's report: notes for
+    every serve driver (`pool_size`, `pool_left` = requests of the plan that
+    no caller had sent when the window closed, `in_flight_end`) and, for a
+    closed loop whose callers could not all have drawn once more
+    (`pool_left < clients`: the pool ran dry, or was about to), the reason
+    why the run is not correct.  An open loop sends its whole schedule and
+    is never judged (`pool_left` None)."""
+    notes = {"pool_size": len(requests), "pool_left": None,
+             "in_flight_end": in_flight(results, seconds)}
+    if mix["loop"] != "closed":
+        return notes, None
+    notes["pool_left"] = len(requests) - sum(
+        1 for r in results if r["sent"] is not None)
+    if notes["pool_left"] >= int(mix["clients"]):
+        return notes, None
+    return notes, (
+        f"the closed loop's pool ran dry: pool_left {notes['pool_left']} of "
+        f"pool_size {len(requests)} is under clients {mix['clients']} "
+        f"(in_flight_end {notes['in_flight_end']}), so the window measured "
+        f"the pool and not the system: raise `max_rps` (now "
+        f"{mix['max_rps']}) in the cell's traffic mix file; not correct")
 
 
 def prefill_buckets(requests, lo: int, hi: int) -> list:

@@ -13,6 +13,7 @@ import math
 import time
 
 from benchmark import flops, reference
+from benchmark.checks import check, held
 
 
 def _build(ctx):
@@ -138,12 +139,15 @@ def run(ctx) -> dict:
         ctx.stop_trace()
         losses += tl
 
-    finite = all(math.isfinite(v) for v in losses)
-    correct = (finite and rel <= cfg["loss_rel_tolerance"] and compiles == 0)
+    checks = {
+        "loss_rel_err": check(rel, cfg["loss_rel_tolerance"]),
+        "nonfinite_losses": check(
+            sum(not math.isfinite(v) for v in losses), 0),
+        "compiles_in_window": check(compiles, 0)}
     rate = steps * tokens_per_step / window_s / ctx.cell["chips"]
     gaps = np.diff(stamps[1:]) if steps > 2 else np.array([window_s / steps])
     return {
-        "correct": correct, "attempted": steps,
+        "correct": held(checks), "checks": checks, "attempted": steps,
         "failed": sum(not math.isfinite(v) for v in win_losses),
         "setup_s": setup_s, "setup_compile_s": setup_cs,
         "setup_hits": setup_hits, "setup_requests": setup_req,
