@@ -17,7 +17,10 @@ production LLM servers (vLLM/Orca-style continuous batching) converged on:
   logarithmic), then runs ONE batched decode step for ALL
   active slots — fixed shapes, so after the first iteration the decode is
   a single compiled program forever, regardless of request churn
-  (asserted via the retrace sentinel's signature count).
+  (asserted via the retrace sentinel's signature count).  With the device
+  sampler and one token a step it keeps ONE decode step queued behind the
+  running one: step n+1 is dispatched from step n's tokens on the device,
+  and step n is fetched and emitted under it (docs/serving.md).
 * a **request/response API**: ``submit() -> RequestHandle`` (Future-style:
   ``result`` / ``done`` / ``cancel`` / ``exception``), per-token streaming
   callbacks, a bounded admission queue that rejects with
@@ -81,7 +84,7 @@ import time
 import weakref
 from collections import deque
 from concurrent.futures import CancelledError
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
@@ -144,6 +147,10 @@ SERVING_MOE_ROUTED = "paddle_tpu_serving_moe_routed_total"
 SERVING_DECODE_SAMPLED_STEPS = "paddle_tpu_serving_decode_sampled_steps_total"
 SERVING_DECODE_TOPK_STEPS = "paddle_tpu_serving_decode_topk_steps_total"
 SERVING_PREFILL_WAVES = "paddle_tpu_serving_prefill_waves_total"
+SERVING_DECODE_LOOKAHEAD_STEPS = \
+    "paddle_tpu_serving_decode_lookahead_steps_total"
+SERVING_DECODE_OVERSHOOT_ROWS = \
+    "paddle_tpu_serving_decode_overshoot_rows_total"
 
 
 class QueueFullError(RuntimeError):
@@ -427,6 +434,16 @@ def _bucket(n: int, lo: int, hi: int) -> int:
     while b < n:
         b *= 2
     return min(b, hi)
+
+
+class _DecodeStep(NamedTuple):
+    """One dispatched decode step until its tokens are fetched and emitted."""
+    ordinal: int        # the `step` stat of its dispatch and emit spans
+    live: dict          # {slot: request} of the rows it computes
+    drafts: dict        # speculative drafts by slot (depth 0 only)
+    lengths: np.ndarray  # every row's position as dispatched (parked: idle)
+    out: object         # its packed output, on the device
+    t0: float           # when it was dispatched
 
 
 class Engine:
@@ -768,6 +785,21 @@ class Engine:
         # not while the scheduler thread prepares the dispatch beside them
         self._held_streams: deque = deque()
         self._stream_lock = threading.RLock()
+        # the look-ahead: how many decode steps the scheduler keeps queued
+        # behind the running one.  1 where step n+1 can be dispatched from
+        # what the host knows before it has step n's tokens: the device
+        # sampler's packed output holds every row's next input token
+        # (merged inside the decode program) and a step emits exactly one
+        # token a live row, so lengths, keys and pages are known a step
+        # ahead.  0 where the next token exists only on the host (the host
+        # sampler) or the next lengths hang on the acceptance computed at
+        # emit (speculative decoding): the same loop, nothing queued
+        self._lookahead = int(self.sample_on_device and
+                              self._spec_width == 1)
+        self._flying: Optional[_DecodeStep] = None  # dispatched, unfetched
+        self._last_out = None       # device: the newest step's packed output
+        self._step_ordinal = 0      # decode steps dispatched
+        self._t_landed = 0.0        # when the newest fetch returned
         self._moe_load = False      # the model's expert layers count load
         self._load_counters: list = []   # (stats key, registry counter)
         self._kv_windows: list = []  # per layer: sliding window or None
@@ -805,6 +837,8 @@ class Engine:
                         "decode_kv_live_positions": 0,
                         "decode_kv_read_positions": 0,
                         "decode_sampled_steps": 0, "decode_topk_steps": 0,
+                        "decode_lookahead_steps": 0,
+                        "decode_overshoot_rows": 0,
                         "moe_assignments": 0, "moe_experts_touched": 0,
                         "moe_load_max": 0, "moe_routed": 0,
                         "tokens": 0, "resubmitted": 0, "redispatched": 0,
@@ -1540,7 +1574,7 @@ class Engine:
             return _pack(logits, load), pool
 
         def decode(values, ids, pool, lengths, tables, temps, topks, keys,
-                   adp=None):
+                   carry=None, adp=None):
             # ONE batched step over every slot row (+ scratch): idle rows
             # are parked at the addressable end so their writes DROP (a
             # prefix-cached row is never clobbered) and their logits are
@@ -1548,7 +1582,15 @@ class Engine:
             # plain decode, W=k the speculative verify — same program
             # shape either way.  `tables` (None on the dense pool) rides
             # along as one more int32 operand: ONE signature per engine
-            # config.
+            # config.  `carry` (the look-ahead; None at depth 0) is the
+            # packed output of the step before, still on the device, and
+            # the rows whose input token the host supplies all the same
+            # (admitted since, or every row after a fetched step): the
+            # others take the token that step chose for them.
+            if carry is not None:
+                last, host_rows = carry
+                ids = jnp.where(host_rows[:, None], ids,
+                                last[:n_rows, None].astype(ids.dtype))
             with _mstate(_dq(values), adp, valid=lengths < park) as load:
                 logits, new_caches = _fwd_all(
                     Tensor(ids, _internal=True),
@@ -1598,6 +1640,14 @@ class Engine:
         self._copy_fn = instrument_jit(
             jax.jit(prefix_copy, donate_argnums=() if on_cpu else (0,)),
             "serving.prefix_copy")
+        if self._lookahead:
+            # what the first step is handed as the output of the step before
+            # (every row's token comes from the host then): the packed
+            # layout of `_pack`, so that the program keeps ONE signature
+            tok = jax.eval_shape(
+                lambda: jnp.argmax(jnp.zeros((1, 1)), -1)).dtype
+            n_load = load_struct.shape[0] if self._moe_load else 0
+            self._last_out = jnp.zeros((2 * n_rows + n_load,), tok)
         with self._lock:
             self._built = True
         # the build just placed the big long-lived allocations: refresh
@@ -1624,6 +1674,9 @@ class Engine:
                     if not self._wake.wait(0.02):
                         idle.drop()         # nobody called: no record
                 self._wake.clear()
+        # a step still in flight is dropped unfetched: its requests are
+        # failed by whoever stopped the loop, never emitted to
+        self._flying = None
 
     def _fail_as_dead(self, cause: BaseException):
         """Death path, from the dying scheduler thread (crash) or a
@@ -1700,10 +1753,11 @@ class Engine:
                               if id(r) in taken_ids))
 
     def _step_once(self) -> bool:
-        """One scheduler iteration: sweep, admit (batched prefill), one
-        batched decode step.  Returns whether any work happened.  Every
-        instant of it lies in one ``serving.*`` leaf phase (the table in
-        docs/observability.md)."""
+        """One scheduler iteration: sweep, admit (one-row prefills), the
+        dispatch of one batched decode step and the fetch and emit of the
+        one before it (`_decode_step`).  Returns whether any work happened.
+        Every instant of it lies in one ``serving.*`` leaf phase (the table
+        in docs/observability.md)."""
         faults.fault_point("serving.scheduler")
         # unlocked reads: the counts only label the iteration's record
         with phase("serving.iteration", active=self._pool.n_active,
@@ -2613,50 +2667,105 @@ class Engine:
 
     # -- decode --------------------------------------------------------------
     def _decode_step(self) -> bool:
+        """Dispatch the next decode step, then fetch and emit the one still
+        unfetched (`_flying`).  At depth 1 (`_lookahead`) the new step stays
+        in flight, so that everything the host does until its fetch runs
+        under a program; at depth 0 it is fetched and emitted at once."""
         with self._lock:
             active = self._pool.active()
-            if not active:
-                return False
+        flying = self._flying
+        if not active and flying is None:
+            return False
+        behind = None       # the step this turn leaves in flight
         with span("serving.decode", active=len(active)):
-            with phase("serving.decode.build"):
-                (drafts, ids, lengths, temps, topks, keys, aids,
-                 tables) = self._decode_inputs(active)
-                kv_live, kv_read = self._decode_kv_positions(active, lengths)
-                sampled, topk = self._decode_sampling_rows(lengths, temps,
-                                                           topks)
             try:
-                with phase("serving.decode.dispatch", active=len(active),
-                           kv_live=kv_live, kv_read=kv_read,
-                           sampled=sampled, topk=topk):
-                    t0 = time.perf_counter()
-                    faults.fault_point("serving.decode", active=len(active))
-                    if self._decode_timeout_s is not None:
-                        _watchdog.arm("serving.decode",
-                                      self._decode_timeout_s)
-                    extra = ((self._adp_args(aids),)
-                             if self._adapters is not None else ())
-                    # the slot-state snapshots go in as numpy: the jit call
-                    # transfers them itself, without a Python-level
-                    # `device_put` apiece
-                    out, self._kv_pool = self._decode_fn(
-                        self._values, ids, self._kv_pool, lengths, tables,
-                        temps, topks, keys, *extra)
-                    self._dispatched(out)
-                with phase("serving.decode.fetch"):
-                    out, lps, load = self._fetch(out, ids.shape)
+                step = (self._decode_dispatch(active, flying) if active
+                        else None)
+                behind = step if self._lookahead else None
+                if flying is not None:
+                    # an engine that runs empty fetches its last step here,
+                    # before `serving.wait`
+                    self._decode_land(flying)
+                self._flying = behind
+                if step is not None and behind is None:
+                    self._decode_land(step)
+                if step is None:
+                    # no dispatch whose tail would let the held tokens go
+                    self._flush_streams()
             finally:
-                if self._decode_timeout_s is not None:
+                # armed anew by every dispatch: it times the step in flight
+                if self._decode_timeout_s is not None and behind is None:
                     _watchdog.disarm()
-            with phase("serving.decode.emit", **self._load_stats(load)):
-                self._count_load(load)
-                self._decode_emit(active, drafts, lengths, out, lps, t0)
-        return True
+        return step is not None or flying is not None
+
+    def _decode_dispatch(self, active: dict,
+                         flying: Optional[_DecodeStep]):
+        """Build and dispatch one decode step over `active`, seen one token
+        ahead of the host's arrays where `flying` is still unfetched.
+        Returns None, with nothing dispatched, when no row would compute
+        (every one of them ends with the unfetched step's token)."""
+        with phase("serving.decode.build"):
+            (drafts, ids, lengths, temps, topks, keys, aids, tables,
+             host_rows) = self._decode_inputs(active, flying)
+            live = {slot: req for slot, req in active.items()
+                    if lengths[slot] < self._park}
+            if not live:
+                return None
+            kv_live, kv_read = self._decode_kv_positions(live, lengths)
+            sampled, topk = self._decode_sampling_rows(lengths, temps, topks)
+            self._step_ordinal += 1
+        with phase("serving.decode.dispatch", active=len(live),
+                   kv_live=kv_live, kv_read=kv_read, sampled=sampled,
+                   topk=topk, step=self._step_ordinal):
+            t0 = time.perf_counter()
+            faults.fault_point("serving.decode", active=len(live))
+            if self._decode_timeout_s is not None:
+                _watchdog.arm("serving.decode", self._decode_timeout_s)
+            carry = (self._last_out, host_rows) if self._lookahead else None
+            extra = ((self._adp_args(aids),)
+                     if self._adapters is not None else ())
+            # the slot-state snapshots go in as numpy: the jit call
+            # transfers them itself, without a Python-level `device_put`
+            # apiece
+            out, self._kv_pool = self._decode_fn(
+                self._values, ids, self._kv_pool, lengths, tables, temps,
+                topks, keys, carry, *extra)
+            if self._lookahead:
+                self._last_out = out
+            if flying is not None:
+                with self._lock:
+                    self._counts["decode_lookahead_steps"] += 1
+                registry().counter(
+                    SERVING_DECODE_LOOKAHEAD_STEPS,
+                    "decode steps dispatched while the step before was "
+                    "still unfetched").inc(1.0)
+            self._dispatched(out)
+        return _DecodeStep(self._step_ordinal, live, drafts, lengths, out,
+                           t0)
+
+    def _decode_land(self, step: _DecodeStep):
+        """Fetch one dispatched decode step and emit its tokens.  Their
+        stream callbacks stay held until the tail of the next dispatch, also
+        where a step is queued behind this one already: the consumers they
+        wake (one thread a stream) then run while the scheduler waits in the
+        next fetch, not beside its sweep, build and dispatch."""
+        with phase("serving.decode.fetch"):
+            out, lps, load = self._fetch(step.out, self._ids.shape)
+            # the step behind it had the device from here on at the latest
+            t0, self._t_landed = (max(step.t0, self._t_landed),
+                                  time.perf_counter())
+        with phase("serving.decode.emit", step=step.ordinal,
+                   **self._load_stats(load)):
+            self._count_load(load)
+            self._decode_emit(step, out, lps, t0)
 
     def _flush_streams(self):
         """Hand every held-back token to its stream callback, in the order
-        emitted.  Called once a program is on the device, before any request
-        finishes (`RequestHandle._finish`) and when the scheduler goes idle;
-        the lock keeps two flushing threads from reordering a stream."""
+        emitted.  Called once a program is on the device (the tail of a
+        dispatch), before any request finishes (`RequestHandle._finish`),
+        at the end of a decode turn that dispatched nothing and when the
+        scheduler goes idle; the lock keeps two flushing threads from
+        reordering a stream."""
         with self._stream_lock:
             while self._held_streams:
                 stream, token = self._held_streams.popleft()
@@ -2715,9 +2824,9 @@ class Engine:
         for k, counter in self._load_counters:
             counter.inc(float(stats[k]))
 
-    def _decode_kv_positions(self, active: dict, lengths):
+    def _decode_kv_positions(self, live: dict, lengths):
         """Summed over the layers, the KV positions one decode dispatch
-        needs (`kv_live`: each active slot's context and its new span; on a
+        needs (`kv_live`: each live slot's context and its new span; on a
         sliding-window layer only what the window admits) and the positions
         its attention read streams (`kv_read`): every row whole on an XLA
         read, each row's live blocks or pages on a kernel (on a window
@@ -2727,7 +2836,7 @@ class Engine:
         span = (self._max_pages_per_slot * self._page_alloc.page_size
                 if self.paged_kv else self.max_len)
         P = self._decode_read_block
-        ctx = np.asarray([int(lengths[s]) + W for s in active], np.int64)
+        ctx = np.asarray([int(lengths[s]) + W for s in live], np.int64)
         kv_live = kv_read = 0
         for window in set(self._kv_windows):
             n_layers = self._kv_windows.count(window)
@@ -2774,9 +2883,16 @@ class Engine:
                         "row with top_k > 0)").inc(1.0)
         return sampled, topk
 
-    def _decode_inputs(self, active: dict):
+    def _decode_inputs(self, active: dict, flying: Optional[_DecodeStep]):
         """`serving.decode.build`: the drafts and the locked snapshot of
-        the slot-state arrays one decode dispatch carries."""
+        the slot-state arrays one decode dispatch carries.  With `flying`
+        unfetched the snapshot is moved one token on for the rows live in
+        it: each emits exactly one token there, so its next position is
+        known, and so is a budget that ends with that token (the row is
+        parked, not computed).  An EOS is not: that row computes once more
+        and `_decode_emit` discards it.  `host_rows` (None at depth 0): the
+        rows whose input token `ids` holds; the others take it from
+        `flying`'s output on the device."""
         W = self._spec_width
         drafts: dict = {}
         if W > 1:
@@ -2806,13 +2922,29 @@ class Engine:
             aids = np.array(self._aids)
             tables = (np.array(self._page_tables) if self.paged_kv
                       else None)
-        return drafts, ids, lengths, temps, topks, keys, aids, tables
+        host_rows = None
+        if self._lookahead:
+            host_rows = np.ones(len(lengths), bool)
+            for slot, req in (flying.live.items() if flying is not None
+                              else ()):
+                if active.get(slot) is not req:
+                    continue        # evicted since: the slot is another's
+                host_rows[slot] = False
+                if len(req._tokens) + 1 >= req.max_new_tokens:
+                    lengths[slot] = self._park
+                else:
+                    lengths[slot] = flying.lengths[slot] + 1
+        return (drafts, ids, lengths, temps, topks, keys, aids, tables,
+                host_rows)
 
-    def _decode_emit(self, active: dict, drafts: dict, lengths, out, lps,
-                     t0):
+    def _decode_emit(self, step: _DecodeStep, out, lps, t0):
         """`serving.decode.emit`: accept, stream and account the tokens
-        of one fetched decode batch; retire what finished."""
+        of one fetched decode batch; retire what finished.  A row whose
+        request ended while the step was in flight (an EOS, a cancel or a
+        deadline the look-ahead could not know of) is discarded: nothing
+        streamed, nothing counted but `decode_overshoot_rows`."""
         W = self._spec_width
+        drafts, lengths = step.drafts, step.lengths
         dt = time.perf_counter() - t0
         with self._lock:
             self._counts["decode_steps"] += 1
@@ -2822,13 +2954,14 @@ class Engine:
         now = time.perf_counter()
         tok_hist = registry().histogram(SERVING_TOKEN_LATENCY,
                                         "per-token decode latency")
-        drafted_total = accepted_total = 0
+        drafted_total = accepted_total = overshoot = 0
         finishers = []
-        for slot, req in active.items():
+        for slot, req in step.live.items():
             if req.done() or req._torn or req._engine is not self:
-                # torn away by a supervisor abandon while this batch ran
-                # (or already re-dispatched into a REBUILT engine): its
-                # outcome is settled elsewhere
+                # ended while this batch ran, or torn away by a supervisor
+                # abandon (or already re-dispatched into a REBUILT engine):
+                # its outcome is settled elsewhere
+                overshoot += 1
                 continue
             if self.sample_on_device:
                 toks_row = out[slot]                      # [W] token ids
@@ -2877,7 +3010,7 @@ class Engine:
             if req.journey is not None:
                 # one phase per batched DISPATCH the request rode (the
                 # existing per-token boundary), never per token
-                attrs = {"emitted": emitted, "active": len(active)}
+                attrs = {"emitted": emitted, "active": len(step.live)}
                 if d is not None:
                     attrs["drafted"] = W - 1
                     attrs["accepted"] = len(run) - 1
@@ -2906,6 +3039,13 @@ class Engine:
             flight.record("serving", "spec_verify", drafted=drafted_total,
                           accepted=accepted_total,
                           rejected=drafted_total - accepted_total)
+        if overshoot:
+            with self._lock:
+                self._counts["decode_overshoot_rows"] += overshoot
+            registry().counter(
+                SERVING_DECODE_OVERSHOOT_ROWS,
+                "rows a decode step computed for a request that had "
+                "already ended").inc(float(overshoot))
         for req in finishers:
             req._finish(None)
         with self._lock:

@@ -454,12 +454,18 @@ def test_scale_up_then_drain_down_end_to_end(tiny_gpt):
         one(0)                                   # warm the first replica
         threads = [threading.Thread(target=one, args=(i,))
                    for i in range(16)]
-        for th in threads:
-            th.start()
-        assert _wait(lambda: len(gw.router.names) == 2, timeout=120), \
-            "scale-up never fired"
-        for th in threads:
-            th.join(timeout=300)
+        # gpt-tiny decodes in a few ms on a calm CPU, and eight waves of it
+        # are over before the last request has queued for 50 ms: stretch a
+        # step to the time it has on a chip, so that the flood breaches the
+        # queue-wait whatever the host's pace
+        with faults.inject("serving.decode", mode="delay", seconds=0.02,
+                           times=None):
+            for th in threads:
+                th.start()
+            assert _wait(lambda: len(gw.router.names) == 2, timeout=120), \
+                "scale-up never fired"
+            for th in threads:
+                th.join(timeout=300)
         assert len(results) == 17
         assert all(s == 200 and n == 4 for s, n in results), \
             results                               # zero lost requests
@@ -480,7 +486,9 @@ def test_scale_up_then_drain_down_end_to_end(tiny_gpt):
         up = sum(counter.value({"direction": "up", "reason": r})
                  for r in ("queue_wait_p99", "ttft_headroom", "shed"))
         assert up == 1.0
-        assert counter.value({"direction": "down", "reason": "idle"}) == 1.0
+        # the router shrinks a moment before the autoscaler's thread counts
+        assert _wait(lambda: counter.value(
+            {"direction": "down", "reason": "idle"}) == 1.0, timeout=30)
         # the router shrinks when the drain completes; the desired
         # gauge flushes on the autoscaler's next tick — wait for it
         assert _wait(lambda: registry().get(FLEET_DESIRED).value() == 1.0,

@@ -412,7 +412,8 @@ def _decode_calls(eng, submit):
     """The argument tuples of every decode dispatch `submit()` causes, and
     the decode program (the engine must have built it already).  Whatever
     the pool, the one signature: `(values, ids, pool, lengths, tables,
-    temps, topks, keys)`, `tables` None on the dense layout."""
+    temps, topks, keys, carry)`, `tables` None on the dense layout and
+    `carry` None where the engine keeps no step queued (depth 0)."""
     fn = eng._decode_fn
     program, calls = fn._fn, []
     fn._fn = lambda *a: (calls.append(a), program(*a))[1]
@@ -421,7 +422,8 @@ def _decode_calls(eng, submit):
     finally:
         fn._fn = program
     for a in calls:
-        assert len(a) == 8 and a[2] is not eng._kv_pool
+        assert len(a) == 9 and a[2] is not eng._kv_pool
+        assert (a[8] is None) == (eng._lookahead == 0)
         assert a[2].layout == ("paged" if eng.paged_kv else "dense")
         assert (a[4] is None) == (a[2].layout == "dense")
         assert a[2].quantized == (eng.kv_dtype == "int8")

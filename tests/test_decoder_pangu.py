@@ -31,6 +31,7 @@ from paddle_tpu.models.kv_cache import (KernelRead, KVPool, SlotCache,
 from paddle_tpu.nn.functional.attention import _sdpa_ref
 from paddle_tpu.observability import trace
 from paddle_tpu.serving import Engine
+from paddle_tpu.testing import faults
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -602,7 +603,12 @@ def test_serve_latent_driver_rehearsal():
     args = argparse.Namespace(seed=2 ** 31 + 7, seconds=3.0, trace=0)
     ctx = bench_run.Ctx(cell, config, mix, args, jax.devices()[:1],
                         bench_run.CompileLog())
-    res = driver.run(ctx)
+    # the tiny model decodes in ~2 ms on a calm CPU and would drain the
+    # mix's pool (408 requests: `max_rps` 100 over 4 s) inside the window:
+    # stretch a step, so that the loop stays closed whatever the host's pace
+    with faults.inject("serving.decode", mode="delay", seconds=0.01,
+                       times=None):
+        res = driver.run(ctx)
     assert res["correct"], res["notes"]
     for name, control in res["notes"]["controls"].items():
         assert not control["correct"], (name, control)

@@ -224,13 +224,16 @@ def test_supervisor_never_replays_streamed_requests(tiny_gpt):
         lambda: Engine(model, max_slots=2, max_len=64),
         name="sup1", poll_interval_s=0.02)
     try:
-        # let prefill + 3 decode crossings through, then kill: the
-        # request dies with exactly 4 tokens streamed — deterministic
+        # let prefill + 3 decode crossings through, then kill.  The engine
+        # keeps one decode step queued behind the running one, so the 4th
+        # dispatch dies with the 3rd step still unfetched: that step is
+        # dropped, never emitted, and the request dies with exactly 3
+        # tokens streamed (the prefill's and two steps') — deterministic
         faults.arm("serving.decode", times=1, after=3)
         h = sup.submit([2, 7, 1], max_new_tokens=12, stream=seen.append)
         err = h.exception(timeout=120)
         assert isinstance(err, RequestInterruptedError)
-        assert err.tokens_streamed == len(seen) == len(h.tokens) == 4
+        assert err.tokens_streamed == len(seen) == len(h.tokens) == 3
         assert h.redispatches == 0
         # the supervisor still heals the engine for the next request
         faults.reset()
