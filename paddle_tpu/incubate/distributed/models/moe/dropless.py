@@ -21,7 +21,10 @@ absent shares.
 
 Routing: softmax over the top-k logits (`scoring="softmax"`), or sigmoid
 scores, top-k over the scores, renormalised over the chosen and scaled
-(`scoring="sigmoid"`, `routed_scale`).  The gate's activation is ReLU or
+(`scoring="sigmoid"`, `routed_scale`); with `expert_bias` the sigmoid router
+CHOOSES by score + a per-expert selection bias (a buffer, not trained; the
+load balancer of the afmoe family moves it between steps of training) and
+WEIGHS by the score alone.  The gate's activation is ReLU or
 SiLU.  `shared_width` adds one gated expert that every token meets.
 
 Load counters.  Inside `collect_load()` every layer call appends
@@ -40,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .....core.op import defop
+from .....core.tensor import Tensor
 from .....nn import initializer as I
 from .....nn.layer_base import Layer
 
@@ -83,17 +87,27 @@ def collect_load(valid=None):
 
 
 def route_top_k(logits, top_k: int, norm_topk_prob: bool = True,
-                scoring: str = "softmax", routed_scale: float = 1.0):
+                scoring: str = "softmax", routed_scale: float = 1.0,
+                bias=None):
     """float32 routing: (weights [T, k], experts [T, k]).  `softmax`: with
     `norm_topk_prob` the weights are the softmax over the k chosen logits
     (= softmax over all experts, top k, renormalised); without it the
     softmax over all experts at the chosen ones.  `sigmoid`: the scores are
     sigmoids of the logits, the k largest are chosen, and with
     `norm_topk_prob` each weight is its score over the chosen scores' sum
-    (+ 1e-20).  Either way times `routed_scale`."""
+    (+ 1e-20); with `bias` [experts] the k largest of score + bias are
+    chosen and the weights are still the scores' own.  Either way times
+    `routed_scale`."""
     logits = logits.astype(jnp.float32)
+    if bias is not None and scoring != "sigmoid":
+        raise ValueError("a selection bias goes with sigmoid scoring")
     if scoring == "sigmoid":
-        w, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+        score = jax.nn.sigmoid(logits)
+        if bias is None:
+            w, idx = jax.lax.top_k(score, top_k)
+        else:
+            _, idx = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
+            w = jnp.take_along_axis(score, idx, -1)
         if norm_topk_prob:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     elif scoring == "softmax":
@@ -118,23 +132,26 @@ def share_rows(assignments: int, held: int, n_all: int) -> int:
 @defop
 def dropless_moe(x, router_input, w_router, w_gate, w_up, w_down, top_k,
                  first=0, norm_topk_prob=True, scoring="softmax",
-                 routed_scale=1.0, activation="relu", shared=None, name=None):
+                 routed_scale=1.0, activation="relu", shared=None, bias=None,
+                 name=None):
     """y = sum over the top-k experts e of p_e * (act(x W_gate^e) *
     (x W_up^e)) W_down^e for every token of x [..., hidden]; the router
     reads `router_input` [..., hidden].  `w_gate`/`w_up` [E_held, hidden,
     width] and `w_down` [E_held, width, hidden] are the experts `first ..
     first + E_held - 1` of the `w_router.shape[1]` routed over.  `shared`
     (gate, up, down), each one matrix: an expert every token meets with
-    weight 1, added under the scope `moe.shared`."""
+    weight 1, added under the scope `moe.shared`.  `bias` [experts]: the
+    router's selection bias (`route_top_k`)."""
     lead, h = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, h)
     n_tok, held, n_all = x2.shape[0], w_gate.shape[0], w_router.shape[1]
     act = _ACT[activation]
     with jax.named_scope("moe.route"):
-        logits = jnp.dot(router_input.reshape(-1, h), w_router,
-                         preferred_element_type=jnp.float32)
-        p, idx = route_top_k(logits, top_k, norm_topk_prob, scoring,
-                             routed_scale)
+        with jax.named_scope("moe.router"):
+            logits = jnp.dot(router_input.reshape(-1, h), w_router,
+                             preferred_element_type=jnp.float32)
+            p, idx = route_top_k(logits, top_k, norm_topk_prob, scoring,
+                                 routed_scale, bias)
         _count_load(idx, lead, first, held)
 
     def ffn(rows, sizes):
@@ -264,13 +281,16 @@ class DroplessMoE(Layer):
     (`route_top_k`).  Parameters: `w_router` [hidden, experts], the stacked
     `w_gate`, `w_up` [held, hidden, width], `w_down` [held, width, hidden]
     and, with `shared_width`, one shared expert's `shared_gate`,
-    `shared_up` [hidden, shared_width], `shared_down`.  No biases."""
+    `shared_up` [hidden, shared_width], `shared_down`.  No biases on any
+    product; with `expert_bias` the buffer `expert_bias` [experts] (float32
+    zeros until something loads or sets it) is the router's selection
+    bias."""
 
     def __init__(self, hidden_size: int, expert_width: int, num_experts: int,
                  top_k: int, norm_topk_prob: bool = True, experts_held=None,
                  weight_attr=None, scoring: str = "softmax",
                  routed_scale: float = 1.0, activation: str = "relu",
-                 shared_width: int = 0):
+                 shared_width: int = 0, expert_bias: bool = False):
         super().__init__()
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -301,6 +321,12 @@ class DroplessMoE(Layer):
             self.shared_gate = param((h, int(shared_width)))
             self.shared_up = param((h, int(shared_width)))
             self.shared_down = param((int(shared_width), h))
+        self.has_bias = bool(expert_bias)
+        if self.has_bias:
+            if scoring != "sigmoid":
+                raise ValueError("expert_bias goes with sigmoid scoring")
+            self.register_buffer("expert_bias", Tensor(
+                jnp.zeros((int(num_experts),), jnp.float32), _internal=True))
 
     def forward(self, x, router_input=None):
         shared = ((self.shared_gate, self.shared_up, self.shared_down)
@@ -310,4 +336,5 @@ class DroplessMoE(Layer):
             self.w_gate, self.w_up, self.w_down, top_k=self.top_k,
             first=self.experts_held[0], norm_topk_prob=self.norm_topk_prob,
             scoring=self.scoring, routed_scale=self.routed_scale,
-            activation=self.activation, shared=shared)
+            activation=self.activation, shared=shared,
+            bias=self.expert_bias if self.has_bias else None)

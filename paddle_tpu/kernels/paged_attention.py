@@ -352,36 +352,46 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
 DENSE_BLOCK = 128
 
 
-def first_block(lengths, block: int, window=None):
+def first_block(lengths, block: int, window=None, ring=None):
     """The first block a row's read needs: 0, or on a sliding-window layer
     the block that holds position ``length - window + 1``, the oldest key
-    the row's first new query still reads."""
+    the row's first new query still reads.  On a ring of ``ring`` positions
+    (position p lives at ``p mod ring``) that block's place in the ring."""
     xp = jnp if isinstance(lengths, jax.Array) else np
     lengths = xp.asarray(lengths, xp.int32)
     if window is None:
         return xp.zeros_like(lengths)
-    return (xp.maximum(lengths - (window - 1), 0) // block).astype(xp.int32)
+    first = xp.maximum(lengths - (window - 1), 0) // block
+    if ring is not None:
+        first = first % (ring // block)
+    return first.astype(xp.int32)
 
 
-def live_blocks(lengths, width: int, max_len: int, block: int, window=None):
+def live_blocks(lengths, width: int, max_len: int, block: int, window=None,
+                ring=None):
     """Blocks of ``block`` positions the dense read streams for each row:
     those that hold positions ``0 .. length + width - 1`` (from
     :func:`first_block` on with a ``window``), none for a parked row
-    (``length >= max_len``).  The kernel's work list is built from this
-    count and the engine's ``decode_kv_read_positions`` sums it (numpy in,
-    numpy out; jax in, jax out)."""
+    (``length >= max_len``).  On a ring of ``ring`` positions the same
+    blocks, each at its place in the ring and none twice: at most the whole
+    ring.  The kernel's work list is built from this count and the engine's
+    ``decode_kv_read_positions`` sums it (numpy in, numpy out; jax in, jax
+    out)."""
     xp = jnp if isinstance(lengths, jax.Array) else np
     lengths = xp.asarray(lengths, xp.int32)
     n = xp.minimum((lengths + (width + block - 1)) // block,
                    max_len // block) - first_block(lengths, block, window)
+    if ring is not None:
+        n = xp.minimum(n, ring // block)
     return xp.where(lengths >= max_len, 0, n).astype(xp.int32)
 
 
-def _work_list(nb, n_blk: int, first=None):
+def _work_list(nb, n_blk: int, first=None, ring: bool = False):
     """The grid as a list of steps: one per live block, and one for a
     parked row (it only writes the row's zeros).  Returns ``(steps, row,
     blk, held)``: how many steps there are, and per step its row, its block
-    within the row's live run (which starts at block ``first[row]``), and
+    within the row's live run (which starts at block ``first[row]`` and, on
+    a ``ring``, wraps past the row's last block to its first), and
     the flat index ``row * n_blk + block`` of the pool block it holds — a
     parked row's step keeps the block of the step before it (an unchanged
     block index is not fetched again).  Entries past ``steps`` are
@@ -398,24 +408,40 @@ def _work_list(nb, n_blk: int, first=None):
     row = xp.minimum((t[:, None] >= ends[None, :]).sum(axis=1),
                      B - 1).astype(xp.int32)
     blk = t - (ends - per_row)[row]
-    flat = xp.where(blk < nb[row], row * n_blk + first[row] + blk, 0)
-    held = (jax.lax.cummax(flat) if xp is jnp
-            else np.maximum.accumulate(flat))
+    cummax = jax.lax.cummax if xp is jnp else np.maximum.accumulate
+    live = blk < nb[row]
+    if ring:
+        # a run that wraps is not increasing: hold the last live step's block
+        held = (row * n_blk + (first[row] + blk) % n_blk)[
+            cummax(xp.where(live, t, 0))]
+    else:
+        held = cummax(xp.where(live, row * n_blk + first[row] + blk, 0))
     return ends[-1], row, blk.astype(xp.int32), held.astype(xp.int32)
 
 
 def dense_blocks_held(lengths, width: int, max_len: int, block: int,
-                      window=None):
+                      window=None, ring=None):
     """The ``(row, block)`` of the pool the kernel's grid holds at each step,
     in grid order: what the K/V index map reads, evaluated on the host.  A
     step whose block differs from the step before is a fetch (the tests
     count them against :func:`live_blocks`)."""
-    n_blk = max_len // block
+    n_blk = (max_len if ring is None else ring) // block
     lengths = np.asarray(lengths)
     steps, _, _, held = _work_list(
-        live_blocks(lengths, width, max_len, block, window), n_blk,
-        first_block(lengths, block, window))
+        live_blocks(lengths, width, max_len, block, window, ring), n_blk,
+        first_block(lengths, block, window, ring), ring is not None)
     return [divmod(int(f), n_blk) for f in held[:int(steps)]]
+
+
+def dense_block(heads: int, kv_heads: int, max_len: int) -> int:
+    """Positions per block of the dense pool's decode read.  A pool of
+    grouped-query heads (``kv_heads < heads``) holds ``1 / group`` the bytes
+    per position, so its block is ``DENSE_BLOCK`` times the largest power
+    of two in the group: a step then streams about the bytes
+    ``DENSE_BLOCK`` was timed at.  Also the unit a window layer's ring is
+    rounded up to (`models/kv_cache.py` `ring_len`), whatever backend
+    reads it."""
+    return min(DENSE_BLOCK << ((heads // kv_heads).bit_length() - 1), max_len)
 
 
 def dense_read_block(*, heads: int, head_dim: int, dtype, width: int,
@@ -425,17 +451,14 @@ def dense_read_block(*, heads: int, head_dim: int, dtype, width: int,
     backend (unless a test pinned the mode), for an int8 pool, for a
     ``max_len`` the block does not divide, or for a span so wide that the
     ``[width * heads, block * kv_heads]`` scores and the double-buffered K
-    and V blocks would not fit VMEM.  A pool of grouped-query heads
-    (``kv_heads < heads``) holds ``1 / group`` the bytes per position, so
-    its block is ``DENSE_BLOCK`` times the largest power of two in the
-    group: a step then streams about the bytes ``DENSE_BLOCK`` was timed
-    at."""
+    and V blocks would not fit VMEM.  The size itself is
+    :func:`dense_block`'s."""
     if _INTERPRET is None and jax.default_backend() == "cpu":
         return None
     if jnp.dtype(dtype) == jnp.int8:
         return None
     kv_heads = heads if kv_heads is None else kv_heads
-    P = min(DENSE_BLOCK << ((heads // kv_heads).bit_length() - 1), max_len)
+    P = dense_block(heads, kv_heads, max_len)
     vmem = (4 * width * heads * P * kv_heads * 4
             + 4 * P * kv_heads * head_dim * jnp.dtype(dtype).itemsize)
     return None if max_len % P or 2 * vmem > _VMEM_LIMIT_BYTES else P
@@ -443,7 +466,7 @@ def dense_read_block(*, heads: int, head_dim: int, dtype, width: int,
 
 def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, first_ref,
                   q_ref, k_ref, v_ref, col_ref, qrow_ref, o_ref, m_ref,
-                  l_ref, acc_ref, *, P, W, H, Hkv, scale, window):
+                  l_ref, acc_ref, *, P, W, H, Hkv, scale, window, ring):
     t = pl.program_id(0)
     r, i = row_ref[t], blk_ref[t]
     n = nb_ref[r]
@@ -467,8 +490,19 @@ def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, first_ref,
             preferred_element_type=jnp.float32) * jnp.float32(scale)
         pos, head = col_ref[0:1, :], col_ref[1:2, :]          # [1, P*Hkv]
         qw, qhead = qrow_ref[:, 0:1], qrow_ref[:, 1:2]        # [W*H, 1]
-        pos = (first_ref[r] + i) * P + pos
-        keep = (head == qhead) & (pos <= len_ref[r] + qw)
+        if ring is None:
+            pos = (first_ref[r] + i) * P + pos
+            keep = (head == qhead) & (pos <= len_ref[r] + qw)
+        else:
+            # a ring of `ring` positions: slot s holds the newest position
+            # p = s (mod ring) up to the span's last one; keys carry their
+            # RoPE, so their order in the ring does not matter
+            slot = jax.lax.rem(first_ref[r] + i,
+                               jnp.int32(ring // P)) * P + pos
+            last = len_ref[r] + (W - 1)
+            back = jax.lax.rem(last, jnp.int32(ring)) - slot
+            pos = last - jnp.where(back >= 0, back, back + ring)
+            keep = (head == qhead) & (pos <= len_ref[r] + qw) & (pos >= 0)
         if window is not None:
             keep &= pos > len_ref[r] + qw - window
         s = jnp.where(keep, s, jnp.float32(_NEG_INF))
@@ -499,7 +533,7 @@ def _dense_kernel(len_ref, nb_ref, row_ref, blk_ref, held_ref, first_ref,
 
 
 def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
-                           scale=None, window=None):
+                           scale=None, window=None, limit=None):
     """Per-slot decode attention over the dense pool, streaming live blocks
     only.
 
@@ -517,6 +551,11 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
             ``max_len`` and reads nothing.
         block: positions per block (default :data:`DENSE_BLOCK`, at most
             ``max_len``); must divide ``max_len``.
+        limit: the rows are RINGS of a window layer, shorter than the
+            ``limit`` positions a row addresses (a parked row sits at
+            ``limit``): position p lives at ``p mod max_len``, the work
+            list is the row's live ring blocks and the mask goes by the
+            position a slot holds.
 
     Returns:
         ``[n_rows, W, heads, head_dim]`` in ``q.dtype`` (zeros for a parked
@@ -533,9 +572,14 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     lengths = jnp.asarray(lengths, jnp.int32)
-    nb = live_blocks(lengths, W, L, P, window)
-    first = first_block(lengths, P, window)
-    steps, row, blk, held = _work_list(nb, n_blk, first)
+    ring = None if limit is None else L
+    if ring is not None and (window is None or window + W - 1 > ring):
+        raise ValueError(f"a ring of {ring} positions does not hold a "
+                         f"window of {window} and a span of {W}")
+    nb = live_blocks(lengths, W, L if ring is None else int(limit), P,
+                     window, ring)
+    first = first_block(lengths, P, window, ring)
+    steps, row, blk, held = _work_list(nb, n_blk, first, ring is not None)
     # which (position, KV head) a column of the scores is, and which (query,
     # KV head it reads) a row: int32 operands, fetched once (their block
     # never moves)
@@ -573,7 +617,7 @@ def dense_decode_attention(q, k_pool, v_pool, lengths, block=None,
     )
     kernel = functools.partial(
         _dense_kernel, P=P, W=W, H=H, Hkv=Hkv, scale=float(scale),
-        window=None if window is None else int(window))
+        window=None if window is None else int(window), ring=ring)
     return pl.pallas_call(
         kernel,
         name="dense_decode_read",

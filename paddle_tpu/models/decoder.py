@@ -33,6 +33,26 @@ beside one shared expert; the router reads the normed MLP input:
     f = dense MLP(m)  or  sum_{e in top 8, held} p_e E_e(m) + E_shared(m)
     x' = h + N(f)
 
+The third is Trinity-Large-Preview (Arcee, 2026; `model_type: afmoe`): 60
+layers in periods of [3 x 4096-token sliding-window attention with RoPE,
+global attention without positions], grouped-query heads (48 / 8 of 128)
+with an RMSNorm over every query and key head (`qk_norm`) and a sigmoid
+gate on the attention's output computed from the layer's normed input
+(`attention_gate`), sandwich norms, a dense SwiGLU MLP on the first
+`first_k_dense_replace` layers and then 256 SwiGLU experts, 4 per token,
+beside one shared expert; the router scores by sigmoid, CHOOSES by score +
+a selection bias (`expert_bias`: a buffer, not trained) and WEIGHS by the
+score alone, renormalised over the chosen and scaled by 2.448; the
+embedding is scaled by sqrt(hidden) (`embedding_scale`, muP):
+
+    x0 = E[ids] sqrt(hidden)
+    a = N(x);  q, k, v = a W_q, a W_k, a W_v;  q, k = N_q(q), N_k(k)  per head
+    q, k = RoPE(q, k) on sliding layers only
+    h = x + N((attention(q, k, v) * sigmoid(a W_gate)) W_o);  m = N(h)
+    s = sigmoid(m W_r);  S = top 4 of (s + b);  p_e = 2.448 s_e / sum_S s
+    f = dense MLP(m)  or  sum_{e in S, held} p_e E_e(m) + E_shared(m)
+    x' = h + N(f)
+
 Serving rides the same KV-cache protocol as GPT (`models/kv_cache.py`):
 `DecoderForCausalLM(ids, caches=..., use_cache=True)`; the engine finds the
 trunk as `.decoder` and the head as `.lm_head`.  Serving only: no dropout,
@@ -61,6 +81,7 @@ from .kv_cache import (cache_positions, cached_attention,
                        cached_latent_attention)
 
 _PERIOD = (0, 1, 1, 1)      # global without positions, then window + RoPE
+_AFMOE_PERIOD = (1, 1, 1, 0)    # three window + RoPE, then global without
 
 
 @dataclass
@@ -110,10 +131,20 @@ class DecoderConfig:
     # seeded initialisation where it is not Normal(0, initializer_range) and
     # gains of 1: the embedding's standard deviation, the sandwich norms'
     # gain, the gain of the latent attention's inner norm on the queries
-    # (it scales every attention score: how peaked a random model attends)
+    # (it scales every attention score: how peaked a random model attends;
+    # with qk_norm, the gain of the per-head norm on the queries)
     embedding_std: float | None = None
     sandwich_norm_gain: float = 1.0
     q_norm_gain: float = 1.0
+    # afmoe: a sigmoid gate on the attention's output, from the layer's
+    # normed input (W_gate: hidden -> heads x head_dim); an RMSNorm over
+    # head_dim on every query and key head, before RoPE; a selection bias on
+    # the sigmoid router's scores (chosen by score + bias, weighed by the
+    # score; a buffer of zeros, not trained); the embedding times a scale
+    attention_gate: bool = False
+    qk_norm: bool = False
+    expert_bias: bool = False
+    embedding_scale: float = 1.0
 
     def __post_init__(self):
         self.rope_layout = tuple(self.rope_layout)
@@ -180,6 +211,35 @@ DECODER_CONFIGS = {
         intermediate_size=128, sandwich_norm=True, kv_lora_rank=16,
         q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
         v_head_dim=16),
+    # Trinity-Large-Preview as published (config.json of the source; 400 B
+    # parameters, 13 B active)
+    "trinity-large-preview": dict(
+        vocab_size=200192, hidden_size=3072, num_hidden_layers=60,
+        num_attention_heads=48, num_key_value_heads=8, head_dim=128,
+        max_position_embeddings=262144, rms_norm_eps=1e-5, rope_theta=1e4,
+        rope_layout=_AFMOE_PERIOD * 15,
+        sliding_window_layout=_AFMOE_PERIOD * 15, sliding_window_size=4096,
+        moe_num_primary_experts=256, moe_num_active_primary_experts=4,
+        moe_ffn_hidden_size=3072, scoring_func="sigmoid",
+        routed_scaling_factor=2.448, router_before_attention=False,
+        hidden_act="silu", n_shared_experts=1, first_k_dense_replace=6,
+        intermediate_size=12288, sandwich_norm=True, attention_gate=True,
+        qk_norm=True, expert_bias=True, embedding_scale=3072 ** 0.5),
+    # the same block at test size: 1 dense + 4 expert layers, the dense
+    # layer and one period [window, window, window, global]; 4 of the 16
+    # experts held
+    "trinity-tiny": dict(
+        vocab_size=256, hidden_size=64, num_hidden_layers=5,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        max_position_embeddings=1024, rms_norm_eps=1e-5, rope_theta=1e4,
+        rope_layout=(1, 1, 1, 1, 0), sliding_window_layout=(1, 1, 1, 1, 0),
+        sliding_window_size=8, moe_num_primary_experts=16,
+        moe_num_active_primary_experts=4, moe_ffn_hidden_size=32,
+        experts_held=(0, 4), scoring_func="sigmoid",
+        routed_scaling_factor=2.448, router_before_attention=False,
+        hidden_act="silu", n_shared_experts=1, first_k_dense_replace=1,
+        intermediate_size=128, sandwich_norm=True, attention_gate=True,
+        qk_norm=True, expert_bias=True, embedding_scale=8.0),
 }
 
 
@@ -195,7 +255,9 @@ def _matrix(layer: Layer, config: DecoderConfig, shape):
 
 class DecoderAttention(Layer):
     """Grouped-query causal attention, with RoPE and a sliding window where
-    the layer's entries of the layouts say so."""
+    the layer's entries of the layouts say so; with `qk_norm` an RMSNorm
+    over head_dim on every query and key head, with `attention_gate` a
+    sigmoid gate on the output (both from the configuration)."""
 
     def __init__(self, config: DecoderConfig, layer: int):
         super().__init__()
@@ -212,6 +274,14 @@ class DecoderAttention(Layer):
         self.k_proj = param((h, self.num_kv_heads * d))
         self.v_proj = param((h, self.num_kv_heads * d))
         self.o_proj = param((self.num_heads * d, h))
+        self.gate_proj = (param((h, self.num_heads * d))
+                          if config.attention_gate else None)
+        self.qk_norm = config.qk_norm
+        if self.qk_norm:
+            self.q_norm = RMSNorm(
+                d, epsilon=config.rms_norm_eps, weight_attr=ParamAttr(
+                    initializer=Constant(config.q_norm_gain)))
+            self.k_norm = RMSNorm(d, epsilon=config.rms_norm_eps)
 
     def forward(self, x, positions, cache=None):
         b, t = x.shape[0], x.shape[1]
@@ -221,6 +291,8 @@ class DecoderAttention(Layer):
                                             self.head_dim])
         v = matmul(x, self.v_proj).reshape([b, t, self.num_kv_heads,
                                             self.head_dim])
+        if self.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
         # the trace tells the two kinds of layer apart by these names
         with jax.named_scope("attn.window" if self.window else
                              "attn.global"):
@@ -231,6 +303,9 @@ class DecoderAttention(Layer):
                 q, k, v, cache, window=self.window,
                 owner="models.decoder.DecoderAttention")
         out = out.reshape([b, t, self.num_heads * self.head_dim])
+        if self.gate_proj is not None:
+            with jax.named_scope("attn.gate"):
+                out = out * F.sigmoid(matmul(x, self.gate_proj))
         return matmul(out, self.o_proj), new_cache
 
 
@@ -331,6 +406,7 @@ class DecoderLayer(Layer):
             routed_scale=config.routed_scaling_factor,
             activation=config.hidden_act,
             shared_width=config.n_shared_experts * config.moe_ffn_hidden_size,
+            expert_bias=config.expert_bias,
             weight_attr=ParamAttr(
                 initializer=Normal(0.0, config.initializer_range)))
 
@@ -375,6 +451,8 @@ class DecoderModel(Layer):
         positions = Tensor(cache_positions(caches[0], input_ids.shape[1]),
                            _internal=True)
         x = self.embed_tokens(input_ids)
+        if self.config.embedding_scale != 1.0:
+            x = x * self.config.embedding_scale
         new_caches = []
         for layer, cache in zip(self.layers, caches):
             x, c = layer(x, positions, cache)

@@ -42,6 +42,17 @@ form (the query taken through ``W_UK``, scores against the latent itself,
 values = its first ``kv_lora_rank`` columns, the output taken through
 ``W_UV``): one shared row per position for all heads.  Dense pool only.
 
+**Rings.**  A sliding-window layer never reads a key more than ``window``
+positions back, so its rows of the dense pool are RINGS of
+``R = window + span - 1`` positions rounded up to the read block
+(:func:`ring_len`; ``span`` the widest span a step writes), not ``max_len``
+long: position p lives at ``p mod R``, a slot holds the newest position
+congruent to it, and the mask goes by the position a slot holds (keys carry
+their RoPE already, so their order in the ring does not matter).  A prompt
+leaves its last ``R`` positions behind.  Nothing asks for it: every window
+layer of a dense pool whose ``max_len`` is longer than ``R`` is a ring
+(`KVPool.zeros` ``windows``; a layer's `SlotCache.limit` says so).
+
 int8 storage (``Engine(kv_dtype="int8")``): one float32 scale per *cached
 position* (the absmax over that position's ``[kv_heads, head_dim]`` vector),
 so a new token's K/V is quantised against its OWN absmax at write time,
@@ -108,6 +119,30 @@ def _span_mask(cols, att_len: int, window):
     return mask[:, None]
 
 
+def ring_len(window, span: int, block: int, row_len: int) -> int:
+    """Positions a layer's row of the dense pool holds: ``row_len``, or on a
+    sliding-window layer the ring of ``window + span - 1`` positions (what
+    the first query of a ``span``-wide step still reads once the whole span
+    is written), rounded up to the read ``block``, where that is shorter."""
+    if window is None:
+        return row_len
+    return min(-(-(window + span - 1) // block) * block, row_len)
+
+
+def _ring_positions(last, ring: int):
+    """``[B, ring]``: the position each slot of a ring holds once every
+    position up to ``last [B, 1]`` is written (the newest one congruent to
+    the slot; negative: never written)."""
+    return last - (last - jnp.arange(ring)[None, :]) % ring
+
+
+def _ring_mask(cols, ring: int, window: int):
+    """:func:`_span_mask` on a ring whose span ``cols`` is written."""
+    pos = _ring_positions(cols[:, -1:], ring)[:, None, :]
+    col = cols[:, :, None]
+    return ((pos <= col) & (pos > col - window) & (pos >= 0))[:, None]
+
+
 def _page_address(table, cols, num_pages: int, page_size: int):
     """``(page id, offset)`` of position ``cols[r, j]`` of row ``r``:
     ``pages[table[r, p // P], p % P]``.  A sentinel table entry
@@ -155,9 +190,12 @@ class SlotCache:
     [rows]``: each row's cached positions (a row parked at the addressable
     end writes nothing); ``k_scale`` / ``v_scale``: the int8 storage's
     per-position float32 scales, shaped like the storage's two leading
-    dims.  Static: ``layout`` and ``read`` (None = the masked XLA read over
+    dims.  Static: ``layout``, ``read`` (None = the masked XLA read over
     every row whole; a :class:`KernelRead` in the engine's decode
-    program).  The new span may be wider than one position (speculative
+    program) and ``limit`` (a window layer's RING, module docstring: the
+    positions a row addresses, more than the ``k.shape[1]`` it holds; None
+    = the row holds what it addresses).  The new span may be wider than one
+    position (speculative
     verification, prefix-tail prefill): position j of a row writes at its
     own offset + j and attends causally within the span.  The latent kind
     (module docstring) holds ``[rows, L, width]`` in ``k`` and None in
@@ -170,6 +208,7 @@ class SlotCache:
     v_scale: Any = None
     layout: str = "dense"
     read: Optional[KernelRead] = None
+    limit: Optional[int] = None
 
     @property
     def quantized(self) -> bool:
@@ -182,6 +221,8 @@ class SlotCache:
     @property
     def span(self) -> int:
         """Positions a row can address."""
+        if self.limit is not None:
+            return self.limit
         return (self.table.shape[1] * self.k.shape[1]
                 if self.layout == "paged" else self.k.shape[1])
 
@@ -213,8 +254,9 @@ class SlotCache:
     def written(self, k, v, cols):
         """The cache with the new positions' K/V scattered in at ``cols``
         (``lengths`` as they were).  A write past a dense row's end, or
-        through a sentinel page, drops: never clipped onto a live row.  The
-        latent kind in a program that reads through the kernel writes
+        through a sentinel page, drops: never clipped onto a live row (on a
+        ring, past the positions the row addresses).  The latent kind in a
+        program that reads through the kernel writes
         through the kernel's own write (a span from ``cols[:, 0]`` on)."""
         if self.latent and self.read is not None:
             from ..kernels import paged_attention as pk
@@ -224,6 +266,9 @@ class SlotCache:
             return self.put(_page_address(
                 jnp.asarray(self.table, jnp.int32), cols, self.k.shape[0],
                 self.k.shape[1]), k, v)
+        if self.limit is not None:
+            ring = self.k.shape[1]
+            cols = jnp.where(cols < self.limit, cols % ring, ring)
         return self.put((jnp.arange(self.k.shape[0])[:, None], cols), k, v)
 
     def _rows(self, dtype):
@@ -260,15 +305,16 @@ class SlotCache:
                 scale=scale, values=values))
         if self.read is None:
             k, v = self._rows(_raw(q).dtype)
+            mask = (_span_mask(cols, self.span, window) if self.limit is None
+                    else _ring_mask(cols, self.k.shape[1], window))
             return F.scaled_dot_product_attention(
-                q, _wrap(k), _wrap(v),
-                attn_mask=_wrap(_span_mask(cols, self.span, window)),
+                q, _wrap(k), _wrap(v), attn_mask=_wrap(mask),
                 dropout_p=0.0, is_causal=False, training=False)
         from ..kernels import paged_attention as pk
         if self.read.kernel == "dense":
             return _wrap(pk.dense_decode_attention(
                 _raw(q), self.k, self.v, self.lengths, block=self.read.block,
-                window=window))
+                window=window, limit=self.limit))
         if window is not None or self.k.shape[2] != q.shape[2]:
             raise ValueError(
                 "the paged decode kernel reads neither a sliding window "
@@ -282,7 +328,7 @@ class SlotCache:
 jax.tree_util.register_dataclass(
     SlotCache,
     data_fields=["k", "v", "lengths", "table", "k_scale", "v_scale"],
-    meta_fields=["layout", "read"])
+    meta_fields=["layout", "read", "limit"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,35 +343,47 @@ class KVPool:
     sidecars when ``k_scale`` is there.  What a layer stores is the model's
     to say (the cache shapes it reports): a K and a V per KV head, or, the
     latent kind, one ``[rows, row_len, width]`` array in ``k`` and no ``v``
-    at all (``v is None``; dense, unquantised)."""
+    at all (``v is None``; dense, unquantised).  A dense layer whose rows
+    are shorter than ``row_len`` is a window layer's ring (module
+    docstring)."""
     k: list
     v: Optional[list]
     k_scale: Optional[list] = None
     v_scale: Optional[list] = None
     layout: str = "dense"
     dtype: Any = None
+    row_len: Optional[int] = None
 
     @classmethod
     def zeros(cls, kv, *, layout: str, rows: int, row_len: int,
-              quantized: bool):
+              quantized: bool, windows=None, ring_span: int = 1,
+              ring_block: Optional[int] = None):
         """Sized from the per-layer ``(k, v)`` cache shapes the model
         reports (``[.., .., kv_heads, hd]``: KV heads, not query heads; or
-        ``([.., .., width], None)`` from a layer that stores a latent)."""
+        ``([.., .., width], None)`` from a layer that stores a latent).
+        ``windows`` (per layer, None = global) with ``ring_block`` makes the
+        dense pool's window layers rings of :func:`ring_len` positions for
+        steps that write at most ``ring_span`` positions."""
         latent = kv[0][1] is None
         if latent and (quantized or layout != "dense"):
             raise ValueError("a latent cache lives on the dense, "
                              "unquantised pool only")
+        if windows is None or ring_block is None or layout != "dense":
+            windows = [None] * len(kv)
+        lens = [ring_len(w, ring_span, ring_block, row_len) for w in windows]
 
-        def buf(s):
-            return jnp.zeros((rows, row_len) + tuple(s.shape[2:]),
+        def buf(s, n):
+            return jnp.zeros((rows, n) + tuple(s.shape[2:]),
                              jnp.int8 if quantized else s.dtype)
 
         def scales():
-            return ([jnp.zeros((rows, row_len), jnp.float32) for _ in kv]
+            return ([jnp.zeros((rows, n), jnp.float32) for n in lens]
                     if quantized else None)
-        return cls([buf(k) for k, _ in kv],
-                   None if latent else [buf(v) for _, v in kv],
-                   scales(), scales(), layout, jnp.dtype(kv[0][0].dtype))
+        return cls([buf(k, n) for (k, _), n in zip(kv, lens)],
+                   None if latent else [buf(v, n)
+                                        for (_, v), n in zip(kv, lens)],
+                   scales(), scales(), layout, jnp.dtype(kv[0][0].dtype),
+                   row_len)
 
     @property
     def latent(self) -> bool:
@@ -341,8 +399,20 @@ class KVPool:
 
     @property
     def nbytes(self) -> int:
-        return sum(int(p.size) * p.dtype.itemsize
-                   for p in jax.tree_util.tree_leaves(self))
+        return sum(self.layer_nbytes)
+
+    @property
+    def layer_nbytes(self) -> list:
+        """Bytes each layer holds (K, V and their scales)."""
+        return [sum(int(g[i].size) * g[i].dtype.itemsize
+                    for g in self.groups()) for i in range(len(self.k))]
+
+    @property
+    def ring_lens(self) -> list:
+        """Per layer: the positions its ring holds, None where a row holds
+        all it addresses."""
+        return [k.shape[1] if self.layout == "dense" and self.row_len and
+                k.shape[1] < self.row_len else None for k in self.k]
 
     def groups(self) -> tuple:
         """The per-layer buffer lists: K, V and, for int8 storage, their
@@ -358,10 +428,11 @@ class KVPool:
         """The per-layer caches of one step (``tables`` with the paged
         layout)."""
         none = [None] * len(self.k)
-        return [SlotCache(k, v, lengths, tables, ks, vs, self.layout, read)
-                for k, v, ks, vs in zip(self.k, self.v or none,
-                                        self.k_scale or none,
-                                        self.v_scale or none)]
+        return [SlotCache(k, v, lengths, tables, ks, vs, self.layout, read,
+                          ring and self.row_len)
+                for k, v, ks, vs, ring in zip(
+                    self.k, self.v or none, self.k_scale or none,
+                    self.v_scale or none, self.ring_lens)]
 
     def updated(self, caches):
         """The pool holding the buffers of the caches a model returned."""
@@ -375,22 +446,34 @@ class KVPool:
         """Static caches (python-int length 0, so the prompt keeps the
         causal flash path) for ``n`` fresh prompts of up to ``bucket``
         positions, in the compute precision: a dense pool takes whole rows
-        back, a paged pool the bucket's positions."""
-        length = bucket if self.layout == "paged" else self.k[0].shape[1]
-
-        def zeros(p):
-            return None if p is None else _wrap(
-                jnp.zeros((n, length) + p.shape[2:], self.dtype))
-        return [(zeros(k), zeros(v), 0)
-                for k, v in zip(self.k, self.v or [None] * len(self.k))]
+        back, a paged pool and a ring the bucket's positions."""
+        def zeros(p, ring):
+            length = (bucket if self.layout == "paged" or ring
+                      else p.shape[1])
+            return _wrap(jnp.zeros((n, length) + p.shape[2:], self.dtype))
+        return [(zeros(k, ring), None if v is None else zeros(v, ring), 0)
+                for k, v, ring in zip(self.k, self.v or [None] * len(self.k),
+                                      self.ring_lens)]
 
     def with_prompts(self, caches, addr, prompt_lens):
         """The pool with freshly prefilled :meth:`prompt_caches` written
-        where ``addr`` says: whole rows at slot indices (``dense``), or
+        where ``addr`` says: whole rows at slot indices (``dense``; a ring
+        takes the prompt's last positions, each at its slot), or
         every real position through its lane's page-table row (``paged``;
         padding positions drop).  Every lane names a request.  int8 storage
         quantises here: the prompt math itself stays full precision."""
         at = addr
+
+        def row(x, ring):
+            """The ring's row of each prompt, from its bucket ``x``."""
+            x = _raw(x)
+            if not ring or x is None:
+                return x
+            pos = jnp.clip(_ring_positions(prompt_lens[:, None] - 1, ring),
+                           0, x.shape[1] - 1)
+            return jnp.take_along_axis(
+                x, pos.reshape(pos.shape + (1,) * (x.ndim - 2)), axis=1)
+
         if self.layout == "paged":
             pos = jnp.arange(caches[0][0].shape[1])
             pid, off = _page_address(addr, jnp.broadcast_to(
@@ -399,8 +482,9 @@ class KVPool:
             live = pos[None, :] < prompt_lens[:, None]
             at = (jnp.where(live, pid, self.rows), off)
         return self.updated([
-            view.put(at, _raw(c[0]), _raw(c[1]))
-            for view, c in zip(self.caches(None), caches)])
+            view.put(at, row(c[0], ring), row(c[1], ring))
+            for view, c, ring in zip(self.caches(None), caches,
+                                     self.ring_lens)])
 
     def copied(self, src, dst):
         """Rows (a prefix hit's cached row into the hitting request's
@@ -415,7 +499,7 @@ class KVPool:
 
 jax.tree_util.register_dataclass(
     KVPool, data_fields=["k", "v", "k_scale", "v_scale"],
-    meta_fields=["layout", "dtype"])
+    meta_fields=["layout", "dtype", "row_len"])
 
 
 def cache_positions(cache, t: int):

@@ -82,7 +82,7 @@ import os
 import threading
 import time
 import weakref
-from collections import deque
+from collections import Counter, deque
 from concurrent.futures import CancelledError
 from typing import Callable, NamedTuple, Optional
 
@@ -417,6 +417,12 @@ def _sampling_rows(batch) -> tuple[int, int]:
     branches of that wave's prefill program turn on."""
     hot = [r for r in batch if r.temperature > 0]
     return len(hot), sum(r.top_k > 0 for r in hot)
+
+
+# a decode dispatch span's KV stats -> the `stats()` counters they sum into
+_KV_STATS = {f"kv_{what}{kind}": f"decode_kv_{what}_positions{kind}"
+             for what in ("live", "read")
+             for kind in ("", "_window", "_global")}
 
 
 def _trunk(model):
@@ -766,6 +772,40 @@ class Engine:
                     block=self._prefix.block)
                 self._own_host_tier = True
 
+        # -- rings (models/kv_cache.py "Rings"): on the dense pool a
+        # sliding-window layer's row holds its last `ring_len` positions
+        # where that is shorter than max_len; `_ring_block` is the unit the
+        # ring is rounded up to (None: no layer is a ring).  What a ring
+        # cannot honour is refused here, by name
+        self._ring_block = None
+        # {(window, ring positions or None): layers of that kind}
+        self._kv_kinds: dict = {}
+        self._pool_bytes_window = 0
+        trunk = _trunk(model)
+        windows = (list(trunk.attention_windows())
+                   if hasattr(trunk, "attention_windows") else [])
+        if not self.paged_kv and any(windows):
+            from ..kernels.paged_attention import dense_block
+            from ..models.kv_cache import ring_len
+            heads = int(cfg.num_attention_heads)
+            blk = dense_block(
+                heads, int(getattr(cfg, "num_key_value_heads", heads)),
+                self.max_len)
+            rings = [ring_len(w, self._spec_width, blk, self.max_len)
+                     for w in windows if w]
+            if min(rings) < self.max_len:
+                self._ring_block = blk
+                if self._prefix is not None:
+                    raise ValueError(
+                        f"{type(model).__name__} cannot be served with "
+                        f"prefix_cache on the dense pool: a sliding-window "
+                        f"layer's row is a ring of its last {min(rings)} "
+                        f"positions (max_len {self.max_len}), so the row a "
+                        f"prefix hit copies holds its donor's last "
+                        f"positions and not the prefix's, and the tail "
+                        f"prefill behind it would read them; paged_kv=True "
+                        f"keeps every position")
+
         self._pool = SlotPool(self.max_slots)
         self._queue: deque = deque()
         self._lock = threading.Lock()
@@ -836,6 +876,10 @@ class Engine:
                         "prefill_tokens": 0, "prefill_padded_tokens": 0,
                         "decode_kv_live_positions": 0,
                         "decode_kv_read_positions": 0,
+                        "decode_kv_live_positions_window": 0,
+                        "decode_kv_live_positions_global": 0,
+                        "decode_kv_read_positions_window": 0,
+                        "decode_kv_read_positions_global": 0,
                         "decode_sampled_steps": 0, "decode_topk_steps": 0,
                         "decode_lookahead_steps": 0,
                         "decode_overshoot_rows": 0,
@@ -1247,6 +1291,14 @@ class Engine:
             out["prefix_entries"] = (0 if self._prefix is None
                                      else len(self._prefix))
             out["kv_pool_bytes"] = self._pool_bytes
+            # by layer kind: sliding-window layers (rings on the dense
+            # pool, `kv_ring_len` positions a row; 0 = none is a ring) and
+            # global layers
+            out["kv_pool_bytes_window"] = self._pool_bytes_window
+            out["kv_pool_bytes_global"] = (self._pool_bytes -
+                                           self._pool_bytes_window)
+            out["kv_ring_len"] = max([r or 0 for _, r in self._kv_kinds],
+                                     default=0)
             out["weight_bytes"] = self._weight_bytes
             if self._adapters is not None:
                 out["adapters_resident"] = self._adapters.n_resident
@@ -1476,13 +1528,17 @@ class Engine:
             kv, layout="paged" if paged else "dense",
             rows=self._page_alloc.num_pages if paged else n_rows,
             row_len=self._page_alloc.page_size if paged else L,
-            quantized=self._kv_quant)
+            quantized=self._kv_quant, windows=self._kv_windows,
+            ring_span=self._spec_width, ring_block=self._ring_block)
         total = self._kv_pool.nbytes
+        rings = self._kv_pool.ring_lens
         led = _perfscope.ledger()
         krow = led.register(
             "kv_pool", total,
             detail=(f"paged KV pool, {self._page_alloc.num_pages} pages"
-                    if paged else f"dense KV pool, {n_rows} slot rows"))
+                    if paged else f"dense KV pool, {n_rows} slot rows" + (
+                        f", window layers rings of {max(filter(None, rings))}"
+                        if any(rings) else "")))
         # prefix-cache sub-account: cached rows/pages live INSIDE the
         # pool bytes, so the ledger tracks them as a nested owner
         # (informational, never double-counted)
@@ -1492,6 +1548,11 @@ class Engine:
             if self._prefix is not None else None)
         with self._lock:
             self._pool_bytes = total
+            self._pool_bytes_window = sum(
+                b for b, w in zip(self._kv_pool.layer_nbytes,
+                                  self._kv_windows) if w)
+            self._kv_kinds = Counter(
+                zip(self._kv_windows, rings))
             self._ledger_rows.append(krow)
             if paged:
                 self._page_alloc.bytes_per_page = total // max(
@@ -2711,12 +2772,11 @@ class Engine:
                     if lengths[slot] < self._park}
             if not live:
                 return None
-            kv_live, kv_read = self._decode_kv_positions(live, lengths)
+            kv = self._decode_kv_positions(live, lengths)
             sampled, topk = self._decode_sampling_rows(lengths, temps, topks)
             self._step_ordinal += 1
-        with phase("serving.decode.dispatch", active=len(live),
-                   kv_live=kv_live, kv_read=kv_read, sampled=sampled,
-                   topk=topk, step=self._step_ordinal):
+        with phase("serving.decode.dispatch", active=len(live), **kv,
+                   sampled=sampled, topk=topk, step=self._step_ordinal):
             t0 = time.perf_counter()
             faults.fault_point("serving.decode", active=len(live))
             if self._decode_timeout_s is not None:
@@ -2829,33 +2889,37 @@ class Engine:
         needs (`kv_live`: each live slot's context and its new span; on a
         sliding-window layer only what the window admits) and the positions
         its attention read streams (`kv_read`): every row whole on an XLA
-        read, each row's live blocks or pages on a kernel (on a window
-        layer from the window's first block on)."""
+        read (a ring's row is the ring), each row's live blocks or pages on
+        a kernel (on a window layer from the window's first block on; on a
+        ring its live ring blocks).  Returns the dispatch span's stats: the
+        two sums and each by layer kind (`_window`, `_global`)."""
         from ..kernels.paged_attention import live_blocks
         W = self._spec_width
         span = (self._max_pages_per_slot * self._page_alloc.page_size
                 if self.paged_kv else self.max_len)
         P = self._decode_read_block
         ctx = np.asarray([int(lengths[s]) + W for s in live], np.int64)
-        kv_live = kv_read = 0
-        for window in set(self._kv_windows):
-            n_layers = self._kv_windows.count(window)
-            live = ctx if window is None else np.minimum(ctx, window + W - 1)
+        out = dict.fromkeys(_KV_STATS, 0)
+        for (window, ring), n_layers in self._kv_kinds.items():
+            need = ctx if window is None else np.minimum(ctx, window + W - 1)
             if P is None:
-                read = len(lengths) * span
+                read = len(lengths) * (ring or span)
             else:
-                nb = live_blocks(lengths, W, span, P, window)
+                nb = live_blocks(lengths, W, span, P, window, ring)
                 if self.paged_kv:
                     # the paged kernel's index map stands on one clamped
                     # page for a parked row
                     nb = np.maximum(nb, 1)
                 read = int(nb.sum()) * P
-            kv_live += n_layers * int(live.sum())
-            kv_read += n_layers * read
+            kind = "global" if window is None else "window"
+            out["kv_live_" + kind] += n_layers * int(need.sum())
+            out["kv_read_" + kind] += n_layers * read
+        out["kv_live"] = out["kv_live_window"] + out["kv_live_global"]
+        out["kv_read"] = out["kv_read_window"] + out["kv_read_global"]
         with self._lock:
-            self._counts["decode_kv_live_positions"] += kv_live
-            self._counts["decode_kv_read_positions"] += kv_read
-        return kv_live, kv_read
+            for k, v in out.items():
+                self._counts[_KV_STATS[k]] += v
+        return out
 
     def _decode_sampling_rows(self, lengths, temps, topks):
         """The live rows of one decode dispatch that draw (`sampled`:

@@ -302,3 +302,195 @@ def test_pool_copy_is_bitwise_and_sentinel_lanes_are_no_ops(form):
         want = np.asarray(b).copy()
         want[3] = want[1]
         np.testing.assert_array_equal(np.asarray(a), want)
+
+
+# -- rings: a window layer's rows of the dense pool (ISSUE 35) ------------------
+
+from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
+from paddle_tpu.models.kv_cache import (_ring_mask, _ring_positions,  # noqa: E402
+                                        _span_mask, ring_len)
+
+WIN, RBLK = 6, 4            # a window of 6 in blocks of 4: rings of 8 / 12
+
+
+@pytest.mark.parametrize("window, span, block, row_len, want", [
+    (None, 1, 4, 32, 32), (6, 1, 4, 32, 8), (6, 4, 4, 32, 12),
+    (8, 1, 4, 32, 8), (8, 2, 4, 32, 12), (4096, 1, 512, 32768, 4096),
+    (4096, 4, 512, 16384, 4608), (30, 1, 4, 32, 32), (40, 1, 4, 32, 32),
+])
+def test_ring_len_is_window_and_span_rounded_up_to_the_block(
+        window, span, block, row_len, want):
+    assert ring_len(window, span, block, row_len) == want
+
+
+def test_ring_positions_name_the_newest_position_of_each_slot():
+    last = jnp.asarray([[2], [7], [8], [21]])
+    pos = np.asarray(_ring_positions(last, 8))
+    assert pos[0].tolist() == [0, 1, 2, -5, -4, -3, -2, -1]   # unwritten < 0
+    assert pos[1].tolist() == list(range(8))
+    assert pos[2].tolist() == [8, 1, 2, 3, 4, 5, 6, 7]
+    assert sorted(pos[3].tolist()) == list(range(14, 22))
+    assert all(p % 8 == s for s, p in enumerate(pos[3]))
+
+
+@pytest.mark.parametrize("t", [1, 3])
+def test_ring_mask_is_the_span_mask_of_the_positions_held(t):
+    ring = ring_len(WIN, t, RBLK, 32)
+    lengths = jnp.asarray([0, 3, 9, 20, 29])
+    cols = lengths[:, None] + jnp.arange(t)[None, :]
+    full = np.asarray(_span_mask(cols, 32, WIN))[:, 0]        # [B, t, 32]
+    got = np.asarray(_ring_mask(cols, ring, WIN))[:, 0]       # [B, t, ring]
+    pos = np.asarray(_ring_positions(cols[:, -1:], ring))
+    for b in range(5):
+        for j in range(t):
+            admitted = {p for p in range(32) if full[b, j, p]}
+            assert {int(pos[b, s]) for s in range(ring)
+                    if got[b, j, s]} == admitted
+
+
+def _ring_pool(quantized=False, span=1):
+    kv = [(jax.ShapeDtypeStruct((1, 1, HKV, D), jnp.float32),) * 2] * 3
+    return KVPool.zeros(kv, layout="dense", rows=5, row_len=L,
+                        quantized=quantized, windows=[None, WIN, WIN],
+                        ring_span=span, ring_block=RBLK)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_pool_window_layers_are_rings(quantized):
+    pool = _ring_pool(quantized)
+    assert pool.ring_lens == [None, 8, 8] and pool.row_len == L
+    assert [k.shape[1] for k in pool.k] == [L, 8, 8]
+    assert [v.shape[1] for v in pool.v] == [L, 8, 8]
+    if quantized:
+        assert [s.shape for s in pool.k_scale] == [(5, L), (5, 8), (5, 8)]
+    item = 1 if quantized else 4
+    per = 5 * 2 * HKV * D * item + (5 * 2 * 4 if quantized else 0)
+    assert pool.layer_nbytes == [L * per, 8 * per, 8 * per]
+    assert pool.nbytes == sum(pool.layer_nbytes)
+    caches = pool.caches(jnp.zeros((5,), jnp.int32))
+    assert [c.limit for c in caches] == [None, L, L]
+    assert [c.span for c in caches] == [L, L, L]
+    # a prompt's caches: whole rows on the full layer, the bucket on a ring
+    assert [c[0].shape[1] for c in pool.prompt_caches(2, 16)] == [L, 16, 16]
+    # no windows, no block, or the paged layout: no ring
+    kv = [(jax.ShapeDtypeStruct((1, 1, HKV, D), jnp.float32),) * 2] * 2
+    for kw in (dict(windows=None, ring_block=RBLK),
+               dict(windows=[WIN, WIN], ring_block=None)):
+        plain = KVPool.zeros(kv, layout="dense", rows=5, row_len=L,
+                             quantized=False, **kw)
+        assert plain.ring_lens == [None, None]
+    paged = KVPool.zeros(kv, layout="paged", rows=N_PAGES, row_len=P,
+                         quantized=False, windows=[WIN, WIN],
+                         ring_block=RBLK)
+    assert paged.ring_lens == [None, None]
+    # a pytree whose ring plan is static
+    leaves, treedef = jax.tree_util.tree_flatten(pool)
+    assert jax.tree_util.tree_unflatten(treedef, leaves).row_len == L
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_ring_prompts_leave_their_last_positions_each_at_its_slot(quantized):
+    pool = _ring_pool(quantized)
+    rs = np.random.RandomState(3)
+    bucket, plens = 16, np.asarray([5, 13], np.int32)
+    caches = []
+    for (k, v, _) in pool.prompt_caches(2, bucket):
+        caches.append((
+            paddle.to_tensor(rs.randn(*k.shape).astype(np.float32)),
+            paddle.to_tensor(rs.randn(*v.shape).astype(np.float32)), 0))
+    new = pool.with_prompts(caches, jnp.asarray([3, 1]), jnp.asarray(plens))
+    for layer in (1, 2):
+        src = np.asarray(caches[layer][0]._value)
+        for lane, (row, n) in enumerate(zip((3, 1), plens)):
+            for p in range(max(0, n - 8), n):       # the last 8 positions
+                want = src[lane, p]
+                got = np.asarray(new.k[layer][row, p % 8])
+                if quantized:
+                    q, sc = _quant(want)
+                    np.testing.assert_array_equal(got, q)
+                    assert new.k_scale[layer][row, p % 8] == sc
+                else:
+                    np.testing.assert_array_equal(got, want)
+        # rows no lane names keep their zeros
+        assert not np.asarray(new.k[layer][jnp.asarray([0, 2, 4])]).any()
+    # the full layer took whole rows
+    np.testing.assert_array_equal(np.asarray(new.k[0][3], np.float32)[:5]
+                                  if not quantized else 0, np.asarray(
+        caches[0][0]._value)[0, :5] if not quantized else 0)
+    # a row copy stays a row copy, ring or not
+    copied = new.copied(jnp.asarray([3]), jnp.asarray([0]))
+    for layer in range(3):
+        np.testing.assert_array_equal(np.asarray(copied.k[layer][0]),
+                                      np.asarray(new.k[layer][3]))
+
+
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("read", ["xla", "kernel"])
+def test_ring_cache_reads_what_the_full_row_reads(read, t):
+    """The same history in a full row and in a ring (every position at
+    `p mod R`), a span of t new positions written to both: the window's
+    attention is the same, a parked row writes nothing, a write past the
+    row's addressable end drops."""
+    ring = ring_len(WIN, t, RBLK, L)
+    rs = np.random.RandomState(t)
+    lengths = np.asarray([0, 5, 11, 26, L - t, L], np.int32)   # last: parked
+    B = len(lengths)
+    full_k = rs.randn(B, L, HKV, D).astype(np.float32)
+    full_v = rs.randn(B, L, HKV, D).astype(np.float32)
+    ring_k = np.zeros((B, ring, HKV, D), np.float32)
+    ring_v = np.zeros((B, ring, HKV, D), np.float32)
+    for b, n in enumerate(lengths[:-1]):
+        for p in range(max(0, n - ring), n):
+            ring_k[b, p % ring], ring_v[b, p % ring] = full_k[b, p], full_v[b, p]
+    q = paddle.to_tensor(rs.randn(B, t, H, D).astype(np.float32))
+    k = paddle.to_tensor(rs.randn(B, t, HKV, D).astype(np.float32))
+    v = paddle.to_tensor(rs.randn(B, t, HKV, D).astype(np.float32))
+    if read == "kernel":
+        pa.use_interpret_mode(True)
+    kr = KernelRead("dense", RBLK) if read == "kernel" else None
+    want, new_full = cached_attention(
+        q, k, v, SlotCache(jnp.asarray(full_k), jnp.asarray(full_v),
+                           jnp.asarray(lengths), read=kr), window=WIN)
+    got, new_ring = cached_attention(
+        q, k, v, SlotCache(jnp.asarray(ring_k), jnp.asarray(ring_v),
+                           jnp.asarray(lengths), read=kr, limit=L),
+        window=WIN)
+    np.testing.assert_allclose(np.asarray(got._value)[:-1],
+                               np.asarray(want._value)[:-1], atol=1e-5,
+                               rtol=0)
+    assert new_ring.limit == L and new_ring.k.shape[1] == ring
+    assert new_ring.lengths.tolist() == (lengths + t).tolist()
+    # the parked row's ring is untouched; every written position sits at
+    # its slot
+    np.testing.assert_array_equal(np.asarray(new_ring.k[-1]), ring_k[-1])
+    for b, n in enumerate(lengths[:-1]):
+        for j in range(t):
+            np.testing.assert_array_equal(
+                np.asarray(new_ring.k[b, (n + j) % ring]),
+                np.asarray(k._value)[b, j])
+
+
+def test_ring_work_list_holds_each_live_ring_block_once():
+    ring, blk, max_len = 12, 4, 32
+    lengths = np.asarray([0, 3, 7, 12, 13, 22, 31, 32])
+    nb = pa.live_blocks(lengths, 1, max_len, blk, WIN, ring)
+    first = pa.first_block(lengths, blk, WIN, ring)
+    # positions length - 5 .. length, block by block
+    want = [sorted({(p // blk) % 3 for p in range(max(0, n - 5), n + 1)})
+            if n < max_len else [] for n in lengths]
+    assert nb.tolist() == [len(w) for w in want]
+    held = pa.dense_blocks_held(lengths, 1, max_len, blk, WIN, ring)
+    by_row = {}             # fetches: a parked row's step holds the last block
+    for i, (row, b) in enumerate(held):
+        if i == 0 or held[i - 1] != (row, b):
+            by_row.setdefault(row, []).append(b)
+    for r, w in enumerate(want[:-1]):
+        assert sorted(by_row[r]) == w and len(set(by_row[r])) == len(w)
+        assert by_row[r][0] == first[r]
+    # a span as wide as the ring allows never asks for more than the ring
+    wide = pa.live_blocks(lengths, 7, max_len, blk, WIN, ring)
+    assert wide.max() <= ring // blk
+    # without a ring nothing changed
+    assert pa.live_blocks(lengths, 1, max_len, blk, WIN).tolist() == \
+        [min((n + blk) // blk, 8) - max(n - 5, 0) // blk if n < 32 else 0
+         for n in lengths]
